@@ -1,0 +1,508 @@
+"""Does the training path still start on the chip?  The quickest proof.
+
+    python chip_smoke.py             # one chip (what the driver runs)
+    python chip_smoke.py --chips 4   # only the four-chip phase; about 24
+                                     # minutes, most of them the classic
+                                     # path's small per-device compiles
+
+Drives model zoo -> `Trainer` -> `gluon.FusedTrainStep` on an attached TPU
+through the entry points a user calls, at full width, and checks what comes
+out against references in the tree.  One process, nothing spawned that needs
+the chip.  Prints one JSON line per phase and, as its last line, exactly
+`{"ok": ..., "device": {"platform", "kind", "count"}}`.  Exits non-zero when
+jax finds no TPU or when any phase failed; no phase carries on without a
+chip, none falls back to a smaller size.  Step times are printed for the
+`waitall` check and as evidence the steps ran; they are not measurements of
+speed (the benchmark's job) and are recorded nowhere as such.
+"""
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import math
+import os
+import sys
+import time
+import traceback
+
+import numpy as onp
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+BATCH = 128          # ResNet-50 b128 bf16: fixed, an OOM is a failure
+EAGER_BATCH = 32
+IMAGE = 224
+FLASH_SHAPE = (4, 12, 2048, 64)   # (B, H, T, D) of the BERT T=2048 step
+BERT_BATCH, BERT_SEQ = 4, 2048
+
+
+class Failed(Exception):
+    """A phase's check did not hold."""
+
+
+def check(cond, what):
+    if not cond:
+        raise Failed(what)
+
+
+def rel_err(got, want):
+    got = onp.asarray(got, onp.float64)
+    want = onp.asarray(want, onp.float64)
+    return float(onp.linalg.norm(got - want) /
+                 max(onp.linalg.norm(want), 1e-30))
+
+
+def memory_stat(dev, key):
+    return dev.memory_stats()[key]
+
+
+def compiled_step(fused, *args, batch_size):
+    """The step's compiled program, with what the checks read off it.  The
+    AOT compile of a step that already ran is served by the persistent
+    cache."""
+    compiled = fused.lower(*args, batch_size=batch_size).compile()
+    text = compiled.as_text()
+    return {
+        "tpu_custom_calls": text.count("tpu_custom_call"),
+        "all_reduces": text.count(" all-reduce(") +
+        text.count(" all-reduce-start("),
+        "program_temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+    }, compiled
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+def phase_device(ctx):
+    import jax
+    import jaxlib
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import _native
+    from mxnet_tpu.analysis.census import peaks_key
+
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = None
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    check(mx.num_tpus() == len(jax.devices()), "mx.num_tpus() != device count")
+    check(mx.current_context() == mx.tpu(0),
+          f"default context is {mx.current_context()}, not tpu(0)")
+    return {
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu_version, "device_kind": dev.device_kind,
+        "peaks_key": peaks_key(dev.device_kind),  # unknown kind: an error
+        "memory_stats": stats is not None,
+        "bytes_limit": (stats or {}).get("bytes_limit"),
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        "compile_cache_from_env": bool(
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+        "native_lib_loaded": _native.lib() is not None,
+    }
+
+
+# M x C of every BatchNorm backward in ResNet-50 at batch 128
+BN_SHAPES = [(128 * 112 * 112, 64), (128 * 56 * 56, 64), (128 * 56 * 56, 256),
+             (128 * 28 * 28, 128), (128 * 28 * 28, 512), (128 * 14 * 14, 256),
+             (128 * 14 * 14, 1024), (128 * 7 * 7, 512), (128 * 7 * 7, 2048)]
+
+
+def _dense_attention(q, k, v, mask):
+    """Plain attention in f32 with a key-padding mask: the reference the
+    flash kernel is held to."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf, precision=hi) * \
+        q.shape[-1] ** -0.5
+    s = jnp.where(mask[:, None, None, :] != 0, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, vf, precision=hi)
+
+
+def phase_kernels(ctx):
+    """Each Pallas kernel of the path, compiled (`interpret=False` passed,
+    not inferred), against its XLA form."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import nn as nn_ops
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    out = {}
+    key = jax.random.key(ctx["seed"])
+
+    # BatchNorm backward's two sums.  f32 sums of M unit-variance terms
+    # differ between two summation orders by about eps * sqrt(M) per
+    # accumulation round; a dropped tile would be off by sqrt(512) ~ 22.
+    xla_sums = jax.jit(lambda dy, xh: nn_ops._bn_bwd_sums(dy, xh, (0,)))
+    worst = 0.0
+    for i, (m, c) in enumerate(BN_SHAPES):
+        k1, k2 = jax.random.split(jax.random.fold_in(key, 2 + i))
+        dy = jax.random.normal(k1, (m, c), jnp.float32)
+        xh = jax.random.normal(k2, (m, c), jnp.float32)
+        got = nn_ops.bn_bwd_reduce_pallas(dy, xh, interpret=False)
+        want = xla_sums(dy, xh)
+        err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+        tol = 2e-4 * math.sqrt(m)
+        check(err <= tol, f"bn_bwd_reduce {m}x{c}: max abs err {err} > {tol}")
+        worst = max(worst, err / tol)
+        del dy, xh
+    out["bn_bwd_shapes"] = len(BN_SHAPES)
+    out["bn_bwd_worst_err_over_tol"] = round(worst, 4)
+
+    # flash attention, the shape and mask the BERT T=2048 step runs
+    b, h, t, d = FLASH_SHAPE
+    kq, kk, kv, kd = jax.random.split(jax.random.fold_in(key, 1), 4)
+    q, k, v = (jax.random.normal(kx, (b, h, t, d), jnp.float32)
+               .astype(jnp.bfloat16) for kx in (kq, kk, kv))
+    lens = onp.random.RandomState(ctx["seed"]).randint(t // 2, t + 1, size=b)
+    mask = jnp.asarray((onp.arange(t)[None, :] < lens[:, None])
+                       .astype(onp.int32))
+
+    flash = functools.partial(pk.flash_attention, mask=mask, interpret=False)
+
+    def grads(attention):
+        def loss(q, k, v):
+            return (attention(q, k, v).astype(jnp.float32) ** 2).sum()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    want_fwd = jax.jit(_dense_attention)(q, k, v, mask)
+    out["flash_fwd_rel_err"] = rel_err(
+        jax.jit(flash)(q, k, v).astype(jnp.float32), want_fwd)
+    check(out["flash_fwd_rel_err"] < 2e-2, f"flash forward: {out}")
+    want_g = grads(lambda q, k, v: _dense_attention(q, k, v, mask)
+                   .astype(jnp.bfloat16))
+    for name, g, w in zip(("dq", "dk", "dv"), grads(flash), want_g):
+        e = rel_err(g.astype(jnp.float32), w.astype(jnp.float32))
+        out[f"flash_{name}_rel_err"] = e
+        check(e < 4e-2, f"flash {name}: rel err {e}")
+
+    # once more with dropout 0.1: v == 1 makes each output row its kept
+    # weight mass over keep, so the mean reads the keep-rate
+    dropping = functools.partial(flash, dropout=0.1, key=kd)
+    dropped = jax.jit(dropping)(q, k, jnp.ones_like(v)).astype(jnp.float32)
+    check(bool(jnp.isfinite(dropped).all()), "flash+dropout: not finite")
+    out["flash_dropout_mean_mass"] = float(dropped.mean())
+    check(abs(out["flash_dropout_mean_mass"] - 1.0) < 0.01,
+          f"flash+dropout keep-rate: mean mass {out}")
+    check(all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
+              for g in grads(dropping)),
+          "flash+dropout backward: not finite")
+    return out
+
+
+def _resnet_step(ctxs, init=None, recipe=None):
+    """(net, module-with-loss, trainer, fused step) of ResNet-50 bf16 under
+    SGD+momentum, parameters set from ``init`` (name -> host array) so that
+    every path compared starts from the same point."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.gluon import loss as gloss
+    from mxnet_tpu.gluon.block import HybridBlock
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    class NetWithLoss(HybridBlock):
+        def __init__(self, net, loss_fn):
+            super().__init__()
+            self.net = net
+            self.loss_fn = loss_fn
+
+        def forward(self, x, y):
+            return self.loss_fn(self.net(x), y)
+
+    net = vision.resnet50_v1()
+    net.initialize(init=mx.init.Xavier(), ctx=ctxs)
+    net.cast("bfloat16")
+    mod = NetWithLoss(net, gloss.SoftmaxCrossEntropyLoss())
+    # the zoo model infers its channel counts: one forward of one image
+    # settles every deferred shape, op by op, before anything is compared
+    net(mx.np.zeros((1, 3, IMAGE, IMAGE), dtype="bfloat16", ctx=ctxs[0]))
+    if init is not None:
+        for name, p in net.collect_params().items():
+            p.set_data(mx.np.array(init[name], dtype=init[name].dtype))
+    trainer = mx.gluon.Trainer(
+        net.collect_params(), "sgd",
+        {"learning_rate": 0.01, "momentum": 0.9},
+        kvstore="tpu_ici" if len(ctxs) > 1 else "device")
+    fused = None if len(ctxs) > 1 else \
+        mx.gluon.FusedTrainStep(mod, trainer, recipe=recipe)
+    return net, mod, trainer, fused
+
+
+def _batch(seed, n, ctx=None):
+    import mxnet_tpu as mx
+    rs = onp.random.RandomState(seed)
+    x = rs.uniform(-1, 1, (n, 3, IMAGE, IMAGE)).astype(onp.float32)
+    y = rs.randint(0, 1000, (n,))
+    return (mx.np.array(x, dtype="bfloat16", ctx=ctx),
+            mx.np.array(y, dtype="int32", ctx=ctx))
+
+
+def _snapshot(net):
+    return {k: p.data().asnumpy() for k, p in net.collect_params().items()}
+
+
+def phase_resnet50_train(ctx):
+    import jax
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd
+
+    out = {}
+    tpu0 = mx.tpu(0)           # named, not defaulted
+    dev = tpu0.jax_device()
+    mx.random.seed(ctx["seed"])
+    x, y = _batch(ctx["seed"], BATCH, tpu0)
+
+    net, _mod, _tr, fused = _resnet_step([tpu0])
+    init = _snapshot(net)
+
+    def step_and_wait():
+        loss = fused(x, y, batch_size=BATCH)
+        return float(loss.asnumpy().astype(onp.float64).mean())
+
+    t0 = time.perf_counter()
+    losses = [step_and_wait()]
+    first = time.perf_counter() - t0
+    for _ in range(4):
+        losses.append(step_and_wait())
+    out["losses"] = [round(v, 4) for v in losses]
+    check(all(math.isfinite(v) for v in losses), f"loss not finite: {losses}")
+    check(losses[4] < losses[0], f"loss did not fall: {losses}")
+
+    params = list(net.collect_params().values())
+    off = [p.name for p in params if p.data()._data.devices() != {dev}]
+    check(not off, f"parameters not on {dev}: {off[:5]}")
+    out["params"] = len(params)
+
+    # does waitall() drain on this backend?  Every timing in the repo ends
+    # in it.  Against block_until_ready of all the step wrote.
+    def timed(wait):
+        t0 = time.perf_counter()
+        loss = fused(x, y, batch_size=BATCH)
+        dispatched = time.perf_counter() - t0
+        wait(loss)
+        return time.perf_counter() - t0, dispatched
+
+    def wait_bur(loss):
+        jax.block_until_ready([loss._data] + [p.data()._data for p in params])
+
+    mx.waitall()
+    bur, wall, disp = [], [], []
+    for _ in range(3):
+        t, d = timed(wait_bur)
+        bur.append(t)
+        disp.append(d)
+        t, d = timed(lambda _loss: mx.waitall())
+        wall.append(t)
+    out["step_s_block_until_ready"] = sorted(bur)[1]
+    out["step_s_waitall"] = sorted(wall)[1]
+    out["dispatch_s"] = sorted(disp)[1]
+    out["compile_s"] = first - out["step_s_block_until_ready"]
+    a, b = out["step_s_block_until_ready"], out["step_s_waitall"]
+    check(abs(a - b) <= 0.2 * max(a, b),
+          f"waitall ({b:.4f}s) and block_until_ready ({a:.4f}s) disagree")
+    out["peak_bytes_in_use"] = memory_stat(dev, "peak_bytes_in_use")
+    out["bytes_in_use"] = memory_stat(dev, "bytes_in_use")
+
+    t0 = time.perf_counter()
+    out.update(compiled_step(fused, x, y, batch_size=BATCH)[0])
+    out["aot_recompile_s"] = time.perf_counter() - t0
+    check(out["tpu_custom_calls"] > 0,
+          "no tpu_custom_call in the step: the Pallas BN backward is not in")
+
+    # the eager path the example uses, from the same initial parameters,
+    # against the fused step at its batch
+    xe, ye = _batch(ctx["seed"] + 1, EAGER_BATCH, tpu0)
+    _n, _m, _t, fused32 = _resnet_step([tpu0], init)
+    fused_first = float(fused32(xe, ye, batch_size=EAGER_BATCH)
+                        .asnumpy().astype(onp.float64).mean())
+    net_e, _m, trainer_e, _f = _resnet_step([tpu0], init)
+    net_e.hybridize(static_alloc=True)
+    loss_fn = mx.gluon.loss.SoftmaxCrossEntropyLoss()
+    eager = []
+    for _ in range(2):
+        with autograd.record():
+            loss = loss_fn(net_e(xe), ye)
+        loss.backward()
+        trainer_e.step(EAGER_BATCH)
+        eager.append(float(loss.asnumpy().astype(onp.float64).mean()))
+    out["eager_losses"] = [round(v, 4) for v in eager]
+    out["fused_b32_first_loss"] = round(fused_first, 4)
+    check(all(math.isfinite(v) for v in eager), f"eager loss: {eager}")
+    check(abs(eager[0] - fused_first) <= 2e-2 * abs(fused_first),
+          f"eager first loss {eager[0]} != fused {fused_first}")
+    return out
+
+
+def phase_bert_flash(ctx):
+    """bert_base pretraining as `benchmark/bert_pretrain_bench.py` builds it
+    (ragged masked batch, attention dropout 0.1) at B=4, T=2048."""
+    import mxnet_tpu as mx
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import bert_pretrain_bench
+
+    b, t = BERT_BATCH, BERT_SEQ
+    mx.random.seed(ctx["seed"])
+    onp.random.seed(ctx["seed"])
+    step, batch, _model = bert_pretrain_bench.build(b, t)
+    out = {}
+    t0 = time.perf_counter()
+    losses = [float(step(*batch, batch_size=b).asnumpy())]
+    out["first_step_s"] = time.perf_counter() - t0
+    for _ in range(2):
+        losses.append(float(step(*batch, batch_size=b).asnumpy()))
+    out["losses"] = [round(v, 4) for v in losses]
+    check(all(math.isfinite(v) for v in losses), f"loss not finite: {losses}")
+    out.update(compiled_step(step, *batch, batch_size=b)[0])
+    check(out["tpu_custom_calls"] > 0,
+          "no tpu_custom_call in the step: the flash path did not engage")
+    return out
+
+
+def phase_memory_profile(ctx):
+    """`profiler.dump_memory_profile` on this backend (it used to be refused
+    by platform string)."""
+    import tempfile
+
+    from mxnet_tpu import profiler
+
+    with tempfile.TemporaryDirectory() as d:
+        path = profiler.dump_memory_profile(os.path.join(d, "memory.pprof"))
+        return {"pprof_bytes": os.path.getsize(path)}
+
+
+def phase_resnet50_dp4(ctx):
+    """`FusedTrainStep(recipe="dp4")` at global batch 128 against the
+    one-device step on the same parameters and batch, then the classic
+    multi-context kvstore path."""
+    import jax
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon.utils import split_and_load
+
+    out = {}
+    devs = jax.devices()
+    check(len(devs) == 4, f"--chips 4 needs four devices, jax has {len(devs)}")
+    tpu0 = mx.tpu(0)
+    mx.random.seed(ctx["seed"])
+    x, y = _batch(ctx["seed"], BATCH, tpu0)
+
+    def three(fused):
+        return [float(fused(x, y, batch_size=BATCH).asnumpy()
+                      .astype(onp.float64).mean()) for _ in range(3)]
+
+    net1, _m, _t, fused1 = _resnet_step([tpu0])
+    init = _snapshot(net1)
+    one = three(fused1)
+    out["one_chip"] = compiled_step(fused1, x, y, batch_size=BATCH)[0]
+
+    net4, _m, _t, fused4 = _resnet_step([tpu0], init, recipe="dp4")
+    dp4 = three(fused4)
+    per_sample = fused4(x, y, batch_size=BATCH)._data   # stays dp-sharded
+    shards = per_sample.addressable_shards
+    check(len({s.device for s in shards}) == 4 and
+          all(s.data.shape == (BATCH // 4,) for s in shards),
+          f"per-sample loss is not 4 shards of {BATCH // 4} on 4 devices")
+    out["one_chip_losses"] = [round(v, 4) for v in one]
+    out["dp4_losses"] = [round(v, 4) for v in dp4]
+    # the first loss is a forward of equal parameters; after that two bf16
+    # trajectories drift apart by what their reductions round differently
+    for i, (a, b) in enumerate(zip(one, dp4)):
+        tol = 5e-2 if i else 1e-2
+        check(math.isfinite(b) and abs(a - b) <= tol * abs(a),
+              f"step {i + 1}: dp4 loss {b} != one-chip loss {a}")
+
+    out["dp4"], compiled = compiled_step(fused4, x, y, batch_size=BATCH)
+    check(out["dp4"]["all_reduces"] > 0, "no all-reduce in the dp4 step")
+    in_shardings = jax.tree_util.tree_leaves(compiled.input_shardings[0])
+    x_sh = [s for s in in_shardings
+            if s.shard_shape(x.shape) == (BATCH // 4,) + x.shape[1:]]
+    check(x_sh and len(x_sh[0].device_set) == 4,
+          "the step's image input is not four shards on four devices")
+    w = next(iter(net4.collect_params().values())).data()._data
+    check(len(w.sharding.device_set) == 4, "dp4 parameters not on 4 devices")
+    in_use = [memory_stat(d, "bytes_in_use") for d in devs]
+    out["bytes_in_use"] = in_use
+    check(all(v > 0 for v in in_use), f"a device holds nothing: {in_use}")
+
+    # classic path: one parameter copy per context, grads summed by the
+    # tpu_ici kvstore
+    ctxs = [mx.tpu(i) for i in range(4)]
+    net_c, _m, trainer_c, _f = _resnet_step(ctxs)
+    net_c.hybridize(static_alloc=True)
+    loss_fn = mx.gluon.loss.SoftmaxCrossEntropyLoss()
+    xe, ye = _batch(ctx["seed"] + 1, EAGER_BATCH)
+    weight = next(p for p in net_c.collect_params().values()
+                  if p.grad_req != "null")
+    before = weight.data(ctxs[0]).asnumpy().astype(onp.float32)
+    classic = []
+    for _ in range(2):
+        xs, ys = split_and_load(xe, ctxs), split_and_load(ye, ctxs)
+        with autograd.record():
+            losses = [loss_fn(net_c(xb), yb).mean() for xb, yb in zip(xs, ys)]
+        autograd.backward(losses)
+        trainer_c.step(EAGER_BATCH)
+        classic.append(sum(float(v.asnumpy()) for v in losses) / len(losses))
+    out["classic_losses"] = [round(v, 4) for v in classic]
+    check(all(math.isfinite(v) for v in classic), f"classic loss: {classic}")
+    where = {next(iter(weight.data(c)._data.devices())) for c in ctxs}
+    check(where == set(devs), f"classic path parameters live on {where}")
+    copies = [weight.data(c).asnumpy().astype(onp.float32) for c in ctxs]
+    out["classic_weight_moved"] = float(onp.abs(copies[0] - before).sum())
+    check(out["classic_weight_moved"] > 0, "the classic path updated nothing")
+    check(all(onp.array_equal(copies[0], c) for c in copies[1:]),
+          "the classic path's parameter copies fell out of sync")
+    return out
+
+
+# ---------------------------------------------------------------------------
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the four-chip phase (resnet50_dp4)")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    import jax
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if device["platform"] != "tpu" or len(devs) < args.chips:
+        print(json.dumps({"phase": "device", "ok": False,
+                          "error": f"need {args.chips} TPU device(s), jax "
+                                   f"found {device}"}))
+        print(json.dumps({"ok": False, "device": device}))
+        return 1
+
+    phases = [phase_device] + (
+        [phase_resnet50_dp4] if args.chips == 4 else
+        [phase_kernels, phase_resnet50_train, phase_bert_flash,
+         phase_memory_profile])
+    ctx = {"seed": args.seed}
+    ok = True
+    for phase in phases:
+        row = {"phase": phase.__name__[len("phase_"):], "ok": True}
+        t0 = time.perf_counter()
+        try:
+            row.update(phase(ctx))
+        except Exception as e:  # reported, and the run exits non-zero
+            traceback.print_exc()
+            row["ok"] = ok = False
+            row["error"] = f"{type(e).__name__}: {e}"[:2000]
+        row["seconds"] = round(time.perf_counter() - t0, 3)
+        print(json.dumps(row), flush=True)
+        if not ok and phase is phase_device:
+            break
+    print(json.dumps({"ok": ok, "device": device}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

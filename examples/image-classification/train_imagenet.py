@@ -91,8 +91,9 @@ def batches(args, ctxs):
 
 def main():
     args = parse()
-    ctxs = [mx.cpu(i) for i in range(args.num_devices)] \
-        if args.num_devices > 1 else [mx.current_context()]
+    # devices of the default accelerator (the CPU mesh when there is none)
+    ctxs = [mx.Context(mx.current_context().device_type, i)
+            for i in range(args.num_devices)]
     net = getattr(vision, args.model)()
     net.initialize(init=mx.init.Xavier(), ctx=ctxs)
     if args.dtype == "bfloat16":

@@ -28,6 +28,20 @@ from . import _distributed
 
 _distributed.init_from_env()
 
+# Persistent compile cache, configured here and nowhere else.  jax reads
+# JAX_COMPILATION_CACHE_DIR itself; without it the cache lives in the
+# checkout, at a path that never moves between runs (a directory named
+# after a pid, a time or a temp file would never hit).  A process held to
+# the CPU (the tests) gets none: jaxlib 0.9.0's XLA:CPU loader logs a
+# machine-feature mismatch at error level on every hit, and a CPU compile
+# is not what costs minutes.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR") and \
+        _jax.config.jax_platforms != "cpu":
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+
 # MXNet float32 ops compute in true float32 (CUDA/MKL kernels); XLA's
 # "fastest" default would silently downcast matmul/conv inputs to bf16 on
 # TPU.  Half-precision speed is opt-in via bf16 arrays / amp, as in the

@@ -46,17 +46,6 @@ def init_from_env():
         return False
     import jax
 
-    # Cross-process collectives on the host platform need an explicit
-    # transport on the pinned jax line (the default CPU client rejects
-    # multiprocess programs with INVALID_ARGUMENT); gloo ships in jaxlib.
-    # Must be set before the first backend creation, which is why it
-    # lives here in the pre-backend bootstrap.
-    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # mxlint: disable=swallowed-exception -- probing for an older-jax config flag; on newer jax gloo is already the default and the flag is gone
-            pass
-
     try:
         jax.distributed.initialize(coordinator_address=spec[0],
                                    num_processes=spec[1],

@@ -42,7 +42,7 @@ import json
 import re
 
 __all__ = [
-    "PEAKS", "CONTRACTS", "SCHEMA",
+    "PEAKS", "DEVICE_KINDS", "peaks_key", "CONTRACTS", "SCHEMA",
     "harvest_cost_analysis", "compiled_cost_summary",
     "per_instruction_costs", "parse_op_name", "bucket_costs",
     "classify_bound", "build_census", "evaluate_contract",
@@ -58,11 +58,31 @@ SCHEMA = "mxtpu-layer-census-v1"
 #: roofline of its own, so cost-model-only runs classify against the
 #: *target* chip (default v5e) — the census models what the chip would
 #: be bound by, not what the host happens to do.
+#: ``flops`` and ``bw`` are the published per-chip figures (Google Cloud
+#: documentation, "TPU v5e" and "TPU v4" system architecture pages).
 PEAKS = {
     "tpu-v5e": {"flops": 197e12, "bw": 819e9, "launch_s": 2e-6},
     "tpu-v4": {"flops": 275e12, "bw": 1228e9, "launch_s": 2e-6},
 }
 DEFAULT_DEVICE = "tpu-v5e"
+
+#: ``jax.Device.device_kind`` -> PEAKS key: the one place a device's own
+#: name meets the table.  A v5e calls itself "TPU v5 lite" (read on the
+#: chip, PR 21).
+DEVICE_KINDS = {"TPU v5 lite": "tpu-v5e", "TPU v4": "tpu-v4"}
+
+
+def peaks_key(device_kind):
+    """The PEAKS key of a device by the name it reports.  A device the
+    table does not know is an error: a utilization against another chip's
+    peak is a wrong number, not an estimate."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_KINDS)}); add its row to census.PEAKS with "
+            "the source of the figures") from None
 
 
 # --------------------------------------------------------------------------
@@ -250,6 +270,7 @@ def _parse_computations(hlo_text):
     comps, entry, fused = {}, None, set()
     applied = set()           # reduce/scatter reducers: modeled at caller
     current = None
+    result_of = {}            # instruction name -> its result shape text
     for line in hlo_text.splitlines():
         if "= " not in line and "{" in line:
             m = _COMP_RE.match(line.strip())
@@ -266,6 +287,14 @@ def _parse_computations(hlo_text):
             m.group(1), m.group(2), m.group(3), m.group(4),
             line[m.end():])
         operands, attrs = _split_operands(rest)
+        result_of[name] = result
+        if opcode not in ("parameter", "constant") and \
+                not _SHAPE_RE.search(operands):
+            # XLA prints operands by name alone (jax 0.9.0); the cost
+            # rules read operand shapes, so put each definition's back
+            operands = ", ".join(
+                f"{result_of.get(n, '')} %{n}"
+                for n in _OPERAND_NAME_RE.findall(operands))
         op_name = ""
         mm = _OPNAME_RE.search(attrs)
         if mm:

@@ -457,7 +457,14 @@ class HybridBlock(Block):
         if self._active and not kwargs:
             for hook in self._forward_pre_hooks:
                 hook(self, args)
-            out = self._call_cached(*args)
+            # as Block.__call__: multi-device parameters (and the aux
+            # write-back) resolve their copy through current_context()
+            in_ctx = _first_ctx(args)
+            if in_ctx is not None and in_ctx != current_context():
+                with in_ctx:
+                    out = self._call_cached(*args)
+            else:
+                out = self._call_cached(*args)
             for hook in self._forward_hooks:
                 hook(self, args, out)
             return out

@@ -1,7 +1,7 @@
 """Device-side augmentation: the fused-step prologue for image input.
 
-The compact-bytes contract (see benchmark/IO_ANALYSIS.md): pixels cross
-the host->device wire exactly once, as uint8 NHWC, and EVERYTHING
+The compact-bytes contract: pixels cross the host->device wire exactly
+once, as uint8 NHWC, and EVERYTHING
 float-valued happens on the chip where XLA fuses it into the first conv
 — normalization, the NCHW transpose, and (new) train-time random
 crop/flip.  The host ships the pre-crop canvas (e.g. 256x256) and the

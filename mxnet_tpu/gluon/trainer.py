@@ -361,8 +361,7 @@ class Trainer:
             wds.append(optimizer._get_wd(i))
             ts.append(optimizer._index_update_count[i])
         # ship per-param scalars as three packed arrays: one host->device
-        # transfer each, not 3*n_params tiny ones (they cross an RPC link
-        # when the chip is remote)
+        # transfer each, not 3*n_params tiny ones
         import numpy as onp
         lrs = jnp.asarray(onp.asarray(lrs, onp.float32))
         wds = jnp.asarray(onp.asarray(wds, onp.float32))

@@ -111,8 +111,6 @@ def _allreduce_fn(devices, shape, dtype, integrity=False):
     the same launch (`_integrity_sideband`) — 2 all-reduce ops in the
     HLO (payload psum + digest pmax), still one launch per bucket.
     """
-    from .._compat import shard_map
-
     mesh = Mesh(onp.asarray(devices), ("dev",))
     sharding = NamedSharding(mesh, P("dev"))
 
@@ -121,16 +119,16 @@ def _allreduce_fn(devices, shape, dtype, integrity=False):
             total = jax.lax.psum(x, "dev")
             return _integrity_sideband(total, f)
 
-        reduce_local = shard_map(
-            local, mesh, in_specs=(P("dev"), P("dev")),
+        reduce_local = jax.shard_map(
+            local, mesh=mesh, in_specs=(P("dev"), P("dev")),
             out_specs=(P("dev"), P("dev")))
         allreduce = jax.jit(reduce_local,
                             in_shardings=(sharding, sharding),
                             out_shardings=(sharding, sharding))
         return allreduce, sharding, mesh
 
-    reduce_local = shard_map(
-        lambda x: jax.lax.psum(x, "dev"), mesh,
+    reduce_local = jax.shard_map(
+        lambda x: jax.lax.psum(x, "dev"), mesh=mesh,
         in_specs=P("dev"), out_specs=P("dev"))
     allreduce = jax.jit(reduce_local,
                         in_shardings=sharding, out_shardings=sharding)
@@ -144,8 +142,6 @@ def _compressed_allreduce_fn(devices, shape, out_dtype, threshold):
     and each device rescales its own shard by the threshold — the same
     sharded shard_map+psum shape as `_allreduce_fn`, no hub device
     (round-3 verdict weak #5)."""
-    from .._compat import shard_map
-
     mesh = Mesh(onp.asarray(devices), ("dev",))
     sharding = NamedSharding(mesh, P("dev"))
     n_dev = len(devices)
@@ -159,8 +155,8 @@ def _compressed_allreduce_fn(devices, shape, out_dtype, threshold):
         total = jax.lax.psum(lvl.astype(acc), "dev")
         return total.astype(out_dtype) * out_dtype.type(threshold)
 
-    reduce_local = shard_map(local, mesh, in_specs=P("dev"),
-                             out_specs=P("dev"))
+    reduce_local = jax.shard_map(local, mesh=mesh, in_specs=P("dev"),
+                                 out_specs=P("dev"))
     allreduce = jax.jit(reduce_local, in_shardings=sharding,
                         out_shardings=sharding)
     return allreduce, sharding, mesh
@@ -229,10 +225,8 @@ def qblock_size():
 
 
 def _fp8_wire_dtype():
-    """The fp8 wire dtype when the pinned toolchain ships one, else
-    None (``set_gradient_compression('fp8')`` then refuses loudly)."""
-    return getattr(jnp, "float8_e4m3fn", None) or \
-        getattr(jnp, "float8_e4m3", None)
+    """The fp8 wire dtype of ``set_gradient_compression('fp8')``."""
+    return jnp.float8_e4m3fn
 
 
 def _blockwise_qparams(qtype, n_dev):
@@ -347,8 +341,6 @@ def _blockwise_allreduce_fn(devices, numel, dtype, qtype, block,
     violation output, and a 3rd all-reduce op in the HLO (scale pmax +
     payload psum + digest pmax — the declared integrity-mode
     contract)."""
-    from .._compat import shard_map
-
     mesh = Mesh(onp.asarray(devices), ("dev",))
     sharding = NamedSharding(mesh, P("dev"))
     body = _blockwise_shard_body(numel, onp.dtype(dtype), qtype, block,
@@ -359,13 +351,13 @@ def _blockwise_allreduce_fn(devices, numel, dtype, qtype, block,
             out, viol = _integrity_sideband(out, f)
             return out, new_res, tok_out, viol
 
-        fn = shard_map(body_i, mesh,
-                       in_specs=(P("dev"),) * 4, out_specs=(P("dev"),) * 4)
+        fn = jax.shard_map(body_i, mesh=mesh, in_specs=(P("dev"),) * 4,
+                           out_specs=(P("dev"),) * 4)
         allreduce = jax.jit(fn, in_shardings=(sharding,) * 4,
                             out_shardings=(sharding,) * 4)
         return allreduce, sharding, mesh
-    fn = shard_map(body, mesh, in_specs=(P("dev"), P("dev"), P("dev")),
-                   out_specs=(P("dev"), P("dev"), P("dev")))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("dev"),) * 3,
+                       out_specs=(P("dev"),) * 3)
     allreduce = jax.jit(fn, in_shardings=(sharding, sharding, sharding),
                         out_shardings=(sharding, sharding, sharding))
     return allreduce, sharding, mesh
@@ -458,15 +450,13 @@ class TPUICIStore(KVStoreBase):
             from jax._src import distributed
             return distributed.global_state.client
         except (ImportError, AttributeError):
-            # private-module layout drift across jax lines, or
-            # jax.distributed never initialized: no coordination KV
+            # jax.distributed has no public handle on its KV client; a
+            # moved private module means no coordination KV
             return None
 
     @staticmethod
     def _kv_try_get(client, key):
-        """Non-blocking KV read -> value or None.  The pinned jax line's
-        client has no ``key_value_try_get`` (added later), only the
-        blocking get — a short timeout emulates try-get there.
+        """Non-blocking KV read -> value or None.
 
         Transient coordination faults (TimeoutError/ConnectionError —
         a flapping coordinator, an injected ``kvstore.kv`` fault) are
@@ -474,22 +464,18 @@ class TPUICIStore(KVStoreBase):
         (``MXNET_KVSTORE_RETRIES``); each retry ticks
         ``mxtpu_kvstore_retries_total`` and a retry that then succeeds
         ticks ``mxtpu_faults_recovered_total``.  Anything else (most
-        commonly "key absent", which the pinned line reports as an
-        error) maps to None without burning the retry budget."""
+        commonly "key absent", which the client reports as an error)
+        maps to None without burning the retry budget."""
         from ..resilience import faultline as _faultline
         from ..resilience.policies import retry_transient
 
-        try_get = getattr(client, "key_value_try_get", None)
-
         def attempt():
             _faultline.check("kvstore.kv")
-            if try_get is not None:
-                return try_get(key)
-            return client.blocking_key_value_get(key, 200)  # ms
+            return client.key_value_try_get(key)
 
         try:
             out = retry_transient(attempt, site="kvstore.kv")
-        # mxlint: disable=swallowed-exception -- absent-key probes are the normal case on the pinned jax line (blocking get raises NOT_FOUND); after the transient retry budget, unreachable and absent both mean "no stamp"
+        # mxlint: disable=swallowed-exception -- absent-key probes are the normal case (try_get raises NOT_FOUND); after the transient retry budget, unreachable and absent both mean "no stamp"
         except Exception:
             return None
         if isinstance(out, str):
@@ -599,7 +585,7 @@ class TPUICIStore(KVStoreBase):
     # The sentinel's StragglerPolicy needs every rank's per-step wall
     # time; each rank stamps its own next to its heartbeat in the same
     # coordination KV.  Writes are delete+set like the heartbeat (the
-    # pinned jax line's KV is write-once per key).
+    # coordination KV is write-once per key).
 
     def record_steptime(self, seconds):
         """Stamp this rank's last step wall time (``mxtpu/steptime/<rank>``)

@@ -30,6 +30,7 @@ from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
 from .. import numpy as np
 from .. import numpy_extension as npx
+from ..context import on_tpu
 from ..parallel.mesh import PartitionSpec
 
 __all__ = [
@@ -53,13 +54,6 @@ __all__ = [
 # 2048 (0.62 vs 1.16 ms) — so one pair of constants serves both.
 FLASH_AUTO_MIN_T = 2048           # fwd-only (inference) crossover
 FLASH_AUTO_MIN_T_TRAINING = 1024  # fwd+bwd crossover
-
-
-def _on_tpu():
-    """auto-flash only applies on TPU: off-TPU the Pallas kernel runs in
-    interpret mode (orders of magnitude slower than dense XLA)."""
-    import jax
-    return jax.default_backend() == "tpu"
 
 
 def _flash_shape_ok(t):
@@ -177,7 +171,9 @@ class MultiHeadAttention(HybridBlock):
             # in-kernel (round 6); only a full (B, T, S) attention mask
             # forces the dense path
             mask_ok = mask is None or getattr(mask, "ndim", None) == 2
-            return (_on_tpu() and mask_ok and
+            # TPU only: elsewhere the kernel runs interpreted, orders of
+            # magnitude slower than dense XLA
+            return (on_tpu() and mask_ok and
                     t >= min_t and _flash_shape_ok(t))
         return bool(self._use_flash)
 
@@ -200,7 +196,7 @@ class MultiHeadAttention(HybridBlock):
             # itself is considered passed (sp is chosen because T is long)
             t_local = t // self._sp_mesh.shape[self._sp_axis]
             flash = (self._use_flash is True or
-                     (self._use_flash == "auto" and _on_tpu() and
+                     (self._use_flash == "auto" and on_tpu() and
                       _flash_shape_ok(t_local)))
             out = ring_attention(
                 q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),

@@ -127,16 +127,9 @@ class NDArray:
     def wait_to_read(self):
         """Block until the buffer is defined (reference ``WaitToRead``);
         asynchronous execution errors are raised here, matching the
-        reference's contract (`src/engine/threaded_engine.h:461-498`).
-
-        A one-element host readback backs the wait: tunneled/remote
-        backends ack ``block_until_ready`` without waiting, but a value
-        fetch cannot complete before the producing computation has."""
+        reference's contract (`src/engine/threaded_engine.h:461-498`)."""
         if isinstance(self._data, jax.Array):
             self._data.block_until_ready()
-            probe = self._data[(0,) * self._data.ndim] \
-                if self._data.size else self._data
-            onp.asarray(probe)
         return self
 
     wait_to_write = wait_to_read
@@ -480,8 +473,8 @@ class NDArray:
                 # STATIC slice + concat along each partial axis, value
                 # broadcast at the leaf: every op here is probed safe on
                 # >2^31-element operands, whereas dynamic_update_slice
-                # (the obvious lowering) segfaults on them on this
-                # toolchain (jax 0.4.37 CPU) — hence this shape
+                # (the obvious lowering) segfaulted on them when this
+                # was written (XLA CPU) — hence this shape
                 for ax, (lo, n) in enumerate(zip(sts, blk)):
                     if lo == 0 and n == x.shape[ax]:
                         continue
@@ -827,20 +820,15 @@ def waitall():
     """Drain all pending device work (reference `mx.nd.waitall`,
     `python/mxnet/ndarray/ndarray.py:231`).
 
-    PjRt executes per-device work in submission order, so a host READBACK of
-    a freshly enqueued computation drains that device's queue.  The readback
-    (not ``block_until_ready``) is load-bearing: tunneled/remote backends ack
-    ``block_until_ready`` immediately, but a value fetch cannot complete
-    before everything queued ahead of it has executed.
+    jax has no wait-for-everything call, so this is an ordered drain: a
+    device runs the programs of one process in the order they were
+    enqueued, and a scalar program enqueued now on each local device is
+    ready only after all that was queued before it.  `chip_smoke.py` holds
+    this to the TPU backend on every run (a step timed to
+    ``block_until_ready`` of its outputs and to ``waitall()`` must agree).
+    A deferred execution error (OOM, kernel failure) surfaces here, as the
+    reference rethrows at WaitForAll.
     """
-    for d in jax.devices():
-        try:
-            with jax.default_device(d):
-                onp.asarray(jnp.zeros((), onp.float32) + 0)
-        except jax.errors.JaxRuntimeError:
-            # a deferred execution error (OOM, kernel failure) surfacing at
-            # the drain point — the reference rethrows at WaitForAll too
-            raise
-        # mxlint: disable=swallowed-exception -- best-effort wait on a backend without the alloc API; real execution errors re-raise above
-        except Exception:  # pragma: no cover - backend without alloc
-            pass
+    for d in jax.local_devices():
+        with jax.default_device(d):
+            (jnp.zeros((), onp.float32) + 0).block_until_ready()

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as onp
 
+from .. import context as _context
+
 # Dropout RNG implementation, read ONCE at import (ADVICE r5): the value
 # is consulted inside traced dropout code, so a later env change could
 # never reach already-jitted executables — reading it per-call only made
@@ -285,7 +287,7 @@ def _bn_train_bwd(momentum, eps, axis, res, cts):
     through the naive form reads the activation twice more, and even
     split sums read it twice (see `_bn_bwd_sums`).  On a TPU backend
     the reduction epilogue runs as the tuned Pallas kernel
-    (`bn_bwd_reduce_pallas`, autotune kernel ``bn_bwd_epilogue``)."""
+    (`_bn_bwd_sums_pallas`, autotune kernel ``bn_bwd_epilogue``)."""
     data, gamma, mean, inv = res
     dy, d_mm, d_mv = cts
     red_axes = tuple(i for i in range(data.ndim) if i != axis)
@@ -297,11 +299,9 @@ def _bn_train_bwd(momentum, eps, axis, res, cts):
     dyf = dy.astype(cdt)
     xhat = (data.astype(cdt) - mean.reshape(shape)) * \
         inv.reshape(shape)
-    if jax.default_backend() == "tpu" and cdt == jnp.float32:
-        perm = red_axes + (axis,)          # channel-minor (M, C) view
-        dy2 = dyf.transpose(perm).reshape(n, -1)
-        xh2 = xhat.transpose(perm).reshape(n, -1)
-        sum_dy, sum_dy_xhat = bn_bwd_reduce_pallas(dy2, xh2)
+    if _context.on_tpu() and cdt == jnp.float32:
+        sum_dy, sum_dy_xhat = _bn_bwd_sums_pallas(dyf, xhat, red_axes,
+                                                  axis, n)
     else:
         sum_dy, sum_dy_xhat = _bn_bwd_sums(dyf, xhat, red_axes)
     a = (gamma.astype(cdt) * inv).reshape(shape)
@@ -381,6 +381,35 @@ def _bn_reduce_call(dy, xh, tm, tn, interpret):
     return s[0], ss[0]
 
 
+def _bn_bwd_sums_pallas(dyf, xhat, red_axes, axis, m):
+    """`_bn_bwd_sums` through `bn_bwd_reduce_pallas` over the
+    channel-minor (M, C) view of ``m`` rows.  Traced inside a
+    `parallel.mesh_scope` (a mesh-sharded `FusedTrainStep`) the kernel
+    runs once per device on its rows and the partial sums are `psum`-ed
+    over the batch axes — BatchNorm's sums are over the global batch —
+    because XLA cannot partition a Mosaic call by itself.  Rows that do
+    not divide over those axes take the XLA form."""
+    from ..parallel import mesh as _mesh
+    mesh, batch_axes = _mesh.current_mesh(), _mesh.current_batch_axes()
+    if m % _mesh.axes_size(mesh, batch_axes):
+        return _bn_bwd_sums(dyf, xhat, red_axes)
+    perm = red_axes + (axis,)
+    dy2 = dyf.transpose(perm).reshape(m, -1)
+    xh2 = xhat.transpose(perm).reshape(m, -1)
+    if mesh is None:
+        return bn_bwd_reduce_pallas(dy2, xh2)
+
+    def local(dy2, xh2):
+        sums = bn_bwd_reduce_pallas(dy2, xh2)
+        return tuple(lax.psum(s, batch_axes) for s in sums) \
+            if batch_axes else sums
+
+    rows = _mesh.PartitionSpec(batch_axes or None, None)
+    return _mesh.shard_kernel(local, in_specs=(rows, rows),
+                              out_specs=(_mesh.PartitionSpec(),) * 2)(
+                                  dy2, xh2)
+
+
 def bn_bwd_reduce_pallas(dy, xhat, tm=None, tn=None, interpret=None):
     """Tuned Pallas form of `_bn_bwd_sums` over a channel-minor (M, N)
     view: returns (sum(dy, 0), sum(dy*xhat, 0)) in f32.  Tile targets
@@ -399,8 +428,7 @@ def bn_bwd_reduce_pallas(dy, xhat, tm=None, tn=None, interpret=None):
     from .stem import _fit_tile
     tm = _fit_tile(m, tm)
     tn = _fit_tile(n, tn)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+    interp = (not _context.on_tpu()) if interpret is None else interpret
     return _bn_reduce_call(dy, xhat, tm, tn, interp)
 
 

@@ -60,6 +60,7 @@ import numpy as onp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import context as _context
 from .invoke import invoke
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
@@ -292,11 +293,8 @@ def _cq_factory(block_q, block_k, causal, masked, nh, nq):
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct matching ``like``'s mesh-axis variance: under
     shard_map (ring attention) `check_vma` requires pallas outputs to
-    declare how they vary across mesh axes.  On jax lines predating the
-    vma type system (no `jax.typeof`, pinned 0.4.x) there is nothing to
-    declare — a plain struct is correct."""
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    declare how they vary across mesh axes."""
+    vma = getattr(jax.typeof(like), "vma", None)
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -329,8 +327,7 @@ def _resolve(qd, block_q, block_k, scale, interpret):
             f"block sizes ({bq}, {bk}) must divide sequence length {t}; "
             "pad and mask upstream")
     sc = d ** -0.5 if scale is None else scale
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+    interp = (not _context.on_tpu()) if interpret is None else interpret
     return bq, bk, sc, interp
 
 
@@ -885,6 +882,46 @@ def _flash_lse_bwd(causal, scale, dropout, block_q, block_k, interpret,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def _flash_over_mesh(qd, kd, vd, mi, bi, seed, *static):
+    """`_flash`, as one launch per device when traced inside a
+    `parallel.mesh_scope`: XLA cannot partition a Mosaic call by itself, and
+    attention is independent per (batch, head).  Batch splits over the
+    scope's batch axes and heads over ``tp`` (where the Megatron
+    column-split Q/K/V already leaves them), each only when it divides;
+    anything else is gathered.  Each shard folds its index into the
+    dropout seed — the kernel counts (batch, head) from 0 on every
+    device, so one seed would repeat one mask across shards."""
+    from ..parallel import mesh as _mesh
+    mesh, P = _mesh.current_mesh(), _mesh.PartitionSpec
+    if mesh is None:
+        return _flash(qd, kd, vd, mi, bi, seed, *static)
+    b, h = qd.shape[:2]
+
+    def axes_dividing(n, names):
+        return tuple(names) if n % _mesh.axes_size(mesh, names) == 0 else ()
+
+    b_ax = axes_dividing(b, _mesh.current_batch_axes())
+    h_ax = axes_dividing(h, ("tp",) if "tp" in mesh.axis_names else ())
+    qkv = P(b_ax or None, h_ax or None, None, None)
+
+    def local(qd, kd, vd, mi, bi, seed):
+        if seed is not None:
+            shard = jnp.uint32(0)
+            for a in b_ax + h_ax:
+                shard = shard * jnp.uint32(mesh.shape[a]) + \
+                    jax.lax.axis_index(a).astype(jnp.uint32)
+            seed = seed.at[1].add(shard * jnp.uint32(_BH_FOLD))
+        return _flash(qd, kd, vd, mi, bi, seed, *static)
+
+    bias_spec = None if bi is None else P(
+        (b_ax or None) if bi.shape[0] == b else None,
+        (h_ax or None) if bi.shape[1] == h else None, None, None)
+    return _mesh.shard_kernel(
+        local, in_specs=(qkv, qkv, qkv, P(b_ax or None, None), bias_spec,
+                         P()),
+        out_specs=qkv)(qd, kd, vd, mi, bi, seed)
+
+
 def _entry(fn, q, k, v, causal, scale, block_q, block_k, interpret, mask,
            bias, dropout, key, name):
     from ..ndarray.ndarray import NDArray
@@ -967,6 +1004,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     (`models/transformer.FLASH_AUTO_MIN_T*`,
     benchmark/ATTENTION_ANALYSIS.md) and `parallel.ring_attention` when
     the sequence is sharded across chips.
+
+    Traced inside a `parallel.mesh_scope` (a mesh-sharded
+    `FusedTrainStep`) the kernel launches once per device over its share
+    of (batch, heads) — see `_flash_over_mesh`; `flash_attention_with_lse`
+    is the per-shard building block and never re-shards itself.
     """
-    return _entry(_flash, q, k, v, causal, scale, block_q, block_k,
-                  interpret, mask, bias, dropout, key, "flash_attention")
+    return _entry(_flash_over_mesh, q, k, v, causal, scale, block_q,
+                  block_k, interpret, mask, bias, dropout, key,
+                  "flash_attention")

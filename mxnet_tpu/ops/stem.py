@@ -46,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import context as _context
 from .legacy_math import space_to_depth
 
 __all__ = ["space_to_depth2", "fold_stem_kernel", "stem_conv",
@@ -118,8 +119,9 @@ def _fit_tile(dim, target):
 
 
 def _matmul_kernel(x_ref, w_ref, y_ref):
+    from .pallas_kernels import _prec
     y_ref[...] = jnp.dot(
-        x_ref[...], w_ref[...],
+        x_ref[...], w_ref[...], precision=_prec(x_ref.dtype),
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
@@ -187,8 +189,7 @@ def stem_conv_pallas(xs, wf, tm=None, tn=None, interpret=None):
     w2d = wf.reshape(c_out, k).T
     tm = _fit_tile(m, tm)
     tn = _fit_tile(c_out, tn)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
+    interp = (not _context.on_tpu()) if interpret is None else interpret
     out = _stem_matmul_vjp(flat, w2d, tm, tn, interp)
     return out.reshape(b, h2, w2, c_out).transpose(0, 3, 1, 2)
 
@@ -198,10 +199,12 @@ def stem_conv_auto(xs, w7):
     (C, C_in, 7, 7) weight and run the packed-input stem conv — the
     Pallas matmul form on a TPU backend, the pure-XLA conv elsewhere
     (what the census profiles; interpret-mode Pallas inside a lowered
-    HLO would hide the real cost model).  Gradients flow through the
-    fold to the 7x7 weight either way, so checkpoints keep the classic
-    layout."""
+    HLO would hide the real cost model) and in a program partitioned
+    over a mesh (`parallel.mesh_scope`), which cannot split a Mosaic
+    call.  Gradients flow through the fold to the 7x7 weight either way,
+    so checkpoints keep the classic layout."""
+    from ..parallel.mesh import current_mesh
     wf = fold_stem_kernel(w7)
-    if jax.default_backend() == "tpu":
+    if _context.on_tpu() and current_mesh() is None:
         return stem_conv_pallas(xs, wf)
     return stem_conv(xs, wf)

@@ -72,17 +72,47 @@ def current_mesh():
     return getattr(_state, "mesh", None)
 
 
+def current_batch_axes():
+    """Mesh axes the leading (batch) dim is sharded over in the program
+    being traced under :class:`mesh_scope`; ``()`` outside one."""
+    return getattr(_state, "batch_axes", ())
+
+
 class mesh_scope:
-    def __init__(self, mesh):
+    """Trace-time declaration that the code inside becomes ONE program
+    partitioned over ``mesh``, batch dim over ``batch_axes``
+    (`FusedTrainStep` opens it around its forward+backward trace).  XLA
+    cannot partition a Pallas (Mosaic) call by itself, so a kernel traced
+    inside reads the scope and goes through :func:`shard_kernel`."""
+
+    def __init__(self, mesh, batch_axes=()):
         self.mesh = mesh
+        self.batch_axes = tuple(batch_axes)
 
     def __enter__(self):
-        self._prev = getattr(_state, "mesh", None)
-        _state.mesh = self.mesh
+        self._prev = (current_mesh(), current_batch_axes())
+        _state.mesh, _state.batch_axes = self.mesh, self.batch_axes
         return self.mesh
 
     def __exit__(self, *_exc):
-        _state.mesh = self._prev
+        _state.mesh, _state.batch_axes = self._prev
+
+
+def axes_size(mesh, names):
+    """Number of shards a dim split over mesh axes ``names`` has."""
+    size = 1
+    for name in names:
+        size *= mesh.shape[name]
+    return size
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """``fn`` as one launch per device of the current mesh.  ``check_vma``
+    is off because a pallas_call's outputs declare no variance over mesh
+    axes; an ``out_specs`` that leaves an axis out is therefore a promise
+    that ``fn`` made the value equal along it (e.g. by a ``psum``)."""
+    return jax.shard_map(fn, mesh=current_mesh(), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def data_sharding(mesh, axis_name="dp"):

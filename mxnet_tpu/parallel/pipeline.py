@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .._compat import pcast, shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -53,7 +51,7 @@ def _pipeline_local(params, x_mb, stage_fn, axis_name, num_microbatches):
     cur0 = jnp.zeros_like(x_mb[0])
     # fresh carries are device-invariant; mark them varying over the stage
     # axis so scan carry types match the per-stage outputs
-    outputs0, cur0 = (pcast(a, (axis_name,), to="varying")
+    outputs0, cur0 = (lax.pcast(a, (axis_name,), to="varying")
                       for a in (outputs0, cur0))
     (outputs, _), _ = lax.scan(step, (outputs0, cur0), jnp.arange(steps))
     # broadcast the final outputs from the last stage to every stage so the
@@ -86,7 +84,7 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, axis_name="pp",
 
     pspec = jax.tree_util.tree_map(
         lambda _a: P(axis_name), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=axis_name, num_microbatches=m),
         mesh=mesh,

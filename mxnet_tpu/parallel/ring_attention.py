@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .._compat import pcast, shard_map
-
 __all__ = ["ring_attention", "ring_attention_local"]
 
 
@@ -101,7 +99,7 @@ def ring_attention_local(q, k, v, axis_name, causal=False, scale=None,
     # ring axis (and the batch axis, when sharded) so the scan carry types
     # match the rotating k/v blocks
     vary = (axis_name,) + tuple(extra_vary_axes)
-    m0, l0, acc0 = (pcast(x, vary, to="varying")
+    m0, l0, acc0 = (lax.pcast(x, vary, to="varying")
                     for x in (m0, l0, acc0))
     (m, l, acc, _k, _v, _m), _ = lax.scan(
         step, (m0, l0, acc0, k, v, mask), jnp.arange(axis_size))
@@ -218,7 +216,7 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False, scale=None,
         local = body
         in_specs = (spec, spec, spec)
         args = (q, k, v)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,

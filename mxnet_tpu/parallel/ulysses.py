@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .._compat import shard_map
-
 __all__ = ["ulysses_attention", "ulysses_attention_local"]
 
 
@@ -76,7 +74,7 @@ def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=False,
             f"axis ({sp}); use ring_attention for this config")
 
     spec = P(batch_axis, None, axis_name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention_local, axis_name=axis_name,
                           causal=causal, scale=scale),
         mesh=mesh,

@@ -25,6 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .context import on_tpu
 from .ops.invoke import invoke
 
 __all__ = ["PallasModule", "PallasKernel"]
@@ -32,7 +33,7 @@ __all__ = ["PallasModule", "PallasKernel"]
 
 def _interpret_default():
     # interpret mode everywhere but real TPU hardware
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 class PallasKernel:

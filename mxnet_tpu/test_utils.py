@@ -7,6 +7,7 @@ finite differences vs autograd (:1043), `check_consistency` cross-context
 """
 from __future__ import annotations
 
+import jax
 import numpy as onp
 
 from .context import Context, current_context, cpu
@@ -120,8 +121,6 @@ def check_numeric_gradient(f, inputs, eps=1e-3, rtol=1e-2, atol=1e-3,
     are NDArrays.  Compares d(sum(f))/dx computed by the tape against central
     differences.
     """
-    from ._compat import enable_x64
-
     inputs = list(inputs)
     for x in inputs:
         x.attach_grad()
@@ -136,7 +135,7 @@ def check_numeric_gradient(f, inputs, eps=1e-3, rtol=1e-2, atol=1e-3,
     # amplify past any reasonable tolerance.
     originals = [x._data for x in inputs]
     try:
-        with enable_x64():
+        with jax.enable_x64():
             for x in inputs:
                 # promote real-valued inputs (incl. bf16, numpy kind 'V');
                 # int/bool/unsigned index inputs keep their dtype

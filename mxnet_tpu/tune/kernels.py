@@ -57,15 +57,15 @@ def tag_dtype(tag):
 
 
 def device_kind():
-    """Real device kind on a TPU backend; the census DEFAULT_DEVICE
-    everywhere else (the CPU mesh emulates a v5e pod throughout this
-    repo — hloscan contracts, census artifacts, bench JSONs — so the
-    committed v5e entries are live on it)."""
+    """The census PEAKS key of the device on a TPU backend (an unknown
+    chip is an error); the census DEFAULT_DEVICE everywhere else (the CPU
+    mesh models a v5e pod throughout this repo — hloscan contracts,
+    census artifacts — so the committed v5e entries are live on it)."""
     import jax
-    from ..analysis.census import DEFAULT_DEVICE
+    from ..analysis.census import DEFAULT_DEVICE, peaks_key
     dev = jax.devices()[0]
     if dev.platform == "tpu":
-        return dev.device_kind.replace(" ", "-").lower()
+        return peaks_key(dev.device_kind)
     return DEFAULT_DEVICE
 
 

@@ -504,7 +504,7 @@ def test_mha_flash_dispatch_path_assertion(monkeypatch):
     from mxnet_tpu import autograd
     from mxnet_tpu.models import transformer as tr
 
-    monkeypatch.setattr(tr, "_on_tpu", lambda: True)
+    monkeypatch.setattr(tr, "on_tpu", lambda: True)
     # shrink the crossover so the interpret-mode kernel stays test-sized
     monkeypatch.setattr(tr, "FLASH_AUTO_MIN_T_TRAINING", 32)
     calls = []
@@ -512,7 +512,7 @@ def test_mha_flash_dispatch_path_assertion(monkeypatch):
 
     def spy(*args, **kwargs):
         calls.append(kwargs)
-        kwargs["interpret"] = True  # _on_tpu is faked; stay runnable
+        kwargs["interpret"] = True  # on_tpu is faked; stay runnable
         return real(*args, **kwargs)
 
     monkeypatch.setattr(tr.npx, "flash_attention", spy)
@@ -545,7 +545,7 @@ def test_mha_auto_flash_policy(monkeypatch):
     assert mha._use_flash == "auto"
     # off-TPU (this CI): auto never picks the interpret-mode kernel
     assert not mha._flash_now(tr.FLASH_AUTO_MIN_T, None)
-    monkeypatch.setattr(tr, "_on_tpu", lambda: True)
+    monkeypatch.setattr(tr, "on_tpu", lambda: True)
     assert not mha._flash_now(tr.FLASH_AUTO_MIN_T - 128, None)
     assert mha._flash_now(tr.FLASH_AUTO_MIN_T, None)
     pad_mask = mx.np.ones((2, tr.FLASH_AUTO_MIN_T))
@@ -604,3 +604,51 @@ def test_hybridize_jit_cache_keys_on_backward():
     out.backward()
     assert (False, True) in mha._jit_cache
     assert x2.grad is not None
+
+
+@pytest.mark.parametrize("axes,heads_split", [({"dp": 4}, False),
+                                              ({"dp": 2, "tp": 2}, True)],
+                         ids=["dp4", "dp2.tp2"])
+def test_flash_under_mesh_scope_matches_unsharded(axes, heads_split):
+    """Traced inside a `mesh_scope` (a mesh-sharded FusedTrainStep) the
+    kernel launches once per device over its share of (batch, heads):
+    masked forward and gradients equal the one-device call's, and each
+    shard draws its own dropout mask at the asked keep-rate."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.parallel.mesh import make_mesh, mesh_scope
+
+    mesh = make_mesh(axes, devices=jax.devices()[:4])
+    q, k, v = (jnp.asarray(a) for a in _qkv(3, b=4, h=2, t=64, d=8))
+    mask = jnp.asarray(_prefix_mask([64, 40, 17, 5], 64))
+    spec = P("dp", "tp" if heads_split else None)
+    qs, ks, vs = (jax.device_put(a, NamedSharding(mesh, spec))
+                  for a in (q, k, v))
+    ms = jax.device_put(mask, NamedSharding(mesh, P("dp")))
+
+    def grads(q, k, v, mask, **kw):
+        return jax.value_and_grad(
+            lambda q, k, v: (flash_attention(
+                q, k, v, mask=mask, block_q=32, block_k=32, **kw) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    want = grads(q, k, v, mask)
+    with mesh_scope(mesh, ("dp",)):
+        got = jax.jit(grads)(qs, ks, vs, ms)
+        # one query batch-broadcast, v == 1: a row's output is its kept
+        # weight mass over keep (~1), and equal rows tell masks apart
+        same = jax.device_put(jnp.broadcast_to(q[:1], q.shape), qs.sharding)
+        dropped = jax.jit(lambda q, v: flash_attention(
+            q, q, v, dropout=0.5, key=jax.random.key(1),
+            block_q=32, block_k=32))(same, jnp.ones_like(vs))
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        assert onp.allclose(a, b, rtol=1e-5, atol=1e-5)
+    dropped = onp.asarray(dropped)[..., 0]
+    assert onp.isfinite(dropped).all()
+    assert abs(dropped.mean() - 1.0) < 0.05
+    # batch rows 0 and 2 are the first (batch, head) of two different
+    # shards: one seed for all shards would repeat one mask there
+    assert not onp.allclose(dropped[0], dropped[2])

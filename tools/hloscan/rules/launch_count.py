@@ -12,6 +12,15 @@ The contract pins it::
     "expected_collectives": {"all-reduce": 4}     # exact per-opcode
     "expected_collectives": 4                     # exact total
     "collective_free": true                       # zero collectives
+    "collectives_stage": "lowered"                # where to count
+
+Where to count: by default the optimized module, the only place the
+collectives exist that the SPMD partitioner derives from shardings (a
+`FusedTrainStep(mesh=...)`).  There XLA's combiner has already merged
+them, so the number is also the compiler's and moves with the toolchain.
+A program that spells its collectives itself (`shard_map` + `psum`, one
+per bucket) declares ``"collectives_stage": "lowered"`` and is counted
+before any XLA pass: that census is the bucket plan's own.
 
 Counting convention: *issues*, not instructions — a ``-start``/``-done``
 pair is one launch (the start is counted, the done is the same launch
@@ -37,7 +46,8 @@ class LaunchCount(Rule):
         collective_free = artifact.contract.get("collective_free")
         if expected is None and not collective_free:
             return
-        mod = artifact.best_module
+        stage = artifact.contract.get("collectives_stage")
+        mod = artifact.module(stage) if stage else artifact.best_module
         if mod is None:
             yield artifact.finding(
                 self.name, "no-module",

@@ -13,10 +13,13 @@ wired for `jax.distributed.initialize()`:
   JAX_NUM_PROCESSES         N
   JAX_PROCESS_ID            0..N-1
 
-On a real TPU pod each host runs one process and the TPU runtime supplies
-the topology; `--launcher local` is for CPU-mesh testing (each process gets
-a slice of virtual devices), mirroring how the reference tests dist kvstore
-with N local processes (`tests/nightly/test_distributed_training-gpu.sh`).
+On TPU hardware ONE process per host drives all of that host's chips (a
+chip belongs to one process at a time), so a single host needs no launcher
+at all.  `--launcher local` starts N processes on this host with no device
+partition between them: it is for the CPU mesh (each process gets a slice
+of virtual devices), mirroring how the reference tests dist kvstore with N
+local processes (`tests/nightly/test_distributed_training-gpu.sh`) — N
+local processes that each want the host's chips fail or hang.
 `--launcher ssh -H hostfile` drives a real multi-host cluster the way the
 reference's ssh launcher does: one peer process per host, env-wired over
 the ssh command line (see examples/distributed/README.md for the
