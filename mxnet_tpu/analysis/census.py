@@ -26,12 +26,11 @@ module is that census:
   BN-backward (VERDICT items 3/6) land as waived known-offenders so the
   census documents them instead of hiding them.
 
-On the virtual CPU mesh the census runs in **cost-model-only** mode:
-bound classes and ``mfu_sol`` (the shape-intrinsic speed-of-light MFU,
-``min(1, intensity/ridge)``) come from the model alone.  On real
-hardware, :func:`attach_timings` joins measured per-region seconds (the
-PR 2 profiler timeline / ``jax.profiler.TraceAnnotation`` regions) to
-produce achieved TF/s, GB/s and measured MFU.
+The census is **cost-model-only**: bound classes and ``mfu_sol`` (the
+shape-intrinsic speed-of-light MFU, ``min(1, intensity/ridge)``) come
+from the model alone; the measured fields of a row stay ``None``.  Times
+on the chip come from the benchmark's reduction of the profiler trace
+(``chipbench/trace.py``, ``PERF.md``).
 
 Like ``capture.py``, this module carries zero tooling dependency — the
 CLI/driver/baseline layers live in ``tools/layerscope``.
@@ -46,7 +45,7 @@ __all__ = [
     "harvest_cost_analysis", "compiled_cost_summary",
     "per_instruction_costs", "parse_op_name", "bucket_costs",
     "classify_bound", "build_census", "evaluate_contract",
-    "attach_timings", "timings_from_trace", "publish_metrics",
+    "publish_metrics",
     "census_entrypoint_names", "census_one", "layer_names",
 ]
 
@@ -496,8 +495,7 @@ def classify_bound(flops, byts, n_instr, peaks):
 def build_census(spec, device=DEFAULT_DEVICE):
     """Assemble the census artifact from an entry-point spec
     (``{"entry", "optimized", "cost_analysis", "layers", "contract",
-    "meta"}``).  Cost-model-only: measured fields stay ``None`` until
-    :func:`attach_timings` joins real region timings."""
+    "meta"}``).  Cost-model-only: measured fields stay ``None``."""
     peaks = PEAKS[device]
     # bf16/f16 programs charge MXU ops native-width (the host mesh
     # float-normalizes them to f32 — see per_instruction_costs)
@@ -555,63 +553,6 @@ def build_census(spec, device=DEFAULT_DEVICE):
         "meta": dict(spec.get("meta") or {}),
     }
     doc["findings"] = evaluate_contract(doc, doc["contract"])
-    return doc
-
-
-# --------------------------------------------------------------------------
-# measured-timings join (real hardware: PR 2 profiler timeline)
-# --------------------------------------------------------------------------
-def timings_from_trace(trace, layer_labels):
-    """Sum per-region seconds out of a chrome-trace dict (the profiler
-    timeline / ``jax.profiler.TraceAnnotation`` dump): complete events
-    whose name matches a census row label (``layer`` or
-    ``layer@phase``).  ``trace`` is the parsed JSON dict."""
-    wanted = set(layer_labels)
-    out = {}
-    for ev in trace.get("traceEvents", []):
-        name = ev.get("name")
-        if ev.get("ph") not in ("X", "B") or name not in wanted:
-            continue
-        out[name] = out.get(name, 0.0) + float(ev.get("dur", 0.0)) * 1e-6
-    return out
-
-
-def attach_timings(doc, region_seconds):
-    """Join measured per-region seconds onto a cost-model census.
-
-    ``region_seconds`` maps ``layer`` or ``layer@phase`` to seconds.  A
-    layer-level time splits across that layer's phases proportionally to
-    their modeled time.  Rows with a measurement gain achieved TF/s,
-    GB/s and measured MFU; ``pct_time`` re-normalizes over measured
-    rows; mode flips to ``measured``.  Contract floors re-evaluate
-    against measured MFU where present."""
-    peaks = doc["peaks"]
-    by_layer = {}
-    for row in doc["rows"]:
-        by_layer.setdefault(row["layer"], []).append(row)
-    for row in doc["rows"]:
-        t = region_seconds.get(f"{row['layer']}@{row['phase']}")
-        if t is None and row["layer"] in region_seconds:
-            group = by_layer[row["layer"]]
-            total = sum(r["modeled_time_s"] for r in group) or 1.0
-            t = (region_seconds[row["layer"]] *
-                 row["modeled_time_s"] / total)
-        if t is None or t <= 0:
-            continue
-        row["measured_time_s"] = t
-        row["tf_per_s"] = row["flops"] / t / 1e12
-        row["gb_per_s"] = row["bytes"] / t / 1e9
-        row["mfu"] = min(1.0, row["flops"] / t / peaks["flops"])
-    measured = [r for r in doc["rows"] if r["measured_time_s"]]
-    if measured:
-        doc["mode"] = "measured"
-        total = sum(r["measured_time_s"] for r in measured)
-        for r in doc["rows"]:
-            r["pct_time"] = (round(100.0 * r["measured_time_s"] / total, 3)
-                             if r["measured_time_s"] else 0.0)
-        doc["rows"].sort(key=lambda r: (-(r["measured_time_s"] or 0.0),
-                                        r["layer"], r["phase"]))
-        doc["findings"] = evaluate_contract(doc, doc["contract"])
     return doc
 
 

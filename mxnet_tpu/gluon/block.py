@@ -162,12 +162,18 @@ class Block:
     # -- lifecycle ---------------------------------------------------------
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
+        from .. import telemetry as _telemetry
         if init is None:
             init = _initializer.Uniform()
         params = self.collect_params()
-        for _name, param in params.items():
-            param.initialize(init=param.init, ctx=ctx, default_init=init,
-                             force_reinit=force_reinit)
+        # one span per call: the loop is flat over the whole tree, so no
+        # child's `initialize` runs inside it
+        with _telemetry.span("block.initialize", block=self.name,
+                             params=len(params)):
+            for _name, param in params.items():
+                param.initialize(init=param.init, ctx=ctx,
+                                 default_init=init,
+                                 force_reinit=force_reinit)
 
     def hybridize(self, active=True, **kwargs):
         for child in self._children.values():

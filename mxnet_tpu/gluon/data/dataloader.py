@@ -169,7 +169,11 @@ class DataLoader:
             try:
                 batch = next(inner)
             except StopIteration:
-                return        # exhausted probe: not a batch wait, discard
+                phase.cancel()   # exhausted probe: not a batch wait, discard
+                return
+            except BaseException:
+                phase.cancel()   # a failing worker: leave no span open
+                raise
             phase.__exit__(None, None, None)
             yield batch
 

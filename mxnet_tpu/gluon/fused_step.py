@@ -100,6 +100,7 @@ class FusedTrainStep:
         self._plist = None
         self._train_idx = None
         self._opt_index = None
+        self._n_steps = 0       # steps dispatched; the spans of one share it
 
     def _setup(self, args):
         block, trainer = self._block, self._trainer
@@ -293,6 +294,10 @@ class FusedTrainStep:
         invoked with — shared by :meth:`step` and the AOT capture
         methods (:meth:`trace` / :meth:`lower`), so what hloscan
         inspects is the very program the step dispatches."""
+        with _telemetry.span("fused_step.prepare"):
+            return self._call_args(args, batch_size)
+
+    def _call_args(self, args, batch_size):
         if self._plist is None:
             self._setup(args)
         trainer = self._trainer
@@ -386,15 +391,33 @@ class FusedTrainStep:
                 optimizer.clip_gradient, treedef_id)
 
     def step(self, *args, batch_size=1):
-        call_args = self._prepare(args, batch_size)
-        trainer, plist = self._trainer, self._plist
-        _telemetry.mark_step()
-        with _telemetry.step_phase("fused-step"):
-            outs, auxs, new_ws, new_states, finite = self._jit(*call_args)
-        _telemetry.watchdog().observe(
-            self._jit, name=f"FusedTrainStep[{type(self._block).__name__}]",
-            scope_root=self._block.name)
+        self._n_steps += 1
+        # the whole call is one span, and the `fused-step` phase histogram
+        # observes it; prepare and launch are its children, and what is
+        # left of it is the rebinding
+        with _telemetry.step_phase("fused-step", name="fused_step.step",
+                                   step=self._n_steps):
+            call_args = self._prepare(args, batch_size)
+            _telemetry.mark_step()
+            with _telemetry.span("fused_step.launch") as launch:
+                outs, auxs, new_ws, new_states, finite = \
+                    self._jit(*call_args)
+                # the watchdog's own reading of the jit's cache: true on
+                # the first compile too, which it does not count a retrace
+                launch.args["compiled"] = _telemetry.watchdog().observe(
+                    self._jit,
+                    name=f"FusedTrainStep[{type(self._block).__name__}]",
+                    scope_root=self._block.name) > 0
+            # the donated inputs' last references: dropped here they die
+            # as `_rebind` re-points their NDArrays, inside the step's
+            # span, and not as this frame unwinds, inside nobody's
+            del call_args
+            return self._rebind(outs, auxs, new_ws, new_states, finite)
 
+    def _rebind(self, outs, auxs, new_ws, new_states, finite):
+        """Point every parameter, state and auxiliary at what the step
+        returned, and wrap the outputs."""
+        trainer, plist = self._trainer, self._plist
         for j, k in enumerate(self._train_idx):
             plist[k].data()._rebind(new_ws[j])
         for i, nst in zip(self._opt_index, new_states):

@@ -7,12 +7,13 @@ N per-host dumps into one clock-skew-corrected pod timeline with a
 root-cause verdict (docs/OBSERVABILITY.md "Black box / postmortem").
 """
 from ..lockwitness import LockOrderViolation  # noqa: F401  (observability surface)
-from .flightrec import (FlightRecorder, SCHEMA_VERSION, configure,
+from .flightrec import (FlightRecorder, SCHEMA_VERSION, SpanList, configure,
                         default_recorder, dump, enabled, events,
-                        install_signal_handlers, record, reset,
-                        set_generation, set_rank, set_step, snapshot)
+                        install_signal_handlers, record, record_span, reset,
+                        set_generation, set_rank, set_step, snapshot, spans)
 
 __all__ = ["FlightRecorder", "LockOrderViolation", "SCHEMA_VERSION",
-           "configure", "default_recorder", "dump", "enabled", "events",
-           "install_signal_handlers", "record", "reset",
-           "set_generation", "set_rank", "set_step", "snapshot"]
+           "SpanList", "configure", "default_recorder", "dump", "enabled",
+           "events", "install_signal_handlers", "record", "record_span",
+           "reset", "set_generation", "set_rank", "set_step", "snapshot",
+           "spans"]

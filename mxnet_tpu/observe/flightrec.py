@@ -3,7 +3,10 @@ events behind every existing emitter.
 
 The recorder is a *sink*, not an instrumentation pass: the taps live in
 the subsystems that already observe the interesting transitions —
-``telemetry.spans`` (step phases, collectives), ``resilience.faultline``
+``telemetry.span`` (every timed region of the program is ONE event that
+holds the whole span — begin, end, parent, step — so the ring is also the
+program's span record from process start; ``spans()`` reads it back),
+``resilience.faultline``
 (injections), ``resilience.sentinel`` (straggler demotions, divergence
 trips), ``resilience.elastic`` (reshards, rollbacks, preempt resumes),
 ``resilience.checkpoint`` (save/restore outcomes), ``kvstore.tpu_ici``
@@ -44,11 +47,24 @@ import time
 
 from .. import env as _env
 
-__all__ = ["FlightRecorder", "record", "events", "snapshot", "dump",
+__all__ = ["FlightRecorder", "record", "record_span", "spans", "SpanList",
+           "events", "snapshot", "dump",
            "reset", "configure", "enabled", "set_rank", "set_generation",
            "set_step", "install_signal_handlers", "SCHEMA_VERSION"]
 
 SCHEMA_VERSION = 1
+
+# what `record_span` and `telemetry.span` put in a span event's payload
+# beside the span's own arguments
+_SPAN_KEYS = frozenset(("begin_ns", "end_ns", "seconds", "id", "parent", "step"))
+
+
+class SpanList(list):
+    """What `spans()` returns: the spans, and how many events the ring lost."""
+
+    def __init__(self, dropped=0):
+        super().__init__()
+        self.dropped = dropped
 
 
 class FlightRecorder:
@@ -77,11 +93,30 @@ class FlightRecorder:
         """Append one event; drops silently when disabled."""
         if not self._enabled:
             return
-        ev = (time.monotonic_ns(), time.time_ns(), self._rank,
-              self._generation, category, name, payload or None)
+        self._append((time.monotonic_ns(), time.time_ns(), self._rank,
+                      self._generation, category, name, payload or None))
+
+    def _append(self, ev):
         with self._lock:
             self._buf[self._n % self._cap] = ev
             self._n += 1
+
+    def record_span(self, category, name, begin_ns, end_ns=None, **payload):
+        """Append one event that is a whole span and return its end on the
+        ``mono_ns`` clock.  A span that closes now (``end_ns`` None) ends at
+        the stamp this event gets; one reported after the fact (an XLA
+        compile) carries its own ``end_ns``.  The end is returned with the
+        recorder off too: the caller's histogram and chrome event need it."""
+        now = time.monotonic_ns()
+        end = now if end_ns is None else end_ns
+        if self._enabled:
+            payload["begin_ns"] = begin_ns
+            payload["seconds"] = (end - begin_ns) * 1e-9
+            if end_ns is not None:
+                payload["end_ns"] = end_ns
+            self._append((now, time.time_ns(), self._rank, self._generation,
+                          category, name, payload))
+        return end
 
     # -- context ----------------------------------------------------------
 
@@ -108,14 +143,39 @@ class FlightRecorder:
 
     # -- snapshot / dump --------------------------------------------------
 
+    def _ordered(self):
+        n, cap = self._n, self._cap
+        if n <= cap:
+            return self._buf[:n]
+        i = n % cap
+        return self._buf[i:] + self._buf[:i]
+
     def events(self):
         """Events oldest-first (at most ``capacity``)."""
         with self._lock:
-            n, cap = self._n, self._cap
-            if n <= cap:
-                return [e for e in self._buf[:n]]
-            i = n % cap
-            return self._buf[i:] + self._buf[:i]
+            return self._ordered()
+
+    def spans(self, name=None):
+        """The span events still in the ring, oldest first, as dicts with
+        ``name``, ``cat``, ``begin_ns``, ``end_ns`` (both on the ``mono_ns``
+        clock), ``id``, ``parent``, ``step`` and ``args``; ``.dropped`` on
+        the list is the number of events of any kind the ring has
+        overwritten, so 0 means the record is whole since process start."""
+        with self._lock:
+            evs, dropped = self._ordered(), max(0, self._n - self._cap)
+        out = SpanList(dropped)
+        for mono, _wall, _rank, _gen, cat, ev_name, payload in evs:
+            if not payload or "begin_ns" not in payload or \
+                    (name is not None and ev_name != name):
+                continue
+            args = {k: v for k, v in payload.items() if k not in _SPAN_KEYS}
+            out.append({"name": ev_name, "cat": cat,
+                        "begin_ns": payload["begin_ns"],
+                        "end_ns": payload.get("end_ns", mono),
+                        "id": payload.get("id"),
+                        "parent": payload.get("parent"),
+                        "step": payload.get("step"), "args": args})
+        return out
 
     def snapshot(self, reason="on_demand"):
         """The dump payload as a dict, without touching disk."""
@@ -195,6 +255,14 @@ def default_recorder():
 
 def record(category, name, **payload):
     _recorder.record(category, name, **payload)
+
+
+def record_span(category, name, begin_ns, end_ns=None, **payload):
+    return _recorder.record_span(category, name, begin_ns, end_ns, **payload)
+
+
+def spans(name=None):
+    return _recorder.spans(name)
 
 
 def events():

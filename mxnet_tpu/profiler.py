@@ -27,11 +27,12 @@ _lock = threading.Lock()
 _config = {"filename": "profile.json", "xla_trace_dir": None}
 _running = False
 _events = []
-_t0 = time.perf_counter()
 
 
 def _now_us():
-    return (time.perf_counter() - _t0) * 1e6
+    """Microseconds on the flight recorder's clock (`time.monotonic_ns`), the
+    one `telemetry.span` stamps its chrome events from."""
+    return time.monotonic_ns() / 1e3
 
 
 def set_config(**kwargs):

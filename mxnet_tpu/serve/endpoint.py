@@ -558,8 +558,7 @@ class Endpoint:
         # step-trace span: a profiling dump shows each batch dispatch on
         # the same timeline as op events / step phases / collectives
         with _telemetry.span(f"serve/{self.name}/batch", cat="serve",
-                             args={"rows": rows, "bucket": bucket,
-                                   "requests": len(group)}):
+                             rows=rows, bucket=bucket, requests=len(group)):
             # one transient retry: a deadline miss on the transport gets
             # a second chance instead of failing the whole batch
             out = _retry_transient(model_call, site="serve.model_call",

@@ -6,16 +6,18 @@ The cross-cutting observability layer (docs/OBSERVABILITY.md):
   Prometheus-text and JSON exposition.  ``serve`` endpoints, the kvstore
   collectives, the Gluon ``Trainer`` step phases, and (while profiling)
   ``ops.invoke`` all publish into the default registry;
-* :mod:`.spans` — structured chrome-trace spans over the
-  :mod:`mxnet_tpu.profiler` emitter, so one ``profiler.dump()``
-  interleaves step phases, op events, collective timings, and serve batch
-  dispatches on a single timeline;
-* :mod:`.watchdog` — XLA compile counters via ``jax.monitoring`` plus
-  per-jitted-function retrace detection with steady-state warnings.
+* :mod:`.spans` — ``span``, the one way the program times a region: a
+  ``jax.profiler.TraceAnnotation``, one flight-recorder event that holds
+  the whole span (``observe.spans()``), and, while the profiler runs, a
+  chrome-trace event, so one ``profiler.dump()`` interleaves step phases,
+  op events, collective timings, and serve batch dispatches;
+* :mod:`.watchdog` — XLA compile counters and ``xla.*`` spans via
+  ``jax.monitoring`` plus per-jitted-function retrace detection with
+  steady-state warnings.
 
-Everything is off the hot path by default: the chrome-trace side is gated
-on the profiler running (no per-op Python work otherwise), and registry
-publications happen per step / collective / serve batch, never per op.
+Everything is off the hot path by default: spans and registry
+publications happen per step / collective / serve batch, never per op
+(per-op Python work happens only while the profiler runs).
 """
 from .registry import (
     MetricsRegistry, Counter, Gauge, Histogram, DEFAULT_BUCKETS,

@@ -1,53 +1,123 @@
-"""Structured step-trace spans layered on the profiler's chrome-trace
-emitter.
+"""The one way the program times a region: :class:`span`.
 
-A span is two things at once:
+A span is three things, all on one clock (``time.monotonic_ns``, the
+flight recorder's ``mono_ns``):
 
-* while the profiler runs, a chrome-trace complete event (`ph:"X"`) with a
-  structured category — ``step_phase`` / ``collective`` / ``serve`` — so
-  one ``profiler.dump()`` interleaves host step phases, per-op dispatches,
-  kvstore collectives, and serve batch dispatches on a single timeline;
-* always, a registry observation (``step_phase`` → the trainer phase
-  histogram, ``collective_span`` → kvstore collective counters), so the
-  Prometheus exposition reflects steady-state behavior with the profiler
-  off.
+* always, a ``jax.profiler.TraceAnnotation`` for its extent (outside a
+  profiling session that is a flag test), so in every ``.xplane.pb`` —
+  an operator's ``profiler.set_config(xla_trace_dir=...)`` or a
+  benchmark's — the program's spans lie on the host plane beside the
+  device's operations;
+* at exit, ONE flight-recorder event that is the whole span: name,
+  begin, end, the span that was open on this thread when it began, and
+  the training step it belongs to (``observe.spans()`` reads them back;
+  ``MXNET_BLACKBOX=0`` turns the record off and nothing else);
+* while the profiler runs, a chrome-trace complete event (``ph:"X"``)
+  under the span's category, so one ``profiler.dump()`` interleaves step
+  phases, per-op dispatches, kvstore collectives and serve batches.
 
-The trace side costs nothing when profiling is off (one module-global
-truthiness check); the registry side is one histogram observation per
-*step/collective/batch* — never per op.
+``step_phase`` and ``collective_span`` add their registry series
+(histogram / counters) and are otherwise this span.  A span costs one
+clock read at entry and one event at exit — per step, collective or
+batch, never per op.
 """
 from __future__ import annotations
 
+import itertools
+import threading
 import time
+
+import jax
 
 from .. import observe as _observe
 from .. import profiler as _profiler
 from . import registry as _registry
 
-__all__ = ["span", "step_phase", "collective_span", "mark_step"]
+__all__ = ["span", "record_finished", "step_phase", "collective_span",
+           "mark_step"]
+
+_ids = itertools.count(1)       # next() is atomic under the interpreter lock
+_open = threading.local()       # .stack: the spans open on this thread
+
+
+def _stack():
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _emit_chrome(name, cat, begin_ns, end_ns, args):
+    if _profiler._running:
+        _profiler._emit(name, cat, "X", begin_ns / 1e3, args=args,
+                        dur=(end_ns - begin_ns) / 1e3)
 
 
 class span:
-    """Chrome-trace span under category ``cat`` — emits only while the
-    profiler runs, a no-op otherwise."""
+    """Time the ``with`` block as ``name`` under category ``cat``.
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    ``step`` is the training step the span belongs to; left out, a span
+    takes its parent's.  Further keywords are the span's arguments; code
+    inside the block may add to ``.args`` what it learns there.  After
+    exit ``begin_ns``, ``end_ns`` and ``seconds`` hold the reading."""
 
-    def __init__(self, name, cat="step_phase", args=None):
+    __slots__ = ("name", "cat", "step", "args", "id", "parent",
+                 "begin_ns", "end_ns", "_annotation")
+
+    def __init__(self, name, cat="step_phase", step=None, **args):
         self.name = name
         self.cat = cat
+        self.step = step
         self.args = args
 
     def __enter__(self):
-        self._t0 = _profiler._now_us() if _profiler._running else None
+        stack = _stack()
+        parent = stack[-1] if stack else None
+        self.id = next(_ids)
+        self.parent = parent.id if parent is not None else None
+        if self.step is None and parent is not None:
+            self.step = parent.step
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        stack.append(self)
+        self.begin_ns = time.monotonic_ns()
         return self
 
-    def __exit__(self, *_exc):
-        if self._t0 is not None and _profiler._running:
-            _profiler._emit(self.name, self.cat, "X", self._t0,
-                            args=self.args,
-                            dur=_profiler._now_us() - self._t0)
+    def __exit__(self, *exc):
+        self.end_ns = _observe.record_span(
+            self.cat, self.name, self.begin_ns, id=self.id,
+            parent=self.parent, step=self.step, **self.args)
+        self._close(*exc)
+        _emit_chrome(self.name, self.cat, self.begin_ns, self.end_ns,
+                     self.args)
         return False
+
+    def cancel(self):
+        """Leave the span without recording it (a probe that found no work)."""
+        self._close(None, None, None)
+
+    def _close(self, *exc):
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:     # left out of order: keep the others' parents right
+            stack.remove(self)
+        self._annotation.__exit__(*exc)
+
+    @property
+    def seconds(self):
+        return (self.end_ns - self.begin_ns) * 1e-9
+
+
+def record_finished(name, cat, begin_ns, end_ns, **args):
+    """Record a span that somebody else timed (jax reports a compile when it
+    is over): same event, same parent and step rules, no annotation."""
+    stack = _stack()
+    parent_id, step = (stack[-1].id, stack[-1].step) if stack else (None, None)
+    _observe.record_span(cat, name, begin_ns, end_ns, id=next(_ids),
+                         parent=parent_id, step=step, **args)
+    _emit_chrome(name, cat, begin_ns, end_ns, args)
 
 
 def _phase_histogram():
@@ -63,27 +133,20 @@ def _steps_counter():
         "mxtpu_trainer_steps_total", "Optimizer steps taken")
 
 
-class step_phase:
-    """Time one phase of a training step: chrome-trace span
-    ``step/<phase>`` (cat ``step_phase``) + an observation in the
+class step_phase(span):
+    """One phase of a training step: the span ``step/<phase>`` (or
+    ``name``) plus an observation in the
     ``mxtpu_trainer_step_phase_seconds{phase=...}`` histogram."""
 
-    __slots__ = ("phase", "_span", "_t0")
+    __slots__ = ("phase",)
 
-    def __init__(self, phase):
+    def __init__(self, phase, name=None, step=None, **args):
+        super().__init__(name or f"step/{phase}", step=step, **args)
         self.phase = phase
 
-    def __enter__(self):
-        self._span = span(f"step/{self.phase}")
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._span.__exit__(*exc)
-        _phase_histogram().labels(phase=self.phase).observe(dt)
-        _observe.record("phase", self.phase, seconds=dt)
+        super().__exit__(*exc)
+        _phase_histogram().labels(phase=self.phase).observe(self.seconds)
         return False
 
 
@@ -114,33 +177,27 @@ def _collective_metrics():
     )
 
 
-class collective_span:
-    """Instrument one kvstore collective: count + bytes + latency into the
-    registry, and a ``collective/<op>`` chrome-trace span while
-    profiling."""
+class collective_span(span):
+    """One kvstore collective: the span ``collective/<op>`` plus count,
+    bytes and latency in the registry."""
 
-    __slots__ = ("op", "nbytes", "_span", "_t0")
+    __slots__ = ()
 
     def __init__(self, op, nbytes=0):
-        self.op = op
-        self.nbytes = int(nbytes)
+        super().__init__(f"collective/{op}", cat="collective", op=op,
+                         bytes=int(nbytes))
 
     def __enter__(self):
+        op, nbytes = self.args["op"], self.args["bytes"]
         total, bytes_, _lat, launches = _collective_metrics()
-        total.labels(op=self.op).inc()
+        total.labels(op=op).inc()
         launches.inc()
-        if self.nbytes:
-            bytes_.labels(op=self.op).inc(self.nbytes)
-        self._span = span(f"collective/{self.op}", cat="collective",
-                          args={"op": self.op, "bytes": self.nbytes})
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
+        if nbytes:
+            bytes_.labels(op=op).inc(nbytes)
+        return super().__enter__()
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._span.__exit__(*exc)
-        _collective_metrics()[2].labels(op=self.op).observe(dt)
-        _observe.record("collective", self.op, seconds=dt,
-                        bytes=self.nbytes)
+        super().__exit__(*exc)
+        _collective_metrics()[2].labels(op=self.args["op"]).observe(
+            self.seconds)
         return False

@@ -8,7 +8,11 @@ visible through the profiler; here they are first-class metrics:
 
 * a process-wide ``jax.monitoring`` listener counts every XLA compile
   stage (trace / lower / backend-compile) with durations —
-  ``mxtpu_xla_compile_total{stage}`` / ``mxtpu_xla_compile_seconds``;
+  ``mxtpu_xla_compile_total{stage}`` / ``mxtpu_xla_compile_seconds`` —
+  and records each as a span ``xla.trace`` / ``xla.lower`` /
+  ``xla.compile`` with jax's ``fun_name`` (``telemetry.spans``), so the
+  record says which function compiled, when, and under which step (a
+  trace under 10 ms, as every eager op's is, is counted and not recorded);
 * per-function attribution rides the jit trace-cache size:
   ``RetraceWatchdog.observe(fn, name)`` (called by ``HybridBlock`` and
   ``FusedTrainStep`` after each dispatch, or via the ``watch_jit``
@@ -23,9 +27,11 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 import weakref
 
 from . import registry as _registry
+from . import spans as _spans
 
 __all__ = ["RetraceWatchdog", "watchdog", "watch_jit",
            "install_compile_listener"]
@@ -39,6 +45,15 @@ _EVENT_STAGES = {
     "/jax/core/compile/backend_compile_duration": "compile",
 }
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# a trace shorter than this takes no slot in the span record: eager ops
+# retrace by the hundred, each well under a millisecond (in a BERT-base
+# set-up 938 of the ring's 1,349 events), and the counters below keep their
+# number and their sum
+_TRACE_SPAN_FLOOR_S = 0.01
+
 # compiles are seconds-scale events; default sub-ms buckets would be noise
 _COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0, 60.0)
 
@@ -47,9 +62,10 @@ _listener_installed = False
 
 
 def install_compile_listener(registry=None):
-    """Register the process-wide ``jax.monitoring`` duration listener that
-    feeds the XLA compile counters.  Idempotent; installed automatically
-    on ``mxnet_tpu.telemetry`` import.  Returns True on first install."""
+    """Register the process-wide ``jax.monitoring`` listeners that feed the
+    XLA compile counters and record the ``xla.*`` spans.  Idempotent;
+    installed automatically on ``mxnet_tpu.telemetry`` import.  Returns
+    True on first install."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
@@ -64,14 +80,46 @@ def install_compile_listener(registry=None):
         "mxtpu_xla_compile_seconds", "Time spent in each XLA compile stage",
         labelnames=("stage",), buckets=_COMPILE_BUCKETS)
 
-    def _on_duration(event, duration, **_kw):
+    # per compiling thread: `.hit`, a persistent-cache hit that jax reported
+    # inside the backend-compile event that is still open; `.traces`, the
+    # trace events open (a step's trace holds thousands of inner jit traces:
+    # the counters see them all, the record only the outermost, and of
+    # those only the ones of `_TRACE_SPAN_FLOOR_S` or more)
+    local = threading.local()
+
+    def _on_event(event, **_kw):
+        if event == _CACHE_HIT_EVENT:
+            local.hit = True
+
+    def _on_scalar(event, _start, **_kw):
+        if event == _TRACE_EVENT:
+            local.traces = getattr(local, "traces", 0) + 1
+
+    def _on_time_span(event, start, end, fun_name=None, **_kw):
+        """jax stamps a stage with ``time.time()`` at both ends; the span is
+        put on the record's clock by the two clocks' offset now."""
         stage = _EVENT_STAGES.get(event)
-        if stage is not None:
-            total.labels(stage=stage).inc()
-            seconds.labels(stage=stage).observe(duration)
+        if stage is None:
+            return
+        total.labels(stage=stage).inc()
+        seconds.labels(stage=stage).observe(end - start)
+        args = {"fun_name": fun_name}
+        if stage == "trace":
+            local.traces = getattr(local, "traces", 1) - 1
+            if local.traces > 0 or end - start < _TRACE_SPAN_FLOOR_S:
+                return
+        elif stage == "compile":
+            args["cache_hit"] = getattr(local, "hit", False)
+            local.hit = False
+        to_mono = time.monotonic_ns() - time.time_ns()
+        _spans.record_finished(
+            "xla." + stage, "compile", int(start * 1e9) + to_mono,
+            int(end * 1e9) + to_mono, **args)
 
     import jax.monitoring as _jm
-    _jm.register_event_duration_secs_listener(_on_duration)
+    _jm.register_event_listener(_on_event)
+    _jm.register_scalar_listener(_on_scalar)
+    _jm.register_event_time_span_listener(_on_time_span)
     return True
 
 
@@ -120,7 +168,8 @@ class RetraceWatchdog:
         """Record one completed call of ``fn`` (a ``jax.jit`` callable).
         Compares the trace-cache size against the last call; growth beyond
         the first compile counts as a retrace, and growth after
-        ``steady_after`` calls additionally warns.
+        ``steady_after`` calls additionally warns.  Returns the growth,
+        with the first compile counted (0 where ``fn`` cannot be tracked).
 
         ``scope_root`` is the entry point's name-stack root (the Gluon
         block name whose `jax.named_scope` wraps the traced program) —
@@ -129,7 +178,7 @@ class RetraceWatchdog:
         try:
             size = fn._cache_size()
         except Exception:  # mxlint: disable=swallowed-exception -- not a PjitFunction (mocks, AOT wrappers): nothing to track, observing is optional
-            return
+            return 0
         with self._lock:
             ent = self._tracked.get(id(fn))
             if ent is None:
@@ -146,8 +195,10 @@ class RetraceWatchdog:
             ent.calls += 1
             calls, prev = ent.calls, ent.cache_size
             ent.cache_size = size
-        if prev is None or size <= prev:
-            return
+        if prev is None:
+            return size     # the first compile: growth, but no retrace
+        if size <= prev:
+            return 0
         self._retraces.labels(fn=name).inc(size - prev)
         if calls > self.steady_after:
             extras = "".join(
@@ -159,6 +210,7 @@ class RetraceWatchdog:
                 "input shape/dtype or static argument is drifting "
                 "(unbucketed batch dim?); each one stalls the step for the "
                 "full XLA compile", name, calls, prev, size, extras)
+        return size - prev
 
     def watch(self, fn, name=None):
         """Wrap a jitted callable so every call is observed.  Note: the

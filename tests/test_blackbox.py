@@ -308,6 +308,37 @@ def test_cli_merges_and_prints_verdict(tmp_path, capsys):
     assert len(blackbox.load(str(tmp_path))) == 3
 
 
+def test_a_dump_that_holds_span_events_still_merges(tmp_path):
+    """`telemetry.span` writes whole spans into the ring (ISSUE 25): the dump
+    keeps its schema, the analyzer reads them as it read phase events, and
+    its chrome trace draws them as complete events ending at their stamp."""
+    from mxnet_tpu import telemetry
+    observe.reset(enabled=True)
+    try:
+        with telemetry.step_phase("eval", step=4):
+            with telemetry.span("inner", cat="unit", shard=1):
+                pass
+        observe.record("checkpoint", "save", outcome="written")
+        path = observe.dump(reason="unit", path=str(tmp_path / "blackbox-host00000.json"))
+        spans = observe.spans()
+    finally:
+        observe.reset()
+    doc = blackbox.load_dump(path)
+    assert doc["schema"] == observe.SCHEMA_VERSION
+    assert all(len(ev) == 7 for ev in doc["events"])
+    entries, _offsets, warnings, dropped = blackbox.merge([doc], timeout=TIMEOUT)
+    assert [(e["cat"], e["name"]) for e in entries] == [
+        ("unit", "inner"), ("step_phase", "step/eval"), ("checkpoint", "save")]
+    assert not warnings and not dropped
+    assert blackbox.analyze([doc])["verdict"] == "NONE"
+    inner = entries[0]["payload"]
+    assert inner["parent"] == spans[1]["id"] and inner["step"] == 4
+    assert inner["shard"] == 1 and inner["seconds"] >= 0
+    drawn = blackbox.chrome_trace(entries)["traceEvents"]
+    assert [e["ph"] for e in drawn] == ["X", "X", "i"]
+    assert drawn[0]["dur"] == pytest.approx(inner["seconds"] * 1e6)
+
+
 # -- signal-path audit (ISSUE 20 satellite) -----------------------------------
 
 def test_sigterm_mid_run_dumps_then_terminates(tmp_path):

@@ -242,29 +242,6 @@ def test_attribution_coverage_finding():
     assert f["rule"] == "attribution-coverage"
 
 
-# -- measured-timings join -------------------------------------------------
-def test_attach_timings_computes_achieved_rates():
-    doc = _synthetic_doc()
-    doc.update(mode="cost-model", contract={}, findings=[])
-    census.attach_timings(doc, {"Net/slow@bwd": 1e-6})
-    row = doc["rows"][0]
-    assert doc["mode"] == "measured"
-    assert row["tf_per_s"] == pytest.approx(100.0 / 1e-6 / 1e12)
-    assert row["gb_per_s"] == pytest.approx(400.0 / 1e-6 / 1e9)
-    assert row["mfu"] == pytest.approx(
-        100.0 / 1e-6 / census.PEAKS["tpu-v5e"]["flops"])
-
-
-def test_timings_from_trace():
-    trace = {"traceEvents": [
-        {"name": "Net/slow@bwd", "ph": "X", "dur": 1000.0},
-        {"name": "Net/slow@bwd", "ph": "X", "dur": 500.0},
-        {"name": "ignored", "ph": "X", "dur": 9.0},
-    ]}
-    assert census.timings_from_trace(trace, ["Net/slow@bwd"]) == {
-        "Net/slow@bwd": pytest.approx(1.5e-3)}
-
-
 # -- telemetry -------------------------------------------------------------
 def test_census_gauges_in_exposition(dp_doc):
     reg = MetricsRegistry()

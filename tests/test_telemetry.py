@@ -4,6 +4,7 @@ step-trace spans, retrace watchdog, and the publisher integrations
 import json
 import logging
 import re
+import sys
 import threading
 
 import numpy as onp
@@ -354,6 +355,340 @@ def test_tpu_ici_collective_span_in_trace():
     spans = [e for e in events if e.get("cat") == "collective"]
     assert spans and spans[0]["name"] == "collective/allreduce"
     assert spans[0]["args"]["bytes"] == 2 * 2 * 2 * 4
+
+
+# ---------------------------------------------------------------------------
+# the one span primitive and its record (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+# the module: `telemetry.watchdog` is the function of the same name
+_WATCHDOG = sys.modules["mxnet_tpu.telemetry.watchdog"]
+
+
+@pytest.fixture
+def record(monkeypatch):
+    """A clean, whole span record for one test (the ring is the process's),
+    with every outermost trace on it, however short."""
+    from mxnet_tpu import observe
+    monkeypatch.setattr(_WATCHDOG, "_TRACE_SPAN_FLOOR_S", 0.0)
+    observe.reset(enabled=True)
+    yield observe
+    observe.reset()
+
+
+def _toy_fused_step(dtype="float32"):
+    """Dense(1) under SGD momentum in one FusedTrainStep.  In bfloat16 the
+    momentum state is made bf16 and comes back f32 from the first update,
+    as in ResNet-50's cell, so the step compiles twice."""
+    net = nn.Dense(1)
+    net.initialize()
+    net.cast(dtype)
+
+    class WithLoss(mx.gluon.HybridBlock):
+        def __init__(self, net):
+            super().__init__()
+            self.net = net
+
+        def forward(self, x, y):
+            return ((self.net(x) - y) ** 2).mean()
+
+    trainer = mx.gluon.Trainer(net.collect_params(), "sgd",
+                               {"learning_rate": 0.1, "momentum": 0.9})
+    fused = mx.gluon.FusedTrainStep(WithLoss(net), trainer)
+    x = mx.np.ones((4, 3), dtype=dtype)
+    y = mx.np.ones((4, 1), dtype=dtype)
+    return fused, (x, y)
+
+
+def test_span_writes_one_event_with_begin_end_parent_and_step(record):
+    with telemetry.span("outer", cat="unit", step=7, shard=3) as outer:
+        with telemetry.span("inner", cat="unit") as inner:
+            inner.args["found"] = True
+    events = [e for e in record.events() if e[4] == "unit"]
+    assert [e[5] for e in events] == ["inner", "outer"]    # one each, at exit
+    got = {s["name"]: s for s in record.spans()}
+    assert set(got) == {"inner", "outer"} and record.spans().dropped == 0
+    for s in got.values():
+        assert s["begin_ns"] <= s["end_ns"] and s["cat"] == "unit"
+    assert got["outer"]["parent"] is None and got["outer"]["step"] == 7
+    assert got["inner"]["parent"] == got["outer"]["id"] == outer.id
+    assert got["inner"]["step"] == 7                       # its parent's
+    assert got["outer"]["args"] == {"shard": 3}
+    assert got["inner"]["args"] == {"found": True}
+    # nested in time as well as by id, and the event's stamp is the end
+    assert got["outer"]["begin_ns"] <= got["inner"]["begin_ns"]
+    assert got["inner"]["end_ns"] <= got["outer"]["end_ns"] == events[1][0]
+    assert record.spans("inner") == [got["inner"]]
+    assert outer.seconds == pytest.approx(events[1][6]["seconds"])
+
+
+def test_span_survives_an_exception_and_leaves_no_parent_behind(record):
+    with pytest.raises(KeyError):
+        with telemetry.span("fails", cat="unit"):
+            raise KeyError("x")
+    with telemetry.span("after", cat="unit"):
+        pass
+    got = {s["name"]: s for s in record.spans()}
+    assert got["fails"]["begin_ns"] <= got["fails"]["end_ns"]
+    assert got["after"]["parent"] is None
+
+
+def test_a_second_threads_spans_do_not_take_the_firsts_parent(record):
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        inside.wait(10)
+        with telemetry.span("other-thread", cat="unit"):
+            pass
+        release.set()
+
+    t = threading.Thread(target=other)
+    t.start()
+    with telemetry.span("main-thread", cat="unit"):
+        inside.set()
+        assert release.wait(10)
+    t.join(10)
+    assert not t.is_alive()
+    got = {s["name"]: s for s in record.spans()}
+    assert got["other-thread"]["parent"] is None
+    assert got["other-thread"]["step"] is None
+    assert got["main-thread"]["begin_ns"] <= got["other-thread"]["begin_ns"]
+
+
+def test_fused_step_spans_nest_and_share_the_step(record):
+    fused, args = _toy_fused_step()
+    for _ in range(3):
+        fused(*args, batch_size=4)
+    steps = record.spans("fused_step.step")
+    assert [s["step"] for s in steps] == [1, 2, 3]
+    assert all(s["parent"] is None and s["cat"] == "step_phase" for s in steps)
+    for step in steps:
+        kids = [s for s in record.spans() if s["parent"] == step["id"]]
+        assert [k["name"] for k in sorted(kids, key=lambda k: k["begin_ns"])] \
+            == ["fused_step.prepare", "fused_step.launch"]
+        assert all(k["step"] == step["step"] for k in kids)
+        assert all(step["begin_ns"] <= k["begin_ns"] <= k["end_ns"]
+                   <= step["end_ns"] for k in kids)
+    # a step leaves three events and no more: itself and its two parts
+    names = [s["name"] for s in record.spans() if s["step"] == 3]
+    assert sorted(names) == ["fused_step.launch", "fused_step.prepare",
+                             "fused_step.step"]
+
+
+def test_fused_step_prepare_outside_a_step_has_no_parent(record):
+    fused, args = _toy_fused_step()
+    fused.lower(*args, batch_size=4)
+    (prepare,) = record.spans("fused_step.prepare")
+    assert prepare["parent"] is None and prepare["step"] is None
+    assert record.spans("fused_step.step") == []
+
+
+@pytest.mark.parametrize("dtype,compiles", [("float32", 1), ("bfloat16", 2)])
+def test_launch_says_which_calls_compiled(record, dtype, compiles):
+    """bf16 momentum comes back f32 from the first update: exactly two
+    launches compile, the first one (no retrace to the watchdog) included."""
+    fused, args = _toy_fused_step(dtype)
+    retraces = telemetry.watchdog().retrace_count("FusedTrainStep[WithLoss]")
+    for _ in range(5):
+        fused(*args, batch_size=4)
+    launches = record.spans("fused_step.launch")
+    compiled = [s["args"]["compiled"] for s in launches]
+    assert compiled == [True] * compiles + [False] * (5 - compiles)
+    # the flag is the watchdog's own reading, which counts all but the first
+    assert telemetry.watchdog().retrace_count("FusedTrainStep[WithLoss]") \
+        == retraces + compiles - 1
+    # each compiling launch holds the step program's three xla stages
+    for launch in launches[:compiles]:
+        stages = sorted(s["name"] for s in record.spans()
+                        if s["parent"] == launch["id"]
+                        and "fused" in s["args"].get("fun_name", ""))
+        assert stages == ["xla.compile", "xla.lower", "xla.trace"]
+
+
+def test_xla_spans_carry_fun_name_and_only_the_outermost_trace(record):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner_fn(x):
+        return x * 2.5
+
+    def outer_fn(x):
+        return inner_fn(x) + inner_fn(x + 1)
+
+    x = jnp.ones((7,))       # its own small program, outside the span
+    with telemetry.span("compiling", cat="unit", step=11) as sp:
+        jax.jit(outer_fn)(x)
+    mine = [s for s in record.spans() if s["parent"] == sp.id]
+    assert sorted(s["name"] for s in mine) == \
+        ["xla.compile", "xla.lower", "xla.trace"]
+    assert all("outer_fn" in s["args"]["fun_name"] for s in mine)
+    assert all(s["step"] == 11 and s["cat"] == "compile" for s in mine)
+    assert all(sp.begin_ns <= s["begin_ns"] <= s["end_ns"] for s in mine)
+    (compile_,) = [s for s in mine if s["name"] == "xla.compile"]
+    assert compile_["args"]["cache_hit"] is False
+    # the counters still see every trace, the nested ones too
+    assert not any("inner_fn" in s["args"].get("fun_name", "")
+                   for s in record.spans("xla.trace"))
+
+
+def test_a_short_trace_is_counted_and_takes_no_slot(record, monkeypatch):
+    """Eager ops retrace by the hundred; what they compile stays on record."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(_WATCHDOG, "_TRACE_SPAN_FLOOR_S", float("inf"))
+    traces = lambda: telemetry.default_registry().get_sample_value(
+        "mxtpu_xla_compile_total", {"stage": "trace"}) or 0
+    before = traces()
+    jax.jit(lambda x: x * 3.5 - 2)(jnp.ones((11,)))
+    assert traces() > before and record.spans("xla.trace") == []
+    assert len(record.spans("xla.lower")) == len(record.spans("xla.compile")) >= 1
+
+
+def _host_plane_events(tmp_path, body):
+    """Run `body` under a CPU `jax.profiler` session; [(name, start, end)] of
+    the host planes of the `.xplane.pb` it leaves."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_xplane_host_plane_holds_the_programs_spans(record, tmp_path):
+    fused, args = _toy_fused_step()
+    fused(*args, batch_size=4)          # compile outside the session
+    events = _host_plane_events(
+        tmp_path, lambda: [fused(*args, batch_size=4) for _ in range(2)])
+    by_name = {}
+    for name, start, end in events:
+        by_name.setdefault(name, []).append((start, end))
+    assert len(by_name["fused_step.step"]) == 2
+    for name in ("fused_step.prepare", "fused_step.launch"):
+        assert len(by_name[name]) == 2
+        for (s0, s1), (c0, c1) in zip(sorted(by_name["fused_step.step"]),
+                                      sorted(by_name[name])):
+            assert s0 <= c0 <= c1 <= s1
+    # the same two steps are on the record, on its own clock
+    assert [s["step"] for s in record.spans("fused_step.step")] == [1, 2, 3]
+
+
+def test_recorder_off_spans_still_annotate_and_record_nothing(tmp_path):
+    from mxnet_tpu import observe
+    observe.reset(enabled=False)
+    try:
+        def body():
+            with telemetry.step_phase("eval") as phase:
+                pass
+            assert phase.begin_ns <= phase.end_ns     # the histogram's reading
+        before = telemetry.default_registry().get_sample_value(
+            "mxtpu_trainer_step_phase_seconds_count", {"phase": "eval"}) or 0
+        events = _host_plane_events(tmp_path, body)
+        assert "step/eval" in {name for name, _s, _e in events}
+        assert observe.spans() == [] and observe.events() == []
+        assert telemetry.default_registry().get_sample_value(
+            "mxtpu_trainer_step_phase_seconds_count",
+            {"phase": "eval"}) == before + 1
+    finally:
+        observe.reset()
+
+
+def test_wrappers_go_through_the_one_span(record):
+    """step_phase, collective_span and the serve batch span are span events
+    of their own category; the serve worker's has no parent here."""
+    net, x = _train_3_steps(hybridize=False)
+    kv = mx.kv.create("tpu_ici")
+    kv.pushpull(3, [mx.np.ones((2, 2), ctx=mx.cpu(i)) for i in range(2)])
+    ep = net.as_endpoint(max_batch_size=4, max_latency_ms=2)
+    try:
+        with telemetry.span("caller", cat="unit"):
+            ep.predict(x)
+    finally:
+        ep.shutdown(drain=True)
+    spans = record.spans()
+    by_cat = {}
+    for s in spans:
+        by_cat.setdefault(s["cat"], []).append(s)
+    assert {"step/fwd", "step/bwd", "step/optimizer"} <= \
+        {s["name"] for s in by_cat["step_phase"]}
+    (coll,) = [s for s in by_cat["collective"]
+               if s["name"] == "collective/allreduce"]
+    assert coll["args"] == {"op": "allreduce", "bytes": 2 * 2 * 2 * 4}
+    (batch,) = by_cat["serve"]
+    assert batch["name"] == f"serve/{ep.name}/batch" and batch["parent"] is None
+    assert batch["args"]["rows"] == 2
+
+
+def test_fused_step_histogram_observes_the_whole_step(record):
+    reg = telemetry.default_registry()
+
+    def read(suffix):
+        return reg.get_sample_value(
+            "mxtpu_trainer_step_phase_seconds_" + suffix,
+            {"phase": "fused-step"}) or 0
+    fused, args = _toy_fused_step()
+    count, total = read("count"), read("sum")
+    fused(*args, batch_size=4)
+    (step,) = record.spans("fused_step.step")
+    assert read("count") == count + 1
+    assert read("sum") - total == pytest.approx(
+        (step["end_ns"] - step["begin_ns"]) * 1e-9)
+
+
+def test_chrome_events_are_stamped_from_the_records_clock(record):
+    profiler.dumps(reset=True)
+    profiler.set_state("run")
+    with telemetry.span("both", cat="unit", k=1):
+        pass
+    marker = profiler._now_us()
+    profiler.set_state("stop")
+    (ev,) = [e for e in json.loads(profiler.dumps(format="json", reset=True))
+             ["traceEvents"] if e["name"] == "both"]
+    (sp,) = record.spans("both")
+    assert ev["ts"] == pytest.approx(sp["begin_ns"] / 1e3)
+    assert ev["dur"] == pytest.approx((sp["end_ns"] - sp["begin_ns"]) / 1e3)
+    assert ev["args"] == {"k": 1} and ev["ts"] + ev["dur"] <= marker
+
+
+def test_exhausted_dataloader_probe_leaves_no_span_open(record):
+    data = mx.gluon.data.ArrayDataset(onp.arange(6, dtype="float32"))
+    loader = mx.gluon.data.DataLoader(data, batch_size=3)
+    assert len(list(loader)) == 2
+    assert len(record.spans("step/data-wait")) == 2    # the probe is discarded
+    with telemetry.span("next", cat="unit"):
+        pass
+    assert record.spans("next")[0]["parent"] is None
+
+
+def test_spans_reports_what_the_ring_dropped():
+    from mxnet_tpu.observe import FlightRecorder
+    rec = FlightRecorder(capacity=4, enabled=True)
+    for i in range(6):
+        rec.record_span("unit", f"s{i}", begin_ns=i, id=i, parent=None,
+                        step=None)
+    got = rec.spans()
+    assert [s["name"] for s in got] == ["s2", "s3", "s4", "s5"]
+    assert got.dropped == 2
+    # a span reported after the fact keeps its own end
+    rec.record_span("unit", "late", begin_ns=10, end_ns=25, id=9,
+                    parent=None, step=None)
+    assert rec.spans("late")[0]["end_ns"] == 25
 
 
 # ---------------------------------------------------------------------------

@@ -12,10 +12,9 @@ gauges, and the baseline gate CI runs (``tools/layerscope_baseline.json``,
 checked in EMPTY — all known offenders are waived on the contract with
 reasons, same policy as hloscan).
 
-On the virtual CPU mesh the census is cost-model-only (bound classes
-and speed-of-light MFU from modeled FLOPs/bytes against the target
-chip's peaks); on hardware, ``census.attach_timings`` joins measured
-profiler-region seconds for achieved TF/s / GB/s / MFU.
+The census is cost-model-only (bound classes and speed-of-light MFU
+from modeled FLOPs/bytes against the target chip's peaks); times on the
+chip come from the benchmark (``chipbench/``, ``PERF.md``).
 
 Usage::
 
