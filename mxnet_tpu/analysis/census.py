@@ -733,9 +733,9 @@ CONTRACTS = {
         # (VERDICT items 3/6).  PR 18 retired both: the stem runs in
         # space-to-depth form (SpaceToDepthStem — dense K=192
         # contraction, ops/stem.py) and BN-backward's reduction epilogue
-        # is one joint variadic reduce (ops/nn.py _bn_bwd_sums, the
-        # tuned bn_bwd_epilogue Pallas kernel on TPU), so the floors now
-        # simply pass — see docs/AUTOTUNE.md "waiver retirement".
+        # is two sibling sums that XLA fuses with their producers
+        # (ops/nn.py _bn_train_bwd), so the floors now simply pass —
+        # see docs/AUTOTUNE.md "waiver retirement".
         "min_attributed_flops": 0.90,
         "mfu_floors": {"stem": 0.50, "bn@bwd": 0.10},
     },
@@ -773,11 +773,10 @@ def _census_resnet_profile():
       stem floor fences the conv the chip actually runs;
     * each body unit is a ``_FusedConvBN`` — conv + BN + relu traced in
       ONE named scope, because that is the execution unit the target
-      chip schedules: BN's backward reduction epilogue (the tuned
-      ``bn_bwd_epilogue`` Pallas kernel, ops/nn.py) and the dx
-      elementwise chain fuse into the conv backward, so splitting them
-      into separate census buckets would charge the fused kernel's
-      traffic twice and fence a boundary that does not exist on device.
+      chip schedules: BN's backward reduction epilogue
+      (``_bn_train_bwd``, ops/nn.py) and the dx elementwise chain fuse
+      into the conv backward, so splitting them into separate census
+      buckets would charge the fused kernel's traffic twice and fence a boundary that does not exist on device.
       The ``bn@bwd`` floor fences these fused units;
     * convs are bias-free (each feeds a BatchNorm that would absorb the
       bias; a broadcast add would double the layer's output bytes);

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as onp
 
-from .. import context as _context
 
 # Dropout RNG implementation, read ONCE at import (ADVICE r5): the value
 # is consulted inside traced dropout code, so a later env change could
@@ -243,9 +242,8 @@ def _bn_train_fwd(data, gamma, beta, moving_mean, moving_var, momentum,
     """Single-pass stats (sum, sum-of-squares in f32 — ONE read of the
     activation, two fused reductions) + scale/shift folding: the big
     elementwise op is exactly one multiply-add, which XLA fuses into the
-    producing conv's epilogue.  This BN formulation is worth ~1.5x on
-    ResNet-50 training (see benchmark/MFU_ANALYSIS.md): the naive
-    mean/var/normalize chain reads the activation three times."""
+    producing conv's epilogue.  The naive mean/var/normalize chain reads
+    the activation three times."""
     red_axes = tuple(i for i in range(data.ndim) if i != axis)
     n = 1
     for i in red_axes:
@@ -268,26 +266,16 @@ def _bn_train_fwd(data, gamma, beta, moving_mean, moving_var, momentum,
     return (out, new_mean, new_var), (data, gamma, mean, inv)
 
 
-def _bn_bwd_sums(dyf, xhat, red_axes):
-    """The BN-backward reduction epilogue: sum(dy) and sum(dy*xhat) as
-    ONE variadic reduce — a single multi-output fusion that reads dy
-    (and the xhat recompute chain) once, instead of two reduce fusions
-    that each pull the full activation back from HBM.  The round-trip
-    this kills is exactly what benchmark/bn_epilogue_experiment.py
-    measured; the census bn@bwd MFU floor holds because of it."""
-    zero = jnp.zeros((), dyf.dtype)
-    return lax.reduce((dyf, dyf * xhat), (zero, zero),
-                      lambda acc, v: (acc[0] + v[0], acc[1] + v[1]),
-                      red_axes)
-
-
 def _bn_train_bwd(momentum, eps, axis, res, cts):
-    """Hand-written BN backward: one joint (variadic) reduction over one
-    read of (dy, xhat) plus one elementwise pass — the chain rule
-    through the naive form reads the activation twice more, and even
-    split sums read it twice (see `_bn_bwd_sums`).  On a TPU backend
-    the reduction epilogue runs as the tuned Pallas kernel
-    (`_bn_bwd_sums_pallas`, autotune kernel ``bn_bwd_epilogue``)."""
+    """Hand-written BN backward: sum(dy) and sum(dy*xhat) as two sibling
+    reductions, as the forward writes its two, plus one elementwise pass
+    that writes dx in the activation's dtype.  XLA merges the siblings
+    into one multi-output fusion with their producers (the casts, the
+    xhat recompute and, where dy comes out of one, the convolution), so
+    dy and the activation are read as the step holds them and neither
+    ``dyf`` nor ``xhat`` is written out; the sums accumulate in ``cdt``.
+    Under a mesh they are over a sharded axis and the partitioner
+    all-reduces them, like the forward's."""
     data, gamma, mean, inv = res
     dy, d_mm, d_mv = cts
     red_axes = tuple(i for i in range(data.ndim) if i != axis)
@@ -299,11 +287,8 @@ def _bn_train_bwd(momentum, eps, axis, res, cts):
     dyf = dy.astype(cdt)
     xhat = (data.astype(cdt) - mean.reshape(shape)) * \
         inv.reshape(shape)
-    if _context.on_tpu() and cdt == jnp.float32:
-        sum_dy, sum_dy_xhat = _bn_bwd_sums_pallas(dyf, xhat, red_axes,
-                                                  axis, n)
-    else:
-        sum_dy, sum_dy_xhat = _bn_bwd_sums(dyf, xhat, red_axes)
+    sum_dy = jnp.sum(dyf, axis=red_axes)
+    sum_dy_xhat = jnp.sum(dyf * xhat, axis=red_axes)
     a = (gamma.astype(cdt) * inv).reshape(shape)
     dx = a * (dyf - (sum_dy / n).reshape(shape) -
               xhat * (sum_dy_xhat / n).reshape(shape))
@@ -335,101 +320,6 @@ def batch_norm_train(data, gamma, beta, momentum, eps, axis, moving_mean,
     residual."""
     return _bn_train_core(data, gamma, beta, moving_mean, moving_var,
                           momentum, eps, axis % data.ndim)
-
-
-def _bn_reduce_kernel(nm, dy_ref, xh_ref, s_ref, ss_ref, acc_s, acc_ss):
-    """Grid-accumulated joint reduction (pattern: the fused matmul+stats
-    kernel in benchmark/bn_epilogue_experiment.py): both sums ride one
-    read of each (tm, tn) tile; f32 VMEM scratch carries the partials
-    across the m-grid, written out on the last step."""
-    from jax.experimental import pallas as pl
-    mi = pl.program_id(1)
-
-    @pl.when(mi == 0)
-    def _init():
-        acc_s[...] = jnp.zeros_like(acc_s)
-        acc_ss[...] = jnp.zeros_like(acc_ss)
-
-    dy = dy_ref[...].astype(jnp.float32)
-    xh = xh_ref[...].astype(jnp.float32)
-    acc_s[...] += jnp.sum(dy, axis=0, keepdims=True)
-    acc_ss[...] += jnp.sum(dy * xh, axis=0, keepdims=True)
-
-    @pl.when(mi == nm - 1)
-    def _finish():
-        s_ref[...] = acc_s[...]
-        ss_ref[...] = acc_ss[...]
-
-
-@_functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _bn_reduce_call(dy, xh, tm, tn, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    m, n = dy.shape
-    grid = (n // tn, m // tm)               # m innermost: scratch reuse
-    spec = pl.BlockSpec((tm, tn), lambda ni, mi: (mi, ni))
-    out_spec = pl.BlockSpec((1, tn), lambda ni, mi: (0, ni))
-    s, ss = pl.pallas_call(
-        _functools.partial(_bn_reduce_kernel, m // tm),
-        grid=grid,
-        in_specs=[spec, spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((1, tn), jnp.float32)] * 2,
-        interpret=interpret,
-    )(dy, xh)
-    return s[0], ss[0]
-
-
-def _bn_bwd_sums_pallas(dyf, xhat, red_axes, axis, m):
-    """`_bn_bwd_sums` through `bn_bwd_reduce_pallas` over the
-    channel-minor (M, C) view of ``m`` rows.  Traced inside a
-    `parallel.mesh_scope` (a mesh-sharded `FusedTrainStep`) the kernel
-    runs once per device on its rows and the partial sums are `psum`-ed
-    over the batch axes — BatchNorm's sums are over the global batch —
-    because XLA cannot partition a Mosaic call by itself.  Rows that do
-    not divide over those axes take the XLA form."""
-    from ..parallel import mesh as _mesh
-    mesh, batch_axes = _mesh.current_mesh(), _mesh.current_batch_axes()
-    if m % _mesh.axes_size(mesh, batch_axes):
-        return _bn_bwd_sums(dyf, xhat, red_axes)
-    perm = red_axes + (axis,)
-    dy2 = dyf.transpose(perm).reshape(m, -1)
-    xh2 = xhat.transpose(perm).reshape(m, -1)
-    if mesh is None:
-        return bn_bwd_reduce_pallas(dy2, xh2)
-
-    def local(dy2, xh2):
-        sums = bn_bwd_reduce_pallas(dy2, xh2)
-        return tuple(lax.psum(s, batch_axes) for s in sums) \
-            if batch_axes else sums
-
-    rows = _mesh.PartitionSpec(batch_axes or None, None)
-    return _mesh.shard_kernel(local, in_specs=(rows, rows),
-                              out_specs=(_mesh.PartitionSpec(),) * 2)(
-                                  dy2, xh2)
-
-
-def bn_bwd_reduce_pallas(dy, xhat, tm=None, tn=None, interpret=None):
-    """Tuned Pallas form of `_bn_bwd_sums` over a channel-minor (M, N)
-    view: returns (sum(dy, 0), sum(dy*xhat, 0)) in f32.  Tile targets
-    (tm, tn) come from the autotune cache (kernel ``bn_bwd_epilogue``)
-    and are re-fitted to the concrete shape, so any cached choice is
-    legal.  ``tn`` choices are bit-identical (channels are independent);
-    ``tm`` regroups the f32 partial sums, so it changes ULPs like any
-    reduction retile."""
-    m, n = dy.shape
-    if tm is None or tn is None:
-        from .. import tune
-        sig = tune.signature(dy.dtype, m=m, n=n)
-        params = tune.best("bn_bwd_epilogue", sig, {"tm": 512, "tn": 128})
-        tm = params["tm"] if tm is None else tm
-        tn = params["tn"] if tn is None else tn
-    from .stem import _fit_tile
-    tm = _fit_tile(m, tm)
-    tn = _fit_tile(n, tn)
-    interp = (not _context.on_tpu()) if interpret is None else interpret
-    return _bn_reduce_call(dy, xhat, tm, tn, interp)
 
 
 def batch_norm_inference(data, gamma, beta, moving_mean, moving_var, eps, axis):

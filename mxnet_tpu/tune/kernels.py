@@ -305,63 +305,6 @@ def _stem_build(sig, params):
 
 
 # ===========================================================================
-# fused BN-backward reduction epilogue (ops/nn.py)
-# ===========================================================================
-def _bn_sigs():
-    # the census resnet_profile bn shape: (8, 64, 32, 32) -> m=8192, n=64
-    return [signature("bfloat16", m=8192, n=64)]
-
-
-def _bn_grid(sig):
-    dims, _, _ = parse_signature(sig)
-    m, n = dims["m"], dims["n"]
-    return [{"tm": tm, "tn": tn}
-            for tm in (256, 512, 1024, 2048) if tm <= m
-            for tn in (64, 128, 256) if tn <= n]
-
-
-def _bn_default(sig):
-    # ops/nn.py bn_bwd_reduce_pallas fallback (keep in sync)
-    return {"tm": 512, "tn": 128}
-
-
-def _bn_model(sig, params, peaks):
-    """Roofline for the joint (sum dy, sum dy*xhat) reduction: both
-    inputs stream exactly once regardless of tiling (that is the
-    kernel's whole point), so candidates differ only in the grid-step
-    dispatch floor and VMEM footprint — bigger M-tiles amortize the
-    sequential-grid accumulation rounds."""
-    dims, dtype, _ = parse_signature(sig)
-    m, n = dims["m"], dims["n"]
-    e = tag_dtype(dtype).itemsize
-    tm = min(params["tm"], m)
-    tn = min(params["tn"], n)
-    steps = (n / tn) * (m / tm)
-    t_hbm = (2 * m * n * e               # dy + xhat, streamed once
-             + 2 * 4 * n) / peaks["bw"]  # the (2, C) f32 partials
-    t_vpu = 3.0 * m * n / (peaks["flops"] / 8)   # elementwise mul+adds
-    t_step = peaks["launch_s"] * steps
-    vmem = 2 * e * tm * tn + 2 * 4 * tn          # input tiles + scratch
-    return t_hbm + t_vpu + t_step + 1e-16 * vmem
-
-
-def _bn_build(sig, params):
-    import jax
-    from ..ops.nn import bn_bwd_reduce_pallas
-    dims, dtype, _ = parse_signature(sig)
-    dt = tag_dtype(dtype)
-    m, n = dims["m"], dims["n"]
-    ks = jax.random.split(jax.random.PRNGKey(3), 2)
-    dy = jax.random.normal(ks[0], (m, n), dtype=dt)
-    xhat = jax.random.normal(ks[1], (m, n), dtype=dt)
-    tm, tn = params["tm"], params["tn"]
-
-    def impl(dy, xhat):
-        return bn_bwd_reduce_pallas(dy, xhat, tm=tm, tn=tn)
-    return impl, (dy, xhat), False
-
-
-# ===========================================================================
 _REGISTRY = {
     "flash_attention": KernelSpec(
         "flash_attention", _flash_sigs, _flash_grid, _flash_default,
@@ -371,9 +314,6 @@ _REGISTRY = {
     "stem_s2d": KernelSpec(
         "stem_s2d", _stem_sigs, _stem_grid, _stem_default, _stem_build,
         model_time=_stem_model),
-    "bn_bwd_epilogue": KernelSpec(
-        "bn_bwd_epilogue", _bn_sigs, _bn_grid, _bn_default, _bn_build,
-        model_time=_bn_model),
 }
 
 

@@ -1,9 +1,9 @@
 """Sweep runner — ONE timing/trimming implementation for every sweep.
 
-The three hand-rolled bench loops (``attention_bench --block-sweep``,
-``flash_roofline_experiment``, ``bn_epilogue_experiment``) each re-grew
-their own warmup/median logic; this module is the single copy they and
-``tools/autotune`` now share.  Two measurement modes:
+The hand-rolled bench loops (``attention_bench --block-sweep``,
+``flash_roofline_experiment``) each re-grew their own warmup/median
+logic; this module is the single copy they and ``tools/autotune`` now
+share.  Two measurement modes:
 
 * ``time`` — real device timing with the ``benchmark/timing_util.py``
   discipline (scan-amortized, drain-subtracted, warmup + trimmed

@@ -132,24 +132,13 @@ def test_fused_step_with_frozen_subset():
     assert onp.abs(dw).max() > 0
 
 
-@pytest.mark.parametrize("bn_kernel", [False, True], ids=["xla", "bn_kernel"])
-def test_fused_step_spmd_dp_matches_single_device(bn_kernel, monkeypatch):
-    """``bn_kernel``: BatchNorm in the net, its backward steered onto the
-    Pallas reduction as on a TPU (interpreted here).  Under the mesh the
-    kernel runs per device inside the step's mesh_scope and the partial
-    sums are psum-ed, which must equal the one-device kernel: BatchNorm's
-    statistics are over the global batch either way."""
-    import functools
-
-    from mxnet_tpu import context as mx_context
-    from mxnet_tpu.ops import nn as nn_ops
+@pytest.mark.parametrize("bn", [False, True], ids=["xla", "bn"])
+def test_fused_step_spmd_dp_matches_single_device(bn):
+    """``bn``: BatchNorm in the net.  Its statistics, forward and backward,
+    are sums over the global batch; under the mesh that axis is sharded and
+    the partitioner all-reduces them, which must equal the one-device
+    step."""
     from mxnet_tpu.parallel import mesh as pmesh
-
-    if bn_kernel:
-        monkeypatch.setattr(mx_context, "on_tpu", lambda: True)
-        monkeypatch.setattr(
-            nn_ops, "bn_bwd_reduce_pallas",
-            functools.partial(nn_ops.bn_bwd_reduce_pallas, interpret=True))
 
     x_np = onp.random.RandomState(7).uniform(-1, 1, (16, 3, 6, 6)) \
         .astype(onp.float32)
@@ -159,7 +148,7 @@ def test_fused_step_spmd_dp_matches_single_device(bn_kernel, monkeypatch):
     finals = {}
     init_weights = None
     for mode in ("single", "dp8"):
-        mod, net = _make(9, with_bn=bn_kernel)
+        mod, net = _make(9, with_bn=bn)
         x = mx.np.array(x_np)
         y = mx.np.array(y_np, dtype="int32")
         mod(x, y)

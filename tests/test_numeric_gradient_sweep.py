@@ -177,3 +177,51 @@ def test_batch_norm_train_numeric_gradient():
     onp.testing.assert_allclose(onp.asarray(out), ref, rtol=2e-4, atol=2e-5)
     onp.testing.assert_allclose(onp.asarray(nm), 0.1 * mean, rtol=1e-4)
     onp.testing.assert_allclose(onp.asarray(nv), 0.9 + 0.1 * var, rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("axis", [1, -1])
+def test_batch_norm_train_backward_matches_plain_f32(axis, dtype):
+    """`_bn_train_bwd`'s (dx, dgamma, dbeta) against `jax.grad` of a plain
+    f32 mean/var/normalize BatchNorm over the same values: bf16 operands are
+    promoted to f32 before the multiply and summed in f32, so only dx's
+    final cast separates the two dtypes."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import nn as _nn
+
+    rs = onp.random.RandomState(11)
+    shape = (8, 6, 5, 7) if axis == 1 else (8, 5, 7, 6)
+    c = shape[axis]
+    x = jnp.asarray(rs.randn(*shape).astype("f") * 1.5 + 0.3, dtype)
+    w = jnp.asarray(rs.randn(*shape).astype("f"), dtype)   # dy
+    g = jnp.asarray((rs.rand(c) + 0.5).astype("f"))
+    b = jnp.asarray(rs.rand(c).astype("f"))
+    mm, mv = jnp.zeros(c), jnp.ones(c)
+
+    def ours(x, g, b):
+        out, _, _ = _nn.batch_norm_train(x, g, b, 0.9, 1e-5, axis, mm, mv)
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
+
+    def plain(x, g, b):
+        red = tuple(i for i in range(x.ndim) if i != axis % x.ndim)
+        mean = jnp.mean(x, axis=red, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=red, keepdims=True)
+        bshape = mean.shape
+        out = (x - mean) / jnp.sqrt(var + 1e-5) * g.reshape(bshape) + \
+            b.reshape(bshape)
+        return jnp.sum(out * w.astype(jnp.float32))
+
+    got = jax.grad(ours, argnums=(0, 1, 2))(x, g, b)
+    want = jax.grad(plain, argnums=(0, 1, 2))(x.astype(jnp.float32), g, b)
+    assert got[0].dtype == x.dtype and got[1].dtype == got[2].dtype == g.dtype
+    # the forward test's tolerances; dx in bf16, rounded once at the end,
+    # the numeric-gradient test's.  The two sums are f32 in both dtypes: a
+    # bf16 accumulation would miss the tight pair
+    tight, loose = (2e-4, 2e-5), (2e-2, 2e-3)
+    for name, a, e in zip(("dx", "dgamma", "dbeta"), got, want):
+        rtol, atol = loose if (name, dtype) == ("dx", "bfloat16") else tight
+        onp.testing.assert_allclose(onp.asarray(a.astype(jnp.float32)),
+                                    onp.asarray(e), rtol=rtol, atol=atol,
+                                    err_msg=name)

@@ -1,6 +1,7 @@
-"""Compile the training path's Pallas kernels for a TPU v5e that is
-*described*, not attached: what Mosaic or the SPMD partitioner would refuse
-on the chip, it refuses here, at no chip time.
+"""Compile the training path's Pallas kernels, and BatchNorm's backward,
+for a TPU v5e that is *described*, not attached: what Mosaic or the SPMD
+partitioner would refuse on the chip, it refuses here, and what the TPU
+compiler would materialise, it shows here, at no chip time.
 
 Nothing runs, so nothing here is a result or a time — `chip_smoke.py` is the
 run.  The CPU mesh never lowers these kernels (off-TPU they are interpreted
@@ -13,7 +14,9 @@ worker that is handed the file keeps the library until it exits — so the
 compiles run in the test's own process and all live in this one file.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +25,6 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
-from mxnet_tpu import context as mx_context
 from mxnet_tpu.ops import nn as nn_ops
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops import stem
@@ -70,15 +72,43 @@ def _custom_calls(fn, *args):
     return text.count("tpu_custom_call"), text
 
 
-# M x C of BatchNorm's backward in ResNet-50 at batch 128: the stem output,
-# a stage-2 block and the last stage (the widest and the narrowest M)
-@pytest.mark.parametrize("m,c", [(128 * 112 * 112, 64), (128 * 28 * 28, 512),
-                                 (128 * 7 * 7, 2048)])
-def test_bn_bwd_reduce_compiles(one_chip, m, c):
-    x = jax.ShapeDtypeStruct((m, c), F32, sharding=one_chip)
-    n, _ = _custom_calls(
-        functools.partial(nn_ops.bn_bwd_reduce_pallas, interpret=False), x, x)
-    assert n == 1
+def _bn_grad():
+    """grad of a training-mode NCHW BatchNorm's summed output w.r.t. (x,
+    gamma, beta) — a new function on every call: jit caches traces by
+    function."""
+    def loss(x, gamma, beta, mean, var):
+        out, _, _ = nn_ops.batch_norm_train(x, gamma, beta, 0.9, 1e-5, 1,
+                                            mean, var)
+        return out.astype(F32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _f32_relayouts(text, elements):
+    """Names of the `copy`, `transpose` and `reshape` instructions of a
+    compiled program whose result is an f32 array of ``elements`` elements."""
+    found = []
+    for name, dims, op in re.findall(
+            r"%([\w.\-]+) = f32\[([\d,]+)\]\S* (copy|transpose|reshape)\(",
+            text):
+        if math.prod(int(d) for d in dims.split(",")) == elements:
+            found.append(f"{op} {name} f32[{dims}]")
+    return found
+
+
+# N, C, H, W of BatchNorm's backward in ResNet-50 at batch 128: the stem
+# output, a stage-2 block and the last stage (the widest and the narrowest
+# M = N*H*W).  The guard of PR 26's gain: the two sums are XLA's fused
+# reduction over dy and the activation as the step holds them — no Mosaic
+# kernel, and no f32 copy of an activation laid out again to feed one
+@pytest.mark.parametrize("shape", [(128, 64, 112, 112), (128, 512, 28, 28),
+                                   (128, 2048, 7, 7)],
+                         ids=["1605632-64", "100352-512", "6272-2048"])
+def test_bn_backward_writes_no_f32_activation(one_chip, shape):
+    x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    ch = jax.ShapeDtypeStruct(shape[1:2], F32, sharding=one_chip)
+    n, text = _custom_calls(_bn_grad(), x, ch, ch, ch, ch)
+    assert n == 0
+    assert _f32_relayouts(text, math.prod(shape)) == []
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["mask", "mask+dropout"])
@@ -109,35 +139,21 @@ def test_stem_kernel_compiles(one_chip):
     assert n == 1
 
 
-def test_bn_backward_partitions_over_dp_mesh(dp_mesh, monkeypatch):
+def test_bn_backward_partitions_over_dp_mesh(dp_mesh):
     """BatchNorm's backward inside a program sharded over four chips, as a
-    `FusedTrainStep(recipe="dp4")` of ResNet-50 traces it.  A bare
-    pallas_call there is refused ("Mosaic kernels cannot be automatically
-    partitioned"); under the step's mesh_scope it runs per device and the
-    two sums are all-reduced."""
-    # take the TPU side of the kernel-vs-XLA rule: the process computes on
-    # the CPU, the program is compiled for the chip
-    monkeypatch.setattr(mx_context, "on_tpu", lambda: True)
+    `FusedTrainStep(recipe="dp4")` of ResNet-50 traces it: the two sums are
+    over the global batch, a sharded axis, so XLA's partitioner all-reduces
+    them — no kernel to wrap, with or without the step's mesh_scope."""
     rows = NamedSharding(dp_mesh, P("dp"))
     rep = NamedSharding(dp_mesh, P())
     x = jax.ShapeDtypeStruct((128, 64, 56, 56), BF16, sharding=rows)
     ch = jax.ShapeDtypeStruct((64,), F32, sharding=rep)
 
-    def loss(x, gamma, beta, mean, var):
-        out, _, _ = nn_ops.batch_norm_train(x, gamma, beta, 0.9, 1e-5, 1,
-                                            mean, var)
-        return out.astype(F32).sum()
-
-    def grad():  # a new function each time: jit caches traces by function
-        return jax.grad(loss, argnums=(0, 1, 2))
-
     with mesh_scope(dp_mesh, ("dp",)):
-        n, text = _custom_calls(grad(), x, ch, ch, ch, ch)
-    assert n == 1
-    assert "all-reduce" in text
-    # the bug this guards: the same program with no scope declared
-    with pytest.raises(Exception, match="cannot be automatically partitioned"):
-        _custom_calls(grad(), x, ch, ch, ch, ch)
+        scoped = _custom_calls(_bn_grad(), x, ch, ch, ch, ch)
+    for n, text in (scoped, _custom_calls(_bn_grad(), x, ch, ch, ch, ch)):
+        assert n == 0
+        assert "all-reduce" in text
 
 
 def test_flash_partitions_over_dp_mesh(dp_mesh):
