@@ -458,6 +458,61 @@ def activation(data, act_type="relu"):
     return table[act_type](data)
 
 
+_SQRT_HALF = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+
+
+@jax.custom_vjp
+def _gelu_erf_f32(data):
+    xf = data.astype(jnp.float32)
+    return (0.5 * xf * (1.0 + lax.erf(xf * _SQRT_HALF))).astype(data.dtype)
+
+
+def _gelu_erf_f32_fwd(data):
+    # With 11 operations XLA no longer writes GELU's result in a training
+    # step: it re-evaluates `erf` in the operands of every consumer (the next
+    # matmul, its weight gradient, the backward), which gave back 5.2 of the
+    # 6.0 ms a BERT-base step had gained (PERF.md, PR 29).  Behind the barrier
+    # the result is a tensor, written once by the epilogue of the matmul that
+    # made `data`.  Only here: a barrier that autodiff sees also bars the
+    # cotangent, and splits the backward matmul from GELU'.
+    return lax.optimization_barrier(_gelu_erf_f32(data)), data
+
+
+def _gelu_erf_f32_bwd(data, dy):
+    xf = data.astype(jnp.float32)
+    slope = 0.5 * (1.0 + lax.erf(xf * _SQRT_HALF)) \
+        + xf * jnp.exp(-0.5 * xf * xf) * _INV_SQRT_2PI
+    return ((dy.astype(jnp.float32) * slope).astype(data.dtype),)
+
+
+_gelu_erf_f32.defvjp(_gelu_erf_f32_fwd, _gelu_erf_f32_bwd)
+
+
+def gelu_exact(data):
+    """Exact GELU, ``0.5 * x * (1 + erf(x / sqrt(2)))`` -- what
+    ``hidden_act: "gelu"`` of ``bert-base-uncased`` computes -- in the form that
+    suits the input's dtype, chosen at trace time.
+
+    bf16 and f16: converted to f32 once, the expression above with a native
+    ``erf``, rounded back once; the backward keeps the input alone and
+    recomputes ``erf`` in f32 (reverse mode only: a ``custom_vjp``).
+    ``jax.nn.gelu(approximate=False)`` is ``0.5 * x * erfc(-x * sqrt_half)``,
+    which XLA expands into both branches of ``erfc`` (70 elementwise
+    operations with two divides and an exponential over the whole tensor: more
+    than the FFN matmul's own time in the epilogue it is fused into) and whose
+    argument is rounded to the narrow dtype before ``erfc`` sees it, so the
+    tail ``erfc`` exists for is lost at its input.  The f32 ``erf`` form is 11
+    operations and nearer the true value (tests/test_gelu_exact.py).
+
+    f32 and f64: ``jax.nn.gelu(approximate=False)`` unchanged: there the
+    ``erfc`` form's tail is real precision.
+    """
+    if jnp.issubdtype(data.dtype, jnp.floating) and data.dtype.itemsize < 4:
+        return _gelu_erf_f32(data)
+    return jax.nn.gelu(data, approximate=False)
+
+
 def leaky_relu(data, gamma=None, act_type="leaky", slope=0.25,
                lower_bound=0.125, upper_bound=0.334):
     if act_type == "leaky":
@@ -471,7 +526,7 @@ def leaky_relu(data, gamma=None, act_type="leaky", slope=0.25,
     if act_type == "selu":
         return jax.nn.selu(data)
     if act_type == "gelu":
-        return jax.nn.gelu(data, approximate=False)
+        return gelu_exact(data)
     if act_type == "gelu_tanh":
         return jax.nn.gelu(data, approximate=True)
     if act_type == "rrelu":

@@ -25,6 +25,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from mxnet_tpu.analysis import census
 from mxnet_tpu.ops import nn as nn_ops
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops import stem
@@ -109,6 +110,68 @@ def test_bn_backward_writes_no_f32_activation(one_chip, shape):
     n, text = _custom_calls(_bn_grad(), x, ch, ch, ch, ch)
     assert n == 0
     assert _f32_relayouts(text, math.prod(shape)) == []
+
+
+def _fusions(text):
+    """`[(result type, operand types, the fused computation's instructions)]`
+    of the entry computation's fusions in a compiled program's text, types
+    without layouts."""
+    comps, entry, _, _ = census._parse_computations(text)
+    types = {i.name: re.sub(r"\{[^}]*\}", "", i.result) for i in comps[entry]}
+    return [(types[i.name], [types[n] for n in i.operand_names],
+             comps[census._CALLS_RE.search(i.attrs).group(1)])
+            for i in comps[entry] if i.opcode == "fusion"]
+
+
+def _opcodes(instructions, dims):
+    """Opcodes of the instructions whose result is one array of ``dims``."""
+    return [i.opcode for i in instructions
+            if re.match(r"\w+\[" + dims + r"\]", i.result)]
+
+
+def test_ffn_gelu_epilogue_is_one_erf(one_chip):
+    """BERT-base's FFN at the benchmark cell's shapes, bf16, between its two
+    LayerNorms as an encoder layer has it: Dense(768→3072) → exact GELU →
+    Dense(3072→768) + residual, forward and backward.  The guard of PR 29's
+    gain: GELU rides in the FFN-up matmul's epilogue as one native `erf` (the
+    `erfc` form XLA expands there, with two divides and an exponential over
+    the whole tensor, took longer than the matmul) and is written once, not
+    evaluated again in each consumer's operands; the backward fusion rebuilds
+    GELU' from the pre-activation alone."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    def loss(x, g1, b1, w_up, b_up, w_down, b_down, g2, b2):
+        x = nn_ops.layer_norm(x, g1, b1, eps=1e-12)
+        h = nn_ops.fully_connected(x, w_up, b_up, flatten=False)
+        h = nn_ops.leaky_relu(h, act_type="gelu")
+        h = nn_ops.fully_connected(h, w_down, b_down, flatten=False)
+        out = nn_ops.layer_norm(h + x, g2, b2, eps=1e-12)
+        return (out.astype(F32) ** 2).sum()
+
+    n, text = _custom_calls(
+        jax.grad(loss, argnums=tuple(range(9))), spec(128, 128, 768),
+        spec(768), spec(768), spec(3072, 768), spec(3072), spec(768, 3072),
+        spec(768), spec(768), spec(768))
+    assert n == 0
+    wide = "bf16[128,128,3072]"
+    matmuls = [(out, operands, _opcodes(body, "128,128,3072"))
+               for out, operands, body in _fusions(text)]
+    matmuls = [f for f in matmuls if "convolution" in f[2]]
+    up = [f for f in matmuls if "bf16[3072]" not in f[0]]
+    back = [f for f in matmuls if "bf16[3072]" in f[0]]
+    assert len(up) == 1 and len(back) == 1, [f[:2] for f in matmuls]
+
+    assert up[0][0] == f"({wide}, {wide})"      # x and GELU(x), nothing else
+    assert up[0][2].count("erf") == 1
+    assert not {"exponential", "divide", "select"} & set(up[0][2]), up[0][2]
+
+    assert back[0][0] == f"(bf16[3072], {wide})"
+    assert back[0][1].count(wide) == 1, back[0][1]      # x, not x and GELU(x)
+    assert back[0][2].count("erf") == 1
+    # and nowhere else: not in the operands of the FFN-down matmul forward,
+    # nor of its weight gradient
+    assert len(re.findall(r"= f32\[128,128,3072\]\S* erf\(", text)) == 2
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["mask", "mask+dropout"])
