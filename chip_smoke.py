@@ -314,18 +314,31 @@ def phase_resnet50_train(ctx):
     return out
 
 
-def phase_bert_flash(ctx):
-    """bert_base pretraining as `benchmark/bert_pretrain_bench.py` builds it
-    (ragged masked batch, attention dropout 0.1) at B=4, T=2048."""
+def bert_flash_step(seed, batch, seq, **overrides):
+    """(step, batch) of the benchmark's bert_base held to the flash kernel:
+    `chipbench/configs/bert_base.json` with positions for `seq` and
+    `use_flash` true, built by `chipbench`'s own `build`, on one ragged batch
+    (valid lengths seq/2..seq) from its `make_ring`; attention dropout 0.1
+    and the key-padding mask run in the kernel.  ``overrides`` replace other
+    fields of the configuration (a test cuts the depth)."""
     import mxnet_tpu as mx
+    from chipbench.configs import bert_base
 
-    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-    import bert_pretrain_bench
+    with open(os.path.join(ROOT, "chipbench", "configs", "bert_base.json")) as f:
+        cfg = json.load(f)
+    cfg.update(max_position_embeddings=seq, use_flash=True, **overrides)
+    mx.random.seed(seed)
+    mod, trainer = bert_base.build(cfg)
+    cell = {"ring": 1, "batch": batch, "seq_len": seq,
+            "valid_lengths": [seq // 2, seq]}
+    (arrays, _valid), = bert_base.make_ring(cfg, cell, 1, seed, None)
+    return mx.gluon.FusedTrainStep(mod, trainer), arrays
 
-    b, t = BERT_BATCH, BERT_SEQ
-    mx.random.seed(ctx["seed"])
-    onp.random.seed(ctx["seed"])
-    step, batch, _model = bert_pretrain_bench.build(b, t)
+
+def phase_bert_flash(ctx):
+    """One bert_base pretraining step on the flash path at B=4, T=2048."""
+    b = BERT_BATCH
+    step, batch = bert_flash_step(ctx["seed"], b, BERT_SEQ)
     out = {}
     t0 = time.perf_counter()
     losses = [float(step(*batch, batch_size=b).asnumpy())]

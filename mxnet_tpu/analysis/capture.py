@@ -26,11 +26,13 @@ import os
 
 _ENTRYPOINTS = {}
 
-#: Bucket cap reproducing the PR 4 headline census on the resnet50
-#: profile (benchmark/COLLECTIVES_ANALYSIS.md: 160 -> 4 at 1 MB).
+#: Bucket cap of the captured bucketed step: at 1 MB the profile's 160
+#: gradient tensors pack into 4 all-reduce launches (a count of the
+#: program, which hloscan holds; no collective has been timed on this
+#: machine outside the dp4 cell, ROADMAP D1).
 BUCKETED_STEP_BUCKET_BYTES = 1 << 20
 
-#: The ResNet-50-like gradient profile (benchmark/allreduce_bench.py).
+#: A ResNet-50-like gradient profile: element counts of 160 tensors.
 RESNET50_PROFILE = [256] * 104 + [1024] * 26 + [16384] * 22 + [65536] * 8
 
 

@@ -734,8 +734,7 @@ CONTRACTS = {
         # space-to-depth form (SpaceToDepthStem — dense K=192
         # contraction, ops/stem.py) and BN-backward's reduction epilogue
         # is two sibling sums that XLA fuses with their producers
-        # (ops/nn.py _bn_train_bwd), so the floors now simply pass —
-        # see docs/AUTOTUNE.md "waiver retirement".
+        # (ops/nn.py _bn_train_bwd), so the floors now simply pass.
         "min_attributed_flops": 0.90,
         "mfu_floors": {"stem": 0.50, "bn@bwd": 0.10},
     },

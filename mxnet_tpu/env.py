@@ -2,10 +2,10 @@
 
 Reference: the 102 documented ``MXNET_*`` variables
 (`docs/static_site/src/pages/api/faq/env_var.md`).  On the TPU rebuild a
-large fraction is owned by XLA/PjRt (memory pools, engine threads, cudnn
-autotune); the table below documents every variable this framework
-actually honors, what it does here, and which reference knobs it
-subsumes.  ``mxnet_tpu.env.describe()`` prints the live table.
+large fraction is owned by XLA/PjRt (memory pools, engine threads,
+convolution algorithm search); the table below documents every variable
+this framework actually honors, what it does here, and which reference
+knobs it subsumes.  ``mxnet_tpu.env.describe()`` prints the live table.
 
 Handled at import (see ``apply()`` call in ``mxnet_tpu/__init__``):
 
@@ -51,10 +51,6 @@ MXNET_ENGINE_DEBUG           read once at import (`ops/invoke.py`):
                              stale-read diagnostics — warn at backward
                              when a recorded input was mutated in place
                              (reference §5.2 versioned-var visibility)
-MXNET_DROPOUT_RNG            read once at import (`ops/nn.py`):
-                             ``rbg`` (default, XLA hardware RNG) or
-                             ``threefry`` dropout mask bitstream; see
-                             docs/DESIGN.md "Dropout RNG streams"
 MXNET_TELEMETRY_STEADY_STEPS retrace-watchdog steady-state call count:
                              a jit cache miss after this many calls of a
                              watched function logs a WARNING (default 2;
@@ -195,21 +191,6 @@ MXNET_BLACKBOX_DIR           fixed directory for postmortem dumps;
                              checkpoint step dirs (``<root>/blackbox``)
                              or ``./blackbox`` with no checkpoint root
                              (read at each dump)
-MXNET_AUTOTUNE               ``0`` disables the autotune winner cache:
-                             every tuned kernel (flash attention, the
-                             scan-LSTM cell, the s2d stem, the
-                             BN-backward epilogue) silently uses its
-                             documented static default and ``tune.best``
-                             stops warning about misses (default on;
-                             read once at the first cache consult and
-                             memoized for the process —
-                             ``tune.invalidate()`` re-reads)
-MXNET_AUTOTUNE_CACHE         path of the autotune winner cache to read
-                             instead of the committed
-                             ``tools/autotune_cache.json`` (e.g. a
-                             freshly swept cache under review; read
-                             once at the first cache consult, see
-                             docs/AUTOTUNE.md)
 MXNET_LOCKSCAN_WITNESS       ``1`` installs the lock-acquisition
                              witness (``mxnet_tpu.lockwitness``) as the
                              very first package import: every
@@ -241,8 +222,7 @@ __all__ = ["apply", "describe", "is_naive_engine", "cpu_worker_nthreads",
            "sentinel_slow_factor", "sentinel_loss_factor",
            "sentinel_rollbacks", "kvstore_integrity",
            "parallel_recipe", "recipe_strict", "blackbox_enabled",
-           "blackbox_events", "blackbox_dir", "autotune_enabled",
-           "autotune_cache_path", "lockscan_witness",
+           "blackbox_events", "blackbox_dir", "lockscan_witness",
            "lockscan_report_path"]
 
 _naive_engine = False
@@ -417,24 +397,6 @@ def blackbox_dir(default=None):
     return v.strip()
 
 
-def autotune_enabled(default=True):
-    """Whether tuned dispatch consults the autotune winner cache at all
-    (``0`` = static defaults everywhere, no miss warnings)."""
-    v = os.environ.get("MXNET_AUTOTUNE")
-    if v is None:
-        return default
-    return v not in ("0", "")
-
-
-def autotune_cache_path(default=None):
-    """Cache-file override; None = the committed
-    ``tools/autotune_cache.json``."""
-    v = os.environ.get("MXNET_AUTOTUNE_CACHE")
-    if v is None or not v.strip():
-        return default
-    return v.strip()
-
-
 def lockscan_witness(default=False):
     """Whether the lock-acquisition witness is requested.  NOTE: the
     install itself happens at the top of ``mxnet_tpu/__init__`` from a
@@ -500,7 +462,7 @@ def describe():
              # subsystem-owned knobs (second docstring table); mxlint's
              # env-var-undocumented rule diffs this list against every
              # MXNET_* access in the codebase
-             "MXNET_ENGINE_DEBUG", "MXNET_DROPOUT_RNG",
+             "MXNET_ENGINE_DEBUG",
              "MXNET_TELEMETRY_STEADY_STEPS", "MXNET_PROFILE_RANK",
              "MXNET_PROFILE_DIR", "MXNET_KVSTORE_SPARSE_HOST_BOUND",
              "MXNET_TPU_MODEL_REPO", "MXNET_FAULTLINE",
@@ -514,7 +476,6 @@ def describe():
              "MXNET_SENTINEL_ROLLBACKS", "MXNET_KVSTORE_INTEGRITY",
              "MXNET_PARALLEL_RECIPE", "MXNET_RECIPE_STRICT",
              "MXNET_BLACKBOX", "MXNET_BLACKBOX_EVENTS",
-             "MXNET_BLACKBOX_DIR", "MXNET_AUTOTUNE",
-             "MXNET_AUTOTUNE_CACHE", "MXNET_LOCKSCAN_WITNESS",
+             "MXNET_BLACKBOX_DIR", "MXNET_LOCKSCAN_WITNESS",
              "MXNET_LOCKSCAN_REPORT"]
     return [(n, os.environ.get(n), n in __doc__) for n in names]

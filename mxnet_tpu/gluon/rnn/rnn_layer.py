@@ -26,26 +26,9 @@ from ..nn.basic_layers import _resolve_init
 __all__ = ["RNN", "LSTM", "GRU"]
 
 
-def _cell_step(mode, x_proj, h, c, h2h_w, h2h_b, gate_layout="fused"):
-    """One timestep; x_proj is the precomputed input projection.
-
-    ``gate_layout`` is the tuned LSTM recurrent-matmul shape: ``fused``
-    computes all gates as one (H, 4H) matmul then splits; ``split``
-    issues one (H, H) matmul per gate so each gate's activation chains
-    off a smaller contraction.  Which wins is shape/backend-dependent —
-    exactly why it is an autotune axis (kernel ``lstm_cell``) and not a
-    constant."""
-    if mode == "lstm" and gate_layout == "split":
-        xi, xf, xc, xo = jnp.split(x_proj, 4, axis=-1)
-        wi, wf, wc, wo = jnp.split(h2h_w, 4, axis=0)
-        bi, bf, bc, bo = jnp.split(h2h_b, 4)
-        i = jax.nn.sigmoid(xi + jnp.dot(h, wi.T) + bi)
-        f = jax.nn.sigmoid(xf + jnp.dot(h, wf.T) + bf)
-        cc = jnp.tanh(xc + jnp.dot(h, wc.T) + bc)
-        o = jax.nn.sigmoid(xo + jnp.dot(h, wo.T) + bo)
-        nc = f * c + i * cc
-        nh = o * jnp.tanh(nc)
-        return nh, nc
+def _cell_step(mode, x_proj, h, c, h2h_w, h2h_b):
+    """One timestep; x_proj is the precomputed input projection.  All
+    gates come from one recurrent (H, ngates*H) matmul, then split."""
     g = x_proj + jnp.dot(h, h2h_w.T) + h2h_b
     if mode == "rnn_relu":
         nh = jax.nn.relu(g)
@@ -77,29 +60,8 @@ def _cell_step(mode, x_proj, h, c, h2h_w, h2h_b, gate_layout="fused"):
 
 
 def _run_single_direction(mode, x_tnc, h0, c0, i2h_w, i2h_b, h2h_w, h2h_b,
-                          reverse=False, unroll=None, gate_layout=None):
-    """scan over time for one layer/direction. x: (T, N, C).
-
-    ``unroll`` (scan body replication — amortizes per-step control
-    overhead against the tiny per-step matmul) and ``gate_layout`` (see
-    `_cell_step`) are the LSTM cell's tuned parameters: left ``None``
-    they come from the autotune cache at trace time (kernel
-    ``lstm_cell``, one consult per traced shape), with the pre-tune
-    behavior — plain scan, fused 4H gate matmul — as the documented
-    static default on any miss.  Explicit values are sweep candidates
-    (tune/kernels.py forces them)."""
-    t, n, _ = x_tnc.shape
-    if mode == "lstm" and (unroll is None or gate_layout is None):
-        from ... import tune
-        tuned = tune.best(
-            "lstm_cell", tune.signature(x_tnc.dtype, b=n, t=t,
-                                        h=h0.shape[-1]),
-            {"unroll": 1, "gate_layout": "fused"})
-        unroll = tuned["unroll"] if unroll is None else unroll
-        gate_layout = tuned["gate_layout"] if gate_layout is None \
-            else gate_layout
-    unroll = 1 if unroll is None else int(unroll)
-    gate_layout = gate_layout or "fused"
+                          reverse=False):
+    """scan over time for one layer/direction. x: (T, N, C)."""
     if reverse:
         x_tnc = jnp.flip(x_tnc, axis=0)
     # batch the input projection over all timesteps: one MXU matmul
@@ -107,12 +69,15 @@ def _run_single_direction(mode, x_tnc, h0, c0, i2h_w, i2h_b, h2h_w, h2h_b,
 
     def step(carry, xp):
         h, c = carry
-        nh, nc = _cell_step(mode, xp, h, c, h2h_w, h2h_b,
-                            gate_layout=gate_layout)
+        nh, nc = _cell_step(mode, xp, h, c, h2h_w, h2h_b)
         return (nh, nc), nh
 
-    (hT, cT), out = jax.lax.scan(step, (h0, c0), x_proj,
-                                 unroll=min(unroll, t))
+    # One cell a loop iteration (`unroll` 1) over one fused gate matmul.
+    # Set on jax 0.4.37 on another machine, where a single timing read
+    # 1.24x for `unroll` 4 at `b32.h1024.t64` bf16; it was never repeated
+    # and this machine has no number for it.  Re-pick it from runs of the
+    # recurrent-scan cell (ROADMAP S5, W7) and name the ledger line here.
+    (hT, cT), out = jax.lax.scan(step, (h0, c0), x_proj, unroll=1)
     if reverse:
         out = jnp.flip(out, axis=0)
     return out, hT, cT
@@ -166,8 +131,7 @@ class _RNNLayer(HybridBlock):
         # reference `_RNNLayer.cast` also retargets self._dtype: without
         # it begin_state() keeps emitting float32 initial states, the
         # scan carry promotes every gate op, and layer >= 1 of a bf16
-        # model silently computes in f32 (and the lstm_cell autotune
-        # lookup misses on dtype)
+        # model silently computes in f32
         super().cast(dtype)
         self._dtype = dtype
 

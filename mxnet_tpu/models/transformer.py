@@ -39,19 +39,16 @@ __all__ = [
     "bert_partition_rules", "bert_base", "bert_large",
 ]
 
-# measured flash-vs-dense crossovers on one v5e chip with the round-4
-# Pallas kernel (benchmark/results/attention_tpu_v5e.json, discussion in
-# benchmark/ATTENTION_ANALYSIS.md).  Training (fwd+bwd): flash wins from
-# T=1024 up (0.67 vs 0.71 ms at 1024, 2.4 vs 3.8 at 2048, 9.7 vs 15.0
-# at 4096, 38 vs 58 at 8192) and is the only runnable path at T>=12288
-# where dense fails to compile.  Forward-only: XLA's fused dense
-# attention wins at short T (0.12 vs 0.24 ms at 1024), flash from 2048
-# up (0.91 vs 1.13 ms), and dense hits a reproducible HBM cliff at 8192
-# (903 vs 14 ms).  The CAUSAL crossovers were measured separately in
-# round 5 (results/attention_causal_tpu_v5e.json) with the
-# masked-block-skipping kernel and land on the SAME thresholds:
-# causal fwd+bwd crosses at 1024 (0.54 vs 0.69 ms), causal fwd-only at
-# 2048 (0.62 vs 1.16 ms) — so one pair of constants serves both.
+# Sequence lengths from which `use_flash="auto"` takes the Pallas kernel
+# over XLA's dense attention: the lower one when a backward pass follows
+# (flash's fwd+bwd kernels against dense's joint schedule), the higher
+# one forward-only (XLA's fused dense forward holds out longer).  Causal
+# and non-causal share the pair.  Dense also stops fitting HBM at long T;
+# flash is then the only path.  Set on jax 0.4.37 on another machine (the
+# timings are in git history, 1f4c461); this machine has no number for
+# either crossover — `bert_base.phase1_t128` sits below both and takes
+# dense.  Re-pick them from runs of W1 (`bert_base.phase2_t512`) and W6
+# (T >= 2048) and name the ledger lines here (ROADMAP S4).
 FLASH_AUTO_MIN_T = 2048           # fwd-only (inference) crossover
 FLASH_AUTO_MIN_T_TRAINING = 1024  # fwd+bwd crossover
 
@@ -82,9 +79,8 @@ class MultiHeadAttention(HybridBlock):
         # and bwd — the recipe-realistic BERT configuration stays on the
         # fast path); only full (B, T, S) attention masks still require
         # the dense path, and T must be <=128 or a multiple of 128.  The
-        # default "auto" picks flash per call once T reaches the measured
-        # crossover (FLASH_AUTO_MIN_T, from
-        # benchmark/results/attention_tpu_v5e.json) and every constraint
+        # default "auto" picks flash per call once T reaches the
+        # crossover (FLASH_AUTO_MIN_T*) and every constraint
         # holds; True forces it (and raises on violations), False forces
         # dense.
         # identity checks: `1 in (True, ...)` is True by equality
@@ -119,7 +115,7 @@ class MultiHeadAttention(HybridBlock):
         the ICI ring, and with flash eligible each ring step runs the
         Pallas kernel (lse-merged).  Composes with ``use_flash`` and the
         encoder-level ``remat`` boundary — the three long-context levers
-        stack (benchmark/ATTENTION_ANALYSIS.md, recipe section).
+        stack.
         Key-padding (B, T) masks thread through the ring (each ring step
         applies the resident K block's mask; the lse merge is
         mask-agnostic).  Attention dropout stays excluded here: per-step
@@ -151,10 +147,8 @@ class MultiHeadAttention(HybridBlock):
     def _flash_now(self, t, mask):
         """Resolve the use_flash policy for this call (T is trace-static,
         so the choice bakes into the compiled program per shape).  When a
-        backward pass is coming the LOWER training crossover applies —
-        the flash fwd+bwd kernels beat dense's joint schedule from
-        T=1024 up, while dense's fused forward holds out to T=2048 in
-        forward-only calls (ATTENTION_ANALYSIS.md)."""
+        backward pass is coming the LOWER training crossover applies
+        (see the `FLASH_AUTO_MIN_T*` note)."""
         if self._use_flash == "auto":
             # is_backward_expected covers every backward-bound path:
             # eager tape (recording), train_mode, FusedTrainStep /

@@ -149,7 +149,7 @@ class Node:
             if _engine_debug() else None)
 
 
-# Read ONCE at import (the _DROPOUT_RNG_IMPL convention, ADVICE r5):
+# Read ONCE at import (mxlint's env-read-at-trace-time contract):
 # Node.__init__ consults this on every recorded op, so a per-call environ
 # read was both hot-path overhead and a half-applied-config hazard — ops
 # recorded before an env change carried no versions while later ones did.

@@ -12,23 +12,14 @@ handled by `ops/invoke.py`).  Layouts follow the reference's defaults
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 import numpy as onp
 
 
-# Dropout RNG implementation, read ONCE at import (ADVICE r5): the value
-# is consulted inside traced dropout code, so a later env change could
-# never reach already-jitted executables — reading it per-call only made
-# that failure silent.  Set MXNET_DROPOUT_RNG before importing mxnet_tpu
-# (tests/benchmarks that must pin the stream do exactly that); the
-# programmatic escape hatch is `_dropout_key(key, impl=...)`.  See
-# docs/DESIGN.md ("Dropout RNG streams") for the threefry<->rbg
-# bitstream-change note.
-_DROPOUT_RNG_IMPL = os.environ.get("MXNET_DROPOUT_RNG", "rbg")
+# Dropout mask bits come from XLA's hardware RNG: see `_dropout_key`.
+_DROPOUT_RNG_IMPL = "rbg"
 
 
 def _tuplize(v, n):
@@ -550,22 +541,17 @@ def dropout(data, key, p=0.5, axes=None, mode="training"):
 
 
 def _dropout_key(key, impl=None):
-    """Dropout mask bits come from the XLA hardware RNG (`rbg`) by
-    default: threefry mask generation measured as 28% of a BERT-base
-    train step at T=128 and 43% at T=512 — switching the BULK draw to
-    RngBitGenerator recovered nearly all of it
-    (benchmark/results/bert_t_scaling_tpu_v5e.json, rbg/ drop pairs;
-    BERT_ANALYSIS.md round-5 section).  The key STREAM stays threefry
-    (cheap scalar fold_ins); only the per-site key re-wraps.  Same
-    Bernoulli marginals; bits are backend-stable but differ from the
-    threefry stream — set MXNET_DROPOUT_RNG=threefry for the old bits
-    (``impl`` overrides the env var; benchmarks pin it).  Reference
-    analogue: dropout uses the cuDNN/GPU hardware RNG, not the CPU one
-    (`src/operator/nn/dropout-inl.h`).  The env var is read once at
-    module import (`_DROPOUT_RNG_IMPL`): dropout sites run inside traced
-    programs, so a post-import change could never affect cached
-    executables anyway — pin it before importing mxnet_tpu, or pass
-    ``impl`` explicitly."""
+    """Dropout mask bits come from the XLA hardware RNG (`rbg`): the
+    BULK draw is one RngBitGenerator call where threefry is a chain of
+    integer rounds over every mask element.  Chosen on jax 0.4.37 on
+    another machine (the pairs are in git history, 1f4c461); this
+    machine has no rbg-against-threefry number, and the mask path is
+    still BERT's next item (ROADMAP S4 (b)).  The key STREAM stays
+    threefry (cheap scalar fold_ins); only the per-site key re-wraps.
+    Same Bernoulli marginals; bits are backend-stable but differ from
+    the threefry stream (``impl="threefry"`` gives those bits back;
+    tests pin it).  Reference analogue: dropout uses the cuDNN/GPU
+    hardware RNG, not the CPU one (`src/operator/nn/dropout-inl.h`)."""
     if impl is None:
         impl = _DROPOUT_RNG_IMPL
     if impl != "rbg":

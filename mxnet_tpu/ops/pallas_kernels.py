@@ -9,15 +9,15 @@ an online-softmax accumulator (single-chip analogue of
 `parallel/ring_attention.py`, which does the same blockwise math across
 chips).
 
-Design notes (benchmark/ATTENTION_ANALYSIS.md has the measurements):
+Design notes (the reasons are structural; the timings that first argued
+for them were taken under jax 0.4.37 on another machine and are in git
+history at 1f4c461, not in this tree):
 
 - **Blocks auto-size to q=512, k=1024** (largest power-of-two divisor
-  of T from those targets).  The round-3 kernel used 128x128 blocks: at
-  T=8192 that is ~131k grid invocations of tiny matmuls, and Mosaic's
-  per-iteration overhead alone (~1 us) explained the whole measured
-  115 ms.  Round 5's sweep found wide K blocks amortize the per-block
-  VPU softmax chain (49% of kernel time at 512x512): bk=1024 lifts fwd
-  from 39 to 67 TF/s (see _BLOCK_TARGET_K note).
+  of T from those targets, `_pick_block`).  Small blocks (128x128 is
+  ~131k grid steps of tiny matmuls at T=8192) pay Mosaic's
+  per-iteration overhead on every step; wide K blocks amortize the
+  per-block VPU softmax chain (see the `_BLOCK_TARGET_*` note).
 - **Dots run in the input dtype** (bf16 in production) with f32
   accumulation via `preferred_element_type` — upcasting q/k/v to f32
   *before* the dot quarters the MXU rate.  Tests feed f32 and stay
@@ -71,15 +71,16 @@ _NEG_INF = -1e30
 # block: the fully-masked-row sentinel.  Real scores are O(+-1e2); the
 # only way past the threshold is the _NEG_INF fill.
 _MASKED_ROW = -1e29
-# Default block targets, measured (benchmark/results/
-# flash_roofline_tpu_v5e.json block sweep): K blocks of 1024 beat 512 by
-# 1.68x fwd / 1.36x fwd+bwd at T=4096-8192 — the ablations attribute the
-# old kernel's gap to the per-block VPU softmax chain (49% of kernel
-# time), which wider K rows amortize (half the m/l merge + acc-rescale
-# rounds, better row-reduction vectorization).  Wider q blocks do
-# nothing (1024x512 ~= 512x512): the q loop is the outer grid, its
-# per-block work is already amortized.  bk=2048 ties 1024 within noise
-# and costs 2x the VMEM for the f32 score block — 1024 is the default.
+# Flash attention's default tile: targets for the q and k block sizes,
+# fitted to each T by `_pick_block` (explicit `block_q`/`block_k` win).
+# Wide K rows halve the m/l merge and acc-rescale rounds of the
+# per-block softmax chain; wider q blocks buy nothing (q is the outer
+# grid loop); bk=2048 doubles the VMEM of the f32 score block.  Set on
+# jax 0.4.37 on another machine (the sweep is in git history, 1f4c461);
+# this machine has no number for it: no cell runs the kernel.  Re-pick
+# it from runs of the first cell that does (ROADMAP S4: W1
+# `bert_base.phase2_t512`, W6 long-sequence) and name the ledger line
+# here.
 _BLOCK_TARGET_Q = 512
 _BLOCK_TARGET_K = 1024
 # Odd golden-ratio constant folding the batch*head index into the
@@ -101,15 +102,15 @@ def _prec(dt):
 
 def _pick_block(t, want):
     """Largest power-of-two block <= want dividing t (>=8; t itself only
-    for tiny sequences or genuinely odd T).  Cached autotune winners are
-    fed through here as TARGETS, so a bucket entry (t=1024) stays legal
-    for every concrete length in the bucket (t=1000 -> 8).
+    for tiny sequences or genuinely odd T).  The block targets go
+    through here, so one target is legal for every concrete length
+    (t=1000 -> 8).
 
     The floor is 8, not 128: T=1000-style lengths have no pow2 divisor
-    >=128, and the old whole-T fallback silently built a single-block
-    kernel whose (T, T) f32 score tile can blow VMEM at large T — a
-    small block is slow but correct; sizes below 8 lose the f32 sublane
-    tile and can't happen for even T anyway."""
+    >=128, and a whole-T fallback builds a single-block kernel whose
+    (T, T) f32 score tile can blow VMEM at large T — a small block is
+    slow but correct; sizes below 8 lose the f32 sublane tile and can't
+    happen for even T anyway."""
     if t <= want:
         return t
     b = want
@@ -302,26 +303,17 @@ def _sds(shape, dtype, like):
 
 def _resolve(qd, block_q, block_k, scale, interpret):
     """Resolve block sizes for one flash launch.  Explicit blocks win;
-    otherwise the autotune cache is consulted once per (shape-bucket,
-    dtype, device) key through `tune.best` — a miss falls back to the
-    static `_BLOCK_TARGET_Q/_K` defaults with one warning.  Either way
-    the chosen sizes are TARGETS re-fitted by `_pick_block`, so a
-    cached pow2 winner stays legal for non-pow2 lengths in its bucket
-    (and bit-parity holds for the forward output and dq — the q split
-    never reorders their accumulation; dk/dv accumulate across
-    q-blocks, so only an unchanged block_q keeps them bit-stable)."""
-    b, h, t, d = qd.shape
-    tq, tk = block_q, block_k
-    if tq is None or tk is None:
-        from .. import tune
-        tuned = tune.best(
-            "flash_attention", tune.signature(qd.dtype, b=b, h=h, t=t, d=d),
-            {"block_q": _BLOCK_TARGET_Q, "block_k": _BLOCK_TARGET_K})
-        tq = tuned["block_q"] if tq is None else tq
-        tk = tuned["block_k"] if tk is None else tk
-        bq, bk = _pick_block(t, tq), _pick_block(t, tk)
+    otherwise `_BLOCK_TARGET_Q/_K` are fitted to T by `_pick_block`.
+    (Bit-parity across tilings holds for the forward output and dq —
+    the q split never reorders their accumulation; dk/dv accumulate
+    across q-blocks, so only an unchanged block_q keeps them
+    bit-stable.)"""
+    t, d = qd.shape[2:]
+    if block_q is None or block_k is None:
+        bq = _pick_block(t, _BLOCK_TARGET_Q if block_q is None else block_q)
+        bk = _pick_block(t, _BLOCK_TARGET_K if block_k is None else block_k)
     else:
-        bq, bk = min(tq, t), min(tk, t)
+        bq, bk = min(block_q, t), min(block_k, t)
     if t % bq or t % bk:
         raise ValueError(
             f"block sizes ({bq}, {bk}) must divide sequence length {t}; "
@@ -995,15 +987,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     the identical mask from the same seed: nothing is stored, and the
     fwd/bwd masks are bit-identical by construction (tested).  The
     bitstream is backend-stable (same mask on TPU and in interpret
-    mode) and is NOT the `MXNET_DROPOUT_RNG` stream — it is the
+    mode) and is NOT `npx.dropout`'s `rbg` stream — it is the
     kernel's own documented stream.
 
     Validated exact on real TPU (vs XLA dense).  When the (T, T) score
     matrix FITS in HBM comfortably, plain XLA attention is still faster
-    — use this kernel at the measured crossovers
-    (`models/transformer.FLASH_AUTO_MIN_T*`,
-    benchmark/ATTENTION_ANALYSIS.md) and `parallel.ring_attention` when
-    the sequence is sharded across chips.
+    — use this kernel past the crossovers
+    (`models/transformer.FLASH_AUTO_MIN_T*`) and
+    `parallel.ring_attention` when the sequence is sharded across chips.
 
     Traced inside a `parallel.mesh_scope` (a mesh-sharded
     `FusedTrainStep`) the kernel launches once per device over its share

@@ -1,5 +1,4 @@
-"""Space-to-depth ResNet stem (the tuned kernel that retires the stem
-MFU waiver).
+"""Space-to-depth ResNet stem.
 
 The classic 7x7/stride-2 stem is the census's worst roofline offender:
 at 3 input channels the MXU contraction dim is 3*7*7 = 147 done as a
@@ -34,10 +33,10 @@ Two lowerings:
   census profiles (interpret-mode Pallas in a lowered HLO would hide
   the real cost model) and the CPU-mesh default.
 * :func:`stem_conv_pallas` — the production TPU kernel: XLA-built
-  im2col patches + one Pallas-tiled (M, 192) @ (192, C) matmul, tile
-  sizes (tm, tn) read from the autotune cache through ``tune.best``.
-  K is never split, so every tile choice is bit-identical (the
-  tuned-vs-default parity test rides this).
+  im2col patches + one Pallas-tiled (M, 192) @ (192, C) matmul at
+  tile sizes (tm, tn) = `STEM_TILE_DEFAULT`.  K is never split, so
+  every tile choice is bit-identical (the tiling parity test rides
+  this).
 """
 from __future__ import annotations
 
@@ -53,8 +52,12 @@ __all__ = ["space_to_depth2", "fold_stem_kernel", "stem_conv",
            "s2d_stem_conv", "stem_conv_pallas", "reference_stem_conv",
            "stem_conv_auto", "STEM_TILE_DEFAULT"]
 
-# the documented static fallback for a tune.best miss (also
-# tune/kernels.py _stem_default — keep in sync)
+# The stem matmul's tile: targets for the (M, C_out) output block, fitted
+# to the shape by `_fit_tile` (explicit `tm`/`tn` win).  Set on jax
+# 0.4.37 on another machine; this machine has no number for it: no zoo
+# model uses `SpaceToDepthStem`.  ROADMAP S9 decides the layer's fate —
+# wired into `resnet50_v1`, the tile is re-picked from runs of
+# `resnet50_v1.train` and this comment names the ledger line.
 STEM_TILE_DEFAULT = {"tm": 512, "tn": 128}
 
 
@@ -152,8 +155,8 @@ def _stem_matmul_fwd(flat, w2d, tm, tn, interpret):
 
 
 def _stem_matmul_bwd(tm, tn, interpret, res, ct):
-    # XLA dots: tile-choice-independent, so tuned-vs-default gradients
-    # are bitwise identical for free
+    # XLA dots: tile-choice-independent, so gradients are bitwise
+    # identical across tilings for free
     flat, w2d = res
     ctf = ct.astype(jnp.float32)
     dflat = jnp.dot(ctf, w2d.astype(jnp.float32).T).astype(flat.dtype)
@@ -166,18 +169,13 @@ _stem_matmul_vjp.defvjp(_stem_matmul_fwd, _stem_matmul_bwd)
 
 def stem_conv_pallas(xs, wf, tm=None, tn=None, interpret=None):
     """Production TPU form of :func:`stem_conv`: im2col patches (XLA)
-    feeding one Pallas-tiled matmul.  ``tm``/``tn`` default to the
-    autotune cache (kernel ``stem_s2d``); explicit values are sweep
-    candidates.  K (= 4*C_in*16) is never split across tiles, so every
-    (tm, tn) choice produces bit-identical results."""
+    feeding one Pallas-tiled matmul.  ``tm``/``tn`` default to
+    `STEM_TILE_DEFAULT`.  K (= 4*C_in*16) is never split across tiles,
+    so every (tm, tn) choice produces bit-identical results."""
     b, c_packed, h2, w2 = xs.shape
     c_out = wf.shape[0]
-    if tm is None or tn is None:
-        from .. import tune
-        sig = tune.signature(xs.dtype, b=b, c=c_out, h=2 * h2, w=2 * w2)
-        params = tune.best("stem_s2d", sig, STEM_TILE_DEFAULT)
-        tm = params["tm"] if tm is None else tm
-        tn = params["tn"] if tn is None else tn
+    tm = STEM_TILE_DEFAULT["tm"] if tm is None else tm
+    tn = STEM_TILE_DEFAULT["tn"] if tn is None else tn
     # (B, C_patch, H2, W2) with C_patch ordered (channel, kh, kw) —
     # exactly wf's (4*C_in, 4, 4) flattening
     patches = jax.lax.conv_general_dilated_patches(

@@ -41,8 +41,10 @@ def ring_attention_local(q, k, v, axis_name, causal=False, scale=None,
     instead of a dense einsum: each step produces an exact (out, lse)
     partial for the resident K/V block, merged across ring steps with
     log-sum-exp arithmetic — per-chip memory stays O(T_local * block)
-    even while T_local is long, compounding the kernel-level crossovers
-    (benchmark/ATTENTION_ANALYSIS.md) with the ICI ring.
+    even while T_local is long.  Whether the kernel beats the einsum
+    per ring step is `models/transformer.FLASH_AUTO_MIN_T*`'s question
+    (set on another toolchain; nothing across chips has been timed on
+    this machine: ROADMAP M8, W6).
     """
     axis_size = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
@@ -114,11 +116,10 @@ def _ring_flash(q, k, v, axis_name, axis_size, my_idx, causal, scale,
     Ring step i processes the K/V block that started at position
     my_idx - i, so step 0 is ALWAYS the local (diagonal) block — it runs
     peeled, with the causal kernel (which skips its own fully-masked
-    sub-blocks, benchmark/ATTENTION_ANALYSIS.md round-5 table), and the
-    scanned steps all use the unmasked kernel (off-diagonal blocks are
-    either fully visible or, for causal, fully masked — handled by
-    discarding their lse).  No per-device branching between two pallas
-    programs is needed.
+    sub-blocks), and the scanned steps all use the unmasked kernel
+    (off-diagonal blocks are either fully visible or, for causal, fully
+    masked — handled by discarding their lse).  No per-device branching
+    between two pallas programs is needed.
 
     A key-padding mask needs no merge-side handling at all: each step
     passes the resident block's (B, T_local) mask slice into the kernel,

@@ -92,9 +92,8 @@ def test_engine_debug_flags_stale_read(monkeypatch):
     stale-read warning at backward — the gradient describes the value at
     record time.
 
-    The env var is read ONCE at import (mxlint env-read-at-trace-time;
-    the _DROPOUT_RNG_IMPL convention), so the test toggles the module
-    flag, not the environment."""
+    The env var is read ONCE at import (mxlint env-read-at-trace-time),
+    so the test toggles the module flag, not the environment."""
     import warnings
 
     from mxnet_tpu import autograd

@@ -161,10 +161,9 @@ def test_env_inventory_matches_describe_exactly():
 
 
 def test_engine_debug_env_read_once_at_import():
-    """MXNET_ENGINE_DEBUG follows the _DROPOUT_RNG_IMPL convention: read
-    once at import (it is consulted per recorded op on the tape hot
-    path), so setting it pre-import works and post-import changes are
-    inert."""
+    """MXNET_ENGINE_DEBUG is read once at import (it is consulted per
+    recorded op on the tape hot path), so setting it pre-import works
+    and post-import changes are inert."""
     code = """
         import mxnet_tpu as mx
         from mxnet_tpu.ops import invoke
@@ -179,12 +178,10 @@ def test_engine_debug_env_read_once_at_import():
     assert "engine-debug-ok" in r.stdout
 
 
-def test_dropout_rng_env_read_once_at_import(monkeypatch):
-    """ADVICE r5: MXNET_DROPOUT_RNG is consulted inside traced code, so
-    a post-import change could never reach cached executables — it is
-    now read ONCE at module import.  Changing the env afterwards must
-    have no effect (no silent half-applied state); the programmatic
-    `impl=` override still works."""
+def test_dropout_rng_env_var_is_inert_and_impl_overrides(monkeypatch):
+    """`MXNET_DROPOUT_RNG` is gone: dropout's bit generator is the
+    constant `rbg` (it had one value in use).  Setting the variable has
+    no effect; the programmatic `impl=` override still works."""
     import jax
     import numpy as onp
 
@@ -194,10 +191,10 @@ def test_dropout_rng_env_read_once_at_import(monkeypatch):
     before = jax.random.key_data(_nn._dropout_key(key))
     monkeypatch.setenv("MXNET_DROPOUT_RNG", "threefry")
     after = jax.random.key_data(_nn._dropout_key(key))
-    # env change post-import: ignored (default rbg re-wrap in both)
+    # the variable is read nowhere (rbg re-wrap in both)
     assert (onp.asarray(before) == onp.asarray(after)).all()
-    assert _nn._DROPOUT_RNG_IMPL == "rbg"  # the baked-in default
-    # explicit impl override bypasses the baked value
+    assert _nn._DROPOUT_RNG_IMPL == "rbg"  # the constant
+    # explicit impl override bypasses the constant
     tf = _nn._dropout_key(key, impl="threefry")
     assert jax.random.key_data(tf).size == 2       # untouched threefry key
     assert jax.random.key_data(_nn._dropout_key(key)).size == 4  # rbg wrap

@@ -535,6 +535,65 @@ def test_flash_small_sequence_blocks_clamp():
     assert out.shape == (1, 1, 8, 4)
 
 
+# within budget the whole T is one block; over budget, 384 = 2^7 * 3 steps
+# down to its largest pow2 divisor <= want, never up.  1000 = 8 * 125 has no
+# pow2 divisor >= 128: a whole-T fallback would build a single-block kernel
+# whose (T, T) f32 score tile blows VMEM at large T, so the walk goes down to
+# 8.  Odd T has no pow2 divisor at all: degenerate single block
+@pytest.mark.parametrize("t,want,block", [
+    (384, 512, 384), (384, 256, 128), (384, 64, 64), (1000, 512, 8),
+    (999, 512, 999)])
+def test_pick_block(t, want, block):
+    from mxnet_tpu.ops.pallas_kernels import _pick_block
+    assert _pick_block(t, want) == block
+    assert t % block == 0
+
+
+# what a launch without explicit blocks runs with: the pairs the parent
+# (1f4c461) resolved on this machine, where its tile cache always missed
+@pytest.mark.parametrize("t,blocks", [
+    (128, (128, 128)), (256, (256, 256)), (384, (384, 384)),
+    (512, (512, 512)), (1000, (8, 1000)), (1024, (512, 1024)),
+    (2048, (512, 1024)), (4096, (512, 1024)), (8192, (512, 1024))])
+def test_resolve_default_blocks(t, blocks):
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    qd = jax.ShapeDtypeStruct((2, 4, t, 64), jnp.bfloat16)
+    bq, bk, _, _ = pk._resolve(qd, None, None, None, False)
+    assert (bq, bk) == blocks
+    assert t % bq == 0 and t % bk == 0
+    assert bq <= pk._BLOCK_TARGET_Q and bk <= pk._BLOCK_TARGET_K
+    # one explicit block leaves the other at its default
+    assert pk._resolve(qd, 8, None, None, False)[:2] == (8, bk)
+
+
+def test_flash_q_tiling_bit_parity_fwd_bwd():
+    """A q-block split is bitwise-identical to the default tile in the
+    forward output and dq (the q split never changes their accumulation
+    order; block_k is pinned because the k split reassociates the softmax
+    accumulation).  dk/dv DO accumulate across q-blocks — there a block_q
+    change reorders the f32 sums, so the contract is allclose."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (jnp.asarray(x) for x in _qkv(3, t=256, d=16))
+
+    def run(**blocks):
+        def f(q, k, v):
+            return flash_attention(q, k, v, **blocks).astype(jnp.float32).sum()
+        return (flash_attention(q, k, v, **blocks),
+                jax.grad(f, argnums=(0, 1, 2))(q, k, v))
+
+    out_d, (gq_d, gk_d, gv_d) = run()              # default: one 256 block
+    out_s, (gq_s, gk_s, gv_s) = run(block_q=64, block_k=256)
+    assert onp.array_equal(onp.asarray(out_d), onp.asarray(out_s))
+    assert onp.array_equal(onp.asarray(gq_d), onp.asarray(gq_s))
+    for ga, gb in ((gk_d, gk_s), (gv_d, gv_s)):
+        onp.testing.assert_allclose(onp.asarray(ga), onp.asarray(gb),
+                                    rtol=1e-5, atol=1e-6)
+
+
 def test_mha_auto_flash_policy(monkeypatch):
     """use_flash='auto' (the default) picks flash only on TPU, above the
     measured crossover; key-padding masks and attention dropout are

@@ -232,7 +232,7 @@ def test_discovered_env_vars_sees_known_sites():
     inv = discovered_env_vars()
     assert "MXNET_SEED" in inv
     assert any(p == "mxnet_tpu/env.py" for p, _l in inv["MXNET_SEED"])
-    assert "MXNET_DROPOUT_RNG" in inv     # read in ops/nn.py
+    assert "MXNET_TELEMETRY_STEADY_STEPS" in inv  # telemetry/watchdog.py
     assert "MXNET_ENGINE_DEBUG" in inv    # hoisted read in ops/invoke.py
 
 

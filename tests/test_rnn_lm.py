@@ -67,6 +67,33 @@ def test_rnn_lm_trains():
     assert losses[-1] < losses[0] * 0.5, losses[::10]
 
 
+def test_lstm_cast_bf16_both_layers_scan_in_bf16(monkeypatch):
+    """`_RNNLayer.cast` must retarget self._dtype (reference behavior):
+    otherwise begin_state() emits float32 initial states, the scan carry
+    promotes every gate op, and layer >= 1 of a bf16 model silently
+    computes in f32."""
+    import jax
+    from mxnet_tpu.gluon import rnn
+
+    lstm = rnn.LSTM(64, num_layers=2, layout="TNC", input_size=64)
+    lstm.initialize()
+    lstm.cast("bfloat16")
+    x = mx.np.array(onp.random.RandomState(0).randn(5, 2, 64),
+                    dtype="bfloat16")
+
+    carries = []
+    scan = jax.lax.scan
+
+    def spy(f, init, xs, **kw):
+        carries.append([str(c.dtype) for c in init] + [str(xs.dtype)])
+        return scan(f, init, xs, **kw)
+
+    monkeypatch.setattr(jax.lax, "scan", spy)
+    out = lstm(x)
+    assert str(out.dtype) == "bfloat16"
+    assert carries == [["bfloat16"] * 3] * 2     # h, c, x_proj; per layer
+
+
 def test_bucket_sentence_iter():
     onp.random.seed(2)
     sentences = [list(onp.random.randint(1, 20, onp.random.randint(3, 15)))

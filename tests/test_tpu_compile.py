@@ -176,8 +176,8 @@ def test_ffn_gelu_epilogue_is_one_erf(one_chip):
 
 @pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["mask", "mask+dropout"])
 def test_flash_fwd_bwd_compiles(one_chip, dropout):
-    """The configuration `benchmark/bert_pretrain_bench.py` trains at
-    B=4, T=2048: key-padding mask, attention dropout in-kernel."""
+    """What `chip_smoke.py`'s BERT step launches at B=4, T=2048:
+    key-padding mask, attention dropout in-kernel."""
     qkv = jax.ShapeDtypeStruct((4, 12, 2048, 64), BF16, sharding=one_chip)
     mask = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
     seed = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # CI entry (reference: ci/build.py + runtime_functions.sh stages).
-# Stages: lint | lockscan | import | hloscan | census | autotune | smoke
-# | test | chaos | storm | endure | blackbox | perf | dryrun | all
+# Stages: lint | lockscan | import | hloscan | census | smoke | test |
+# chaos | storm | endure | blackbox | perf | dryrun | all
 # (default: all).
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -58,17 +58,6 @@ run_census() {
   # mesh (docs/OBSERVABILITY.md "Layer census"; waive on the contract
   # with a reason, grandfather with --update-baseline)
   python -m tools.layerscope --verdicts
-}
-run_autotune() {
-  # kernel-parameter cache gate (ISSUE 18): the committed
-  # tools/autotune_cache.json must parse, fingerprint the current
-  # toolchain, cover every registered (kernel, signature), carry no
-  # stale entries, and re-derive every model-mode winner bit-for-bit
-  # (docs/AUTOTUNE.md; no baseline — findings are hard FAILs, fix by
-  # re-sweeping with --update-cache; opt out with MXTPU_AUTOTUNE_GATE=0)
-  if [ "${MXTPU_AUTOTUNE_GATE:-1}" != "0" ]; then
-    python -m tools.autotune --verdicts
-  fi
 }
 run_smoke()  { bash tools/smoke.sh; }
 run_test()   {
@@ -278,7 +267,6 @@ case "$stage" in
   import)  run_import ;;
   hloscan) run_hloscan ;;
   census)  run_census ;;
-  autotune) run_autotune ;;
   smoke)   run_smoke ;;
   test)    run_test ;;
   chaos)   run_chaos ;;
@@ -288,7 +276,6 @@ case "$stage" in
   perf)    run_perf ;;
   dryrun)  run_dryrun ;;
   all)     run_lint; run_lockscan; run_import; run_hloscan; run_census
-           run_autotune
            run_smoke; run_test; run_chaos; run_storm; run_endure
            run_blackbox; run_perf; run_dryrun ;;
   *) echo "unknown stage $stage" >&2; exit 2 ;;

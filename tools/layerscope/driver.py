@@ -96,7 +96,7 @@ def render_table(doc, out=None):
 
 def top_sag(doc, n=5):
     """Top-``n`` layers by % of step time with their bound class — the
-    bench rider's ``layer_census_top_sag`` summary."""
+    ``layer_census_top_sag`` summary."""
     rows = [r for r in doc["rows"]][:n]
     return [f"{r['layer']}@{r['phase']} {r['pct_time']:.1f}% {r['bound']}"
             for r in rows]

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # Pre-commit smoke gate (VERDICT r1 "Next round" #1): never ship a snapshot
-# that cannot import, train a step, or start the bench.  Run from repo root:
+# that cannot import or train a step.  Run from repo root:
 #   bash tools/smoke.sh
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -271,13 +271,6 @@ assert _rec_d >= 1, "recoveries not booked under kind=flaky"
 assert _storm_wall < 10.0, f"retry storm blew the wall budget: {_storm_wall}"
 print(f"smoke: flaky-kv retry storm ok ({int(_ret_d)} retries, "
       f"{int(_rec_d)} flaky recoveries, {_storm_wall:.1f}s)")
-
-# 3. bench.py must at least import (its main guard must not run)
-import importlib.util as _u
-spec = _u.spec_from_file_location("bench", "bench.py")
-m = _u.module_from_spec(spec)
-spec.loader.exec_module(m)
-print("smoke: bench import ok")
 EOF
 
 # 3b. quick compiled-program contract gate (ISSUE 7): the cheap
@@ -340,38 +333,12 @@ assert dp == tp, f"recipe dp2.tp2 diverged from the dp oracle: {dp} vs {tp}"
 print(f"smoke: recipe dp2.tp2 parity ok (3-step losses {tp})")
 EOF
 
-# 3e. autotune dispatch gate (ISSUE 18): the flash blocks the kernel
-# would actually launch with must come from the committed cache entry —
-# if dispatch silently falls back to static defaults (cache unreadable,
-# fingerprint drift, signature mismatch) this fires.  The full cache
-# gate (coverage, stale entries, model re-derivation) runs in ci.sh's
-# autotune stage.
-python - <<'EOF'
-import jax
-import jax.numpy as jnp
-
-from mxnet_tpu import tune
-from mxnet_tpu.ops.pallas_kernels import _pick_block, _resolve
-
-b, h, t, d = 8, 8, 4096, 64   # the attention bench shape
-entry = tune.lookup("flash_attention",
-                    tune.signature(jnp.bfloat16, b=b, h=h, t=t, d=d))
-assert entry is not None, \
-    "committed cache has no flash_attention entry for the bench shape"
-qd = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16)
-bq, bk, _, _ = _resolve(qd, None, None, None, None)
-want = (_pick_block(t, entry["block_q"]), _pick_block(t, entry["block_k"]))
-assert (bq, bk) == want, \
-    f"flash dispatch chose {(bq, bk)} but the cache pins {want}"
-print(f"smoke: autotuned flash blocks ok (bq={bq}, bk={bk} from cache)")
-EOF
-
 # 4. the driver entry points compile on the virtual mesh (the full
-# hloscan + census + recipe + autotune dryrun riders run in ci.sh's
-# dryrun stage, not here — 3d/3e above cover the quick checks)
+# hloscan + census + recipe dryrun riders run in ci.sh's
+# dryrun stage, not here — 3d above covers the quick checks)
 MXTPU_DRYRUN_HLOSCAN=0 MXTPU_DRYRUN_CENSUS=0 MXTPU_DRYRUN_RESILIENCE=0 \
   MXTPU_DRYRUN_FLEET=0 MXTPU_DRYRUN_GRAY=0 MXTPU_DRYRUN_RECIPE=0 \
-  MXTPU_DRYRUN_AUTOTUNE=0 MXTPU_DRYRUN_LOCKSCAN=0 \
+  MXTPU_DRYRUN_LOCKSCAN=0 \
   python -c "
 import __graft_entry__ as g
 g.dryrun_multichip(8)
