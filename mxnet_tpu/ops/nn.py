@@ -528,6 +528,24 @@ def leaky_relu(data, gamma=None, act_type="leaky", slope=0.25,
 
 
 def dropout(data, key, p=0.5, axes=None, mode="training"):
+    """Inverted dropout: keep each slot with probability ``1 - p`` and scale
+    the kept ones by ``1 / (1 - p)``; ``axes`` names the axes the mask
+    varies over (it is broadcast over the others).  ``p == 0`` and every
+    mode but ``"training"`` return ``data`` itself.
+
+    The keep-mask is made once, behind `lax.optimization_barrier`: one
+    fusion reads the random words and writes the mask as ``pred``, and every
+    consumer, forward and backward, reads that one tensor.  Without the
+    barrier XLA repeats the compare in a backward fusion, because a compare
+    is cheap (PERF.md, PR 29: it duplicates what is cheap into every
+    consumer), and then the words themselves, 4 bytes a slot where 1 was
+    already written, are copied to HBM to outlive the forward pass and
+    fetched back: in a BERT-base step at batch 128, T=128, 24 buffers of
+    50.3 MB, 1.21 GB of its 10.04 GB of temporaries (PERF.md, PR 31; ledger,
+    PR 31, `bert_base.phase1_t128`).  The mask is ``pred``, so the barrier
+    bars no cotangent; the draw, the compare and the `where` are unchanged
+    and so are the bits (tests/test_gluon.py); tests/test_tpu_compile.py
+    holds the compiled step to one reader per random tensor."""
     if p == 0.0 or mode != "training":
         return data
     shape = list(data.shape)
@@ -536,7 +554,8 @@ def dropout(data, key, p=0.5, axes=None, mode="training"):
             if i not in axes:
                 shape[i] = 1
     keep = 1.0 - p
-    mask = jax.random.bernoulli(_dropout_key(key), keep, tuple(shape))
+    mask = lax.optimization_barrier(
+        jax.random.bernoulli(_dropout_key(key), keep, tuple(shape)))
     return jnp.where(mask, data / keep, 0).astype(data.dtype)
 
 
@@ -545,8 +564,10 @@ def _dropout_key(key, impl=None):
     BULK draw is one RngBitGenerator call where threefry is a chain of
     integer rounds over every mask element.  Chosen on jax 0.4.37 on
     another machine (the pairs are in git history, 1f4c461); this
-    machine has no rbg-against-threefry number, and the mask path is
-    still BERT's next item (ROADMAP S4 (b)).  The key STREAM stays
+    machine has no rbg-against-threefry number.  What the words cost
+    after the draw is `dropout`'s to hold down (written once as
+    ``pred``, PR 31); what is left of the mask path on the chip, the
+    attention masks' relayout, is ROADMAP S4 (b).  The key STREAM stays
     threefry (cheap scalar fold_ins); only the per-site key re-wraps.
     Same Bernoulli marginals; bits are backend-stable but differ from
     the threefry stream (``impl="threefry"`` gives those bits back;

@@ -1,11 +1,15 @@
 """Gluon blocks (reference: tests/python/unittest/test_gluon.py)."""
+import jax
+import jax.numpy as jnp
 import numpy as onp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd
 from mxnet_tpu.gluon import nn, Parameter, Trainer, loss as gloss
 from mxnet_tpu.gluon.parameter import DeferredInitializationError
+from mxnet_tpu.ops import nn as nn_ops
 from mxnet_tpu.test_utils import assert_almost_equal
 
 
@@ -129,6 +133,70 @@ def test_dropout_train_vs_eval():
     out_eval = do(x)
     assert (out_eval.asnumpy() == 1).all()
     assert (out_train.asnumpy() == 0).sum() > 10  # some dropped
+
+
+def _dropout_as_before(x, key, p, axes):
+    """`ops.nn.dropout` as it read before the mask went behind a barrier
+    (PR 31): the expression the new form has to equal bit for bit."""
+    shape = tuple(n if not axes or i in axes else 1
+                  for i, n in enumerate(x.shape))
+    keep = 1.0 - p
+    mask = jax.random.bernoulli(nn_ops._dropout_key(key), keep, shape)
+    return jnp.where(mask, x / keep, 0).astype(x.dtype)
+
+
+def _under(transform):
+    """fn(x, g, key) -> fn run under one of the transforms a dropout site
+    meets: a jitted step, a vmapped cell, a rematerialised block, a batch
+    sharded over four devices."""
+    if transform == "jit":
+        return jax.jit
+    if transform == "vmap":
+        def batched(fn):
+            def run(x, g, key):
+                return jax.vmap(fn)(x, g, jax.random.split(key, x.shape[0]))
+            return run
+        return batched
+    if transform == "checkpoint":
+        return lambda fn: jax.jit(jax.checkpoint(fn))
+    mesh = Mesh(onp.asarray(jax.devices()[:4]), ("dp",))
+    rows, rep = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+    return lambda fn: jax.jit(fn, in_shardings=(rows, rows, rep),
+                              out_shardings=(rows, rows))
+
+
+@pytest.mark.parametrize("transform", ["jit", "vmap", "checkpoint", "dp4"])
+@pytest.mark.parametrize("axes", [None, (0, 2)], ids=["full", "axes02"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_dropout_mask_bits_unchanged(dtype, axes, transform):
+    """The keep-mask is made once, behind `lax.optimization_barrier`, so
+    that a compiled step writes it once (tests/test_tpu_compile.py); the
+    draw, the compare and the `where` are the old ones, so value and
+    gradient are the old bits under every transform."""
+    key = jax.random.key(31)
+    kx, kg, kd = jax.random.split(key, 3)
+    x = jax.random.normal(kx, (8, 16, 128), jnp.float32).astype(dtype)
+    g = jax.random.normal(kg, x.shape, jnp.float32).astype(dtype)
+
+    def value_and_grad(drop):
+        def fn(x, g, key):
+            out, pull = jax.vjp(lambda v: drop(v, key, 0.1, axes), x)
+            return out, pull(g)[0]
+        return fn
+
+    new = _under(transform)(value_and_grad(nn_ops.dropout))(x, g, kd)
+    old = _under(transform)(value_and_grad(_dropout_as_before))(x, g, kd)
+    for a, b in zip(new, old):
+        assert a.dtype == b.dtype == x.dtype and a.shape == x.shape
+        assert onp.asarray(a).tobytes() == onp.asarray(b).tobytes()
+    dropped = float((onp.asarray(new[0], "float32") == 0).mean())
+    assert 0.02 < dropped < 0.25
+
+
+@pytest.mark.parametrize("p, mode", [(0.0, "training"), (0.5, "eval")])
+def test_dropout_off_returns_its_input(p, mode):
+    x = jnp.ones((4, 8))
+    assert nn_ops.dropout(x, jax.random.key(0), p=p, mode=mode) is x
 
 
 def test_conv2d():

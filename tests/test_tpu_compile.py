@@ -1,7 +1,8 @@
-"""Compile the training path's Pallas kernels, and BatchNorm's backward,
-for a TPU v5e that is *described*, not attached: what Mosaic or the SPMD
-partitioner would refuse on the chip, it refuses here, and what the TPU
-compiler would materialise, it shows here, at no chip time.
+"""Compile the training path's Pallas kernels, BatchNorm's backward, BERT's
+FFN and a two-layer cut of the BERT cell's step for a TPU v5e that is
+*described*, not attached: what Mosaic or the SPMD partitioner would refuse
+on the chip, it refuses here, and what the TPU compiler would materialise,
+it shows here, at no chip time.
 
 Nothing runs, so nothing here is a result or a time — `chip_smoke.py` is the
 run.  The CPU mesh never lowers these kernels (off-TPU they are interpreted
@@ -172,6 +173,58 @@ def test_ffn_gelu_epilogue_is_one_erf(one_chip):
     # and nowhere else: not in the operands of the FFN-down matmul forward,
     # nor of its weight gradient
     assert len(re.findall(r"= f32\[128,128,3072\]\S* erf\(", text)) == 2
+
+
+def _bert_cell_step(one_chip, layers, batch, seq):
+    """The benchmark's `bert_base` step (`chipbench/configs/bert_base`: MLM +
+    NSP loss, Adam, one `FusedTrainStep`, dense attention at this length)
+    cut to ``layers`` layers, compiled for the described chip from the shapes
+    of the arguments the step itself prepares."""
+    import mxnet_tpu as mx
+    from chipbench import run as bench
+    from chipbench.configs import bert_base
+
+    cfg = dict(bench.load_json(bench.HERE, "configs", "bert_base.json"),
+               num_hidden_layers=layers)
+    fused = mx.gluon.FusedTrainStep(*bert_base.build(cfg))
+    ints = tuple(mx.np.zeros((batch, seq), dtype="int32") for _ in range(4))
+    *arrays, clip, treedef_id = fused._prepare(ints, batch)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        arrays)
+    return fused._jit.lower(*shapes, clip, treedef_id).compile()
+
+
+def test_dropout_mask_is_written_once(one_chip):
+    """The guard of PR 31's gain, on the benchmark cell's own step at batch
+    128, T=128 and two layers (a bare Dense → dropout → residual does not
+    show the fault): every dropout mask's random words -- or, for the
+    attention masks, their one relayout `copy` -- are read by exactly one
+    fusion, the compare that writes the mask as `pred`, and by nothing else.
+    Without the barrier in `ops.nn.dropout` XLA repeats the compare in a
+    backward fusion, so the hidden-size words (4 bytes a slot, not 1) are
+    copied out of the fast memory to HBM to outlive the forward pass and
+    sliced back: 4 `copy-start`s of `u32[128,128,768]` here, 24 in the cell."""
+    compiled = _bert_cell_step(one_chip, layers=2, batch=128, seq=128)
+    comps, entry, _, _ = census._parse_computations(compiled.as_text())
+    entry = comps[entry]
+
+    def readers(name):
+        return [i for i in entry if name in i.operand_names]
+
+    draws = [i for i in entry if i.opcode == "rng-bit-generator"]
+    shapes = sorted(i.result.split("{")[0] for i in draws)
+    # embedding, and per layer: attention output and FFN; per layer: attention
+    assert shapes == ["u32[128,12,128,128]"] * 2 + ["u32[128,128,768]"] * 5
+    for draw in draws:
+        read_by = readers(draw.name)
+        if [i.opcode for i in read_by] == ["copy"]:     # the relayout
+            read_by = readers(read_by[0].name)
+        assert [i.opcode for i in read_by] == ["fusion"], \
+            (draw.name, [(i.opcode, i.name) for i in read_by])
+        assert read_by[0].result.startswith("pred["), read_by[0].result
+    assert not [i.name for i in entry
+                if i.opcode == "copy-start" and "u32[128,128,768]" in i.result]
 
 
 @pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["mask", "mask+dropout"])
