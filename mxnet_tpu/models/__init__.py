@@ -8,6 +8,9 @@ parameters carry partition rules so the same Block runs single-chip or
 dp/tp/sp-sharded over a `jax.sharding.Mesh` unchanged.
 """
 from .rnn_lm import RNNModel, rnn_lm_partition_rules
+from .decoder import (
+    RMSNorm, GroupedQueryAttention, DecoderLayer, DecoderLM, CausalLMLoss,
+)
 from .transformer import (
     MultiHeadAttention,
     PositionwiseFFN,
@@ -25,4 +28,6 @@ __all__ = [
     "MultiHeadAttention", "PositionwiseFFN", "TransformerEncoderLayer",
     "TransformerEncoder", "BertModel", "BertForPretraining",
     "bert_partition_rules", "bert_base", "bert_large",
+    "RMSNorm", "GroupedQueryAttention", "DecoderLayer", "DecoderLM",
+    "CausalLMLoss",
 ]

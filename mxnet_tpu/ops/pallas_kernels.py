@@ -83,6 +83,19 @@ _MASKED_ROW = -1e29
 # here.
 _BLOCK_TARGET_Q = 512
 _BLOCK_TARGET_K = 1024
+# Under a sliding window the band is `window` keys wide and a q block
+# computes whole every K block the band touches; every grid step, live or
+# skipped, costs about 0.3 us.  Set from my chip runs, PR 32 (TPU v5 lite,
+# q (2, 32, 8192, 128) on 4 key-value heads, window 1024, bf16; forward /
+# forward + backward, ms): 1024 x 1024 5.46 / 21.71, 512 x 1024 6.68 /
+# 24.46, 1024 x 512 8.08 / 25.93, 512 x 512 8.96 / 27.47, 256 x 1024
+# 8.98 / 30.68, 256 x 512 12.69 / 38.46, 1024 x 256 16.41 / 39.16, 256 x
+# 256 21.07 / 60.21 (the band's FLOPs are 1.31 ms forward at peak).  The
+# same runs read full causal attention at 512 x 1024 (the default above)
+# 11.78 / 42.53 and at 1024 x 1024 10.39 / 39.03: the default was not
+# moved, BERT's masked and dropped-out calls have no run at that tile.
+_WINDOW_BLOCK_TARGET_Q = 1024
+_WINDOW_BLOCK_TARGET_K = 1024
 # Odd golden-ratio constant folding the batch*head index into the
 # threefry key (bijective in uint32, so distinct heads get distinct
 # keys).
@@ -121,12 +134,44 @@ def _pick_block(t, want):
     return t  # odd T: no pow2 divisor at all — degenerate, single block
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, transposed=False):
-    """Mask s (q-major), or s^T when ``transposed`` (k-major rows)."""
+def _causal_mask(s, qi, ki, block_q, block_k, transposed=False,
+                 window=None):
+    """Mask s (q-major), or s^T when ``transposed`` (k-major rows).  With
+    a ``window`` a query also loses the keys more than window-1 behind."""
     q_ax, k_ax = (1, 0) if transposed else (0, 1)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_ax)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_ax)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = seen & (q_pos - k_pos < window)
+    return jnp.where(seen, s, _NEG_INF)
+
+
+def _k_range(qi, block_q, block_k, nk, causal, window):
+    """(first, last) K block that q block ``qi`` sees any key of."""
+    last = ((qi + 1) * block_q - 1) // block_k if causal else nk - 1
+    first = 0 if window is None else \
+        jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+    return first, last
+
+
+def _q_range(ki, block_q, block_k, nq, causal, window):
+    """(first, last) Q block that sees any key of K block ``ki``."""
+    first = (ki * block_k) // block_q if causal else 0
+    last = nq - 1 if window is None else \
+        jnp.minimum(((ki + 1) * block_k + window - 2) // block_q, nq - 1)
+    return first, last
+
+
+def _between(i, first, last, causal, window, diagonal="last"):
+    """The block-skip predicate of a causal (and windowed) grid step: the
+    causal bound alone (``last`` on a q-major grid, ``first`` on a k-major
+    one), both under a window, None when every block is live."""
+    if not causal:
+        return None
+    if window is not None:
+        return (i >= first) & (i <= last)
+    return i <= last if diagonal == "last" else i >= first
 
 
 # ---------------------------------------------------------------------------
@@ -259,15 +304,19 @@ def _bias_bh(bb, hb, h):
     return lambda bh: bh
 
 
-def _ck_factory(block_q, block_k, causal, masked, nh):
+def _ck_factory(block_q, block_k, causal, masked, nh, window=None):
     """Fetch-index clamp for q-major grids.  Causal: K blocks past the
     diagonal re-fetch the last valid block (copy elided by Mosaic).
+    Window: blocks wholly behind the band fetch the band's first block.
     Masked: blocks past the batch row's `kend` (scalar-prefetched)
     clamp the same way, so padded tails move no HBM traffic."""
     def ck(bh, qi, ki, refs):
         j = ki
         if causal:
             j = jnp.minimum(j, ((qi + 1) * block_q - 1) // block_k)
+        if window is not None:
+            j = jnp.maximum(j, _k_range(qi, block_q, block_k, None, True,
+                                        window)[0])
         if masked:
             kend = refs[0][bh // nh]
             j = jnp.minimum(j, jnp.maximum(kend - 1, 0) // block_k)
@@ -275,15 +324,20 @@ def _ck_factory(block_q, block_k, causal, masked, nh):
     return ck
 
 
-def _cq_factory(block_q, block_k, causal, masked, nh, nq):
+def _cq_factory(block_q, block_k, causal, masked, nh, nq, window=None):
     """Fetch-index clamp for k-major grids.  Causal: Q blocks before the
-    diagonal re-fetch the first valid block.  Masked: K rows entirely
-    past `kend` freeze the fetch at the final q block (the index the
-    previous live row ended on), so dead rows move no HBM traffic."""
+    diagonal re-fetch the first valid block.  Window: Q blocks wholly
+    past the band re-fetch the band's last block.  Masked: K rows
+    entirely past `kend` freeze the fetch at the final q block (the
+    index the previous live row ended on), so dead rows move no HBM
+    traffic."""
     def cq(bh, ki, qi, refs):
         j = qi
         if causal:
             j = jnp.maximum(j, (ki * block_k) // block_q)
+        if window is not None:
+            j = jnp.minimum(j, _q_range(ki, block_q, block_k, nq, True,
+                                        window)[1])
         if masked:
             alive = ki * block_k < refs[0][bh // nh]
             j = jnp.where(alive, j, nq - 1)
@@ -301,17 +355,21 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _resolve(qd, block_q, block_k, scale, interpret):
+def _resolve(qd, block_q, block_k, scale, interpret, window=None):
     """Resolve block sizes for one flash launch.  Explicit blocks win;
-    otherwise `_BLOCK_TARGET_Q/_K` are fitted to T by `_pick_block`.
+    otherwise `_BLOCK_TARGET_Q/_K` (`_WINDOW_BLOCK_TARGET_Q/_K` under a
+    window) are fitted to T by `_pick_block`.
     (Bit-parity across tilings holds for the forward output and dq —
     the q split never reorders their accumulation; dk/dv accumulate
     across q-blocks, so only an unchanged block_q keeps them
     bit-stable.)"""
     t, d = qd.shape[2:]
     if block_q is None or block_k is None:
-        bq = _pick_block(t, _BLOCK_TARGET_Q if block_q is None else block_q)
-        bk = _pick_block(t, _BLOCK_TARGET_K if block_k is None else block_k)
+        want_q, want_k = (_BLOCK_TARGET_Q, _BLOCK_TARGET_K) \
+            if window is None else \
+            (_WINDOW_BLOCK_TARGET_Q, _WINDOW_BLOCK_TARGET_K)
+        bq = _pick_block(t, want_q if block_q is None else block_q)
+        bk = _pick_block(t, want_k if block_k is None else block_k)
     else:
         bq, bk = min(block_q, t), min(block_k, t)
     if t % bq or t % bk:
@@ -332,7 +390,7 @@ def _alive(causal_cond, masked_cond, body):
 
 
 def _pallas(kernel, grid, in_specs, out_specs, out_shape, scratch,
-            interp, masked, operands, kend):
+            interp, masked, operands, kend, name):
     """One entry for both regimes: a plain grid, or (masked) a
     PrefetchScalarGridSpec shipping `kend` ahead of the operands so the
     BlockSpec index maps can clamp fetches on it."""
@@ -341,19 +399,19 @@ def _pallas(kernel, grid, in_specs, out_specs, out_shape, scratch,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch)
         return pl.pallas_call(kernel, grid_spec=grid_spec,
-                              out_shape=out_shape,
-                              interpret=interp)(kend, *operands)
+                              out_shape=out_shape, interpret=interp,
+                              name=name)(kend, *operands)
     return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          scratch_shapes=scratch,
-                          interpret=interp)(*operands)
+                          scratch_shapes=scratch, interpret=interp,
+                          name=name)(*operands)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
-                has_bias, thr, inv_keep):
+                has_bias, thr, inv_keep, window=None):
     i = 1 if masked else 0
     kend_ref = refs[0] if masked else None
     q_ref, kt_ref, v_ref = refs[i:i + 3]
@@ -380,8 +438,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
     # at the last VALID block, not nk-1.  Masked: the same skip applies
     # past the batch row's kend (scalar-prefetched) — scratch state
     # persists across skipped steps, so the epilogue condition is
-    # unchanged.
-    last_ki = ((qi + 1) * block_q - 1) // block_k if causal else nk - 1
+    # unchanged.  Window: K blocks wholly behind the band are skipped the
+    # same way; a row may then see nothing of the band's first block, so
+    # its exponent anchors at 0 as a fully-masked row's does.
+    first_ki, last_ki = _k_range(qi, block_q, block_k, nk, causal, window)
 
     @pl.when(ki == 0)
     def _init():
@@ -400,14 +460,14 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window=window)
         if masked:
             s = jnp.where(mask_ref[0] != 0, s, _NEG_INF)   # (1, bk) bcast
 
         m_prev = m_ref[...]                    # (block_q, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        if masked:
+        if masked or window is not None:
             # fully-masked-so-far rows: exp(s - m) would be exp(0)=1 with
             # both at _NEG_INF; anchoring the exponent at 0 keeps p = 0
             m_exp = jnp.where(m_new > _MASKED_ROW, m_new, 0.0)
@@ -428,7 +488,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
             preferred_element_type=jnp.float32, precision=_prec(v.dtype))
         m_ref[...] = m_new
 
-    _alive(ki <= last_ki if causal else None,
+    _alive(_between(ki, first_ki, last_ki, causal, window),
            ki * block_k < kend_ref[bh // nh] if masked else None,
            _compute)
 
@@ -439,33 +499,46 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
         lse_ref[0] = m_ref[...] + jnp.log(l)     # (block_q, 1)
 
 
+def _kv_head(h, hkv):
+    """Index map from a (batch*head) grid index to the row of K and V
+    reshaped (B*Hkv, T, D): query head i reads key-value head
+    i // (H/Hkv), so grouped-query K and V are never repeated in HBM."""
+    if hkv == h:
+        return lambda bh: bh
+    group = h // hkv
+    return lambda bh: (bh // h) * hkv + (bh % h) // group
+
+
 def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, window=None):
     b, h, t, d = qd.shape
-    bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret)
+    hkv = kd.shape[1]
+    bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret,
+                                  window)
     nk = t // bk
     masked = mask is not None
     has_bias = bias is not None
     drop = float(dropout or 0.0)
 
     qr = qd.reshape(b * h, t, d)
-    ktr = kd.reshape(b * h, t, d).swapaxes(1, 2)   # (bh, D, T)
-    vr = vd.reshape(b * h, t, d)
+    ktr = kd.reshape(b * hkv, t, d).swapaxes(1, 2)   # (b*hkv, D, T)
+    vr = vd.reshape(b * hkv, t, d)
     kernel = functools.partial(
         _fwd_kernel, scale=sc, causal=causal, block_q=bq, block_k=bk,
         nk=nk, nh=h, masked=masked, has_bias=has_bias,
         thr=_keep_threshold(1.0 - drop) if drop else None,
-        inv_keep=1.0 / (1.0 - drop) if drop else 1.0)
+        inv_keep=1.0 / (1.0 - drop) if drop else 1.0, window=window)
     # Causal/masked: clamp the K/V fetch index for skipped (fully-masked)
     # blocks to the last valid one — an unchanged block index means Mosaic
     # elides the copy, so skipped grid steps move no HBM traffic.
-    ck = _ck_factory(bq, bk, causal, masked, h)
+    ck = _ck_factory(bq, bk, causal, masked, h, window)
+    kvh = _kv_head(h, hkv)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, d, bk),
-                     lambda bh, qi, ki, *r: (bh, 0, ck(bh, qi, ki, r))),
+                     lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
         pl.BlockSpec((1, bk, d),
-                     lambda bh, qi, ki, *r: (bh, ck(bh, qi, ki, r), 0)),
+                     lambda bh, qi, ki, *r: (kvh(bh), ck(bh, qi, ki, r), 0)),
     ]
     operands = [qr, ktr, vr]
     kend = None
@@ -504,7 +577,8 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        interp=interp, masked=masked, operands=operands, kend=kend)
+        interp=interp, masked=masked, operands=operands, kend=kend,
+        name="flash_fwd")
     return out.reshape(b, h, t, d), lse.reshape(b, h, t)
 
 
@@ -523,7 +597,7 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
 # gradients instead of exp(+huge) garbage.
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
-                   has_bias, thr, inv_keep):
+                   has_bias, thr, inv_keep, window=None):
     i = 1 if masked else 0
     kend_ref = refs[0] if masked else None
     q_ref, kt_ref, k_ref, vt_ref, do_ref, lse_ref, dl_ref = refs[i:i + 7]
@@ -543,7 +617,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    last_ki = ((qi + 1) * block_q - 1) // block_k if causal else nk - 1
+    first_ki, last_ki = _k_range(qi, block_q, block_k, nk, causal, window)
 
     @pl.when(ki == 0)
     def _init():
@@ -564,7 +638,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window=window)
         if masked:
             s = jnp.where(mask_ref[0] != 0, s, _NEG_INF)
             lse = jnp.where(lse > _MASKED_ROW, lse, 0.0)
@@ -581,7 +655,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
             preferred_element_type=jnp.float32,
             precision=_prec(k.dtype))
 
-    _alive(ki <= last_ki if causal else None,
+    _alive(_between(ki, first_ki, last_ki, causal, window),
            ki * block_k < kend_ref[bh // nh] if masked else None,
            _compute)
 
@@ -591,7 +665,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, nh, masked,
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, nh, masked,
-                    has_bias, thr, inv_keep):
+                    has_bias, thr, inv_keep, window=None, nhkv=None):
     i = 1 if masked else 0
     kend_ref = refs[0] if masked else None
     (qt_ref, q_ref, k_ref, v_ref, dot_ref, do_ref, lse_ref,
@@ -612,11 +686,23 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, nh, masked,
     bh = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    # Grouped-query heads: grid axis 0 runs over (batch, key-value head)
+    # and axis 2 over (query head of the group, q block), so dk and dv
+    # accumulate over every query head that reads this key-value head.
+    group = 1 if nhkv is None else nh // nhkv
+    if group > 1:
+        g, qi = qi // nq, qi % nq
+        bh = _q_head(nh, nhkv)(bh, g)
     # Causal, k-major: Q blocks strictly before the diagonal see nothing
     # of this K block; the first contributing block holds position ki*bk.
-    first_qi = (ki * block_k) // block_q if causal else 0
+    # Window: nor do Q blocks wholly past ki's last key + window - 1.
+    first_qi, last_qi = _q_range(ki, block_q, block_k, nq, causal, window)
 
-    @pl.when(qi == first_qi)
+    first_step = qi == first_qi
+    if group > 1:
+        first_step = first_step & (g == 0)
+
+    @pl.when(first_step)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -638,7 +724,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, nh, masked,
         if has_bias:
             st = st + bias_ref[0].astype(jnp.float32)
         if causal:
-            st = _causal_mask(st, qi, ki, block_q, block_k, transposed=True)
+            st = _causal_mask(st, qi, ki, block_q, block_k, transposed=True,
+                              window=window)
         if masked:
             st = jnp.where(mask_ref[0] != 0, st, _NEG_INF)  # (bk, 1) bcast
             lse = jnp.where(lse > _MASKED_ROW, lse, 0.0)
@@ -665,20 +752,35 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, nh, masked,
             preferred_element_type=jnp.float32,
             precision=_prec(q.dtype))
 
-    _alive(qi >= first_qi if causal else None,
+    _alive(_between(qi, first_qi, last_qi, causal, window, "first"),
            ki * block_k < kend_ref[bh // nh] if masked else None,
            _compute)
 
-    @pl.when(qi == nq - 1)
+    last_step = qi == last_qi
+    if group > 1:
+        last_step = last_step & (g == group - 1)
+
+    @pl.when(last_step)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _q_head(h, hkv):
+    """Index map from a (batch*key-value head) grid index and the query
+    head's place in its group to the (batch*head) row of Q."""
+    group = h // hkv
+    return lambda bkv, g: (bkv // hkv) * h + (bkv % hkv) * group + g
+
+
 def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
-                    scale, dropout, block_q, block_k, interpret, dlse=None):
+                    scale, dropout, block_q, block_k, interpret, dlse=None,
+                    window=None):
     b, h, t, d = qd.shape
-    bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret)
+    hkv = kd.shape[1]
+    group = h // hkv
+    bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret,
+                                  window)
     nq, nk = t // bq, t // bk
     masked = mask is not None
     has_bias = bias is not None
@@ -694,8 +796,8 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
         delta = delta - dlse.astype(jnp.float32)
 
     qr = qd.reshape(b * h, t, d)
-    kr = kd.reshape(b * h, t, d)
-    vr = vd.reshape(b * h, t, d)
+    kr = kd.reshape(b * hkv, t, d)
+    vr = vd.reshape(b * hkv, t, d)
     dor = ct.reshape(b * h, t, d)
     qtr = qr.swapaxes(1, 2)                    # (bh, D, T)
     ktr = kr.swapaxes(1, 2)
@@ -706,8 +808,9 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
     lse_row = lse.reshape(b * h, 1, t)         # k-major kernels broadcast
     dlt_row = delta.reshape(b * h, 1, t)       # over score ROWS
 
-    ck = _ck_factory(bq, bk, causal, masked, h)
-    cq = _cq_factory(bq, bk, causal, masked, h, nq)
+    ck = _ck_factory(bq, bk, causal, masked, h, window)
+    cq = _cq_factory(bq, bk, causal, masked, h, nq, window)
+    kvh = _kv_head(h, hkv)
     kend = _kend(mask) if masked else None
     if has_bias:
         bb, hb = bias.shape[0], bias.shape[1]
@@ -718,11 +821,11 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, d, bk),
-                     lambda bh, qi, ki, *r: (bh, 0, ck(bh, qi, ki, r))),
+                     lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
         pl.BlockSpec((1, bk, d),
-                     lambda bh, qi, ki, *r: (bh, ck(bh, qi, ki, r), 0)),
+                     lambda bh, qi, ki, *r: (kvh(bh), ck(bh, qi, ki, r), 0)),
         pl.BlockSpec((1, d, bk),
-                     lambda bh, qi, ki, *r: (bh, 0, ck(bh, qi, ki, r))),
+                     lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, *r: (bh, qi, 0)),
@@ -745,41 +848,59 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
         functools.partial(_bwd_dq_kernel, scale=sc, causal=causal,
                           block_q=bq, block_k=bk, nk=nk, nh=h,
                           masked=masked, has_bias=has_bias, thr=thr,
-                          inv_keep=inv_keep),
+                          inv_keep=inv_keep, window=window),
         (b * h, nq, nk), in_specs,
         out_specs=pl.BlockSpec((1, bq, d),
                                lambda bh, qi, ki, *r: (bh, qi, 0)),
         out_shape=_sds((b * h, t, d), qd.dtype, qr),
         scratch=[pltpu.VMEM((bq, d), jnp.float32)],
-        interp=interp, masked=masked, operands=operands, kend=kend)
+        interp=interp, masked=masked, operands=operands, kend=kend,
+        name="flash_bwd_dq")
+
+    # k-major grid: axis 0 over (batch, key-value head), axis 2 over q
+    # blocks — of every query head of the group in turn, when grouped
+    if group == 1:
+        def qh(bh, j):
+            return bh
+
+        def qb(bh, ki, j, r):
+            return cq(bh, ki, j, r)
+    else:
+        q_head = _q_head(h, hkv)
+
+        def qh(bkv, j):
+            return q_head(bkv, j // nq)
+
+        def qb(bkv, ki, j, r):
+            return cq(qh(bkv, j), ki, j % nq, r)
 
     in_specs = [
         pl.BlockSpec((1, d, bq),
-                     lambda bh, ki, qi, *r: (bh, 0, cq(bh, ki, qi, r))),
+                     lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
         pl.BlockSpec((1, bq, d),
-                     lambda bh, ki, qi, *r: (bh, cq(bh, ki, qi, r), 0)),
-        pl.BlockSpec((1, bk, d), lambda bh, ki, qi, *r: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, d), lambda bh, ki, qi, *r: (bh, ki, 0)),
+                     lambda bh, ki, j, *r: (qh(bh, j), qb(bh, ki, j, r), 0)),
+        pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
+        pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
         pl.BlockSpec((1, d, bq),
-                     lambda bh, ki, qi, *r: (bh, 0, cq(bh, ki, qi, r))),
+                     lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
         pl.BlockSpec((1, bq, d),
-                     lambda bh, ki, qi, *r: (bh, cq(bh, ki, qi, r), 0)),
+                     lambda bh, ki, j, *r: (qh(bh, j), qb(bh, ki, j, r), 0)),
         pl.BlockSpec((1, 1, bq),
-                     lambda bh, ki, qi, *r: (bh, 0, cq(bh, ki, qi, r))),
+                     lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
         pl.BlockSpec((1, 1, bq),
-                     lambda bh, ki, qi, *r: (bh, 0, cq(bh, ki, qi, r))),
+                     lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
     ]
     operands = [qtr, qr, kr, vr, dotr, dor, lse_row, dlt_row]
     if masked:
         # k-major: the mask selects score ROWS — column layout (B, T, 1)
         operands.append(mask.reshape(b, t, 1))
         in_specs.append(pl.BlockSpec(
-            (1, bk, 1), lambda bh, ki, qi, *r: (bh // h, ki, 0)))
+            (1, bk, 1), lambda bh, ki, j, *r: (bh // hkv, ki, 0)))
     if has_bias:
         operands.append(btr)
         in_specs.append(pl.BlockSpec(
             (1, bk, bq),
-            lambda bh, ki, qi, *r: (bmap(bh), ki, cq(bh, ki, qi, r))))
+            lambda bh, ki, j, *r: (bmap(qh(bh, j)), ki, qb(bh, ki, j, r))))
     if drop:
         operands.append(seed)
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -787,22 +908,24 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
         functools.partial(_bwd_dkv_kernel, scale=sc, causal=causal,
                           block_q=bq, block_k=bk, nq=nq, nh=h,
                           masked=masked, has_bias=has_bias, thr=thr,
-                          inv_keep=inv_keep),
-        (b * h, nk, nq), in_specs,
+                          inv_keep=inv_keep, window=window,
+                          nhkv=None if group == 1 else hkv),
+        (b * hkv, nk, group * nq), in_specs,
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi, *r: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, qi, *r: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
         ],
         out_shape=[
-            _sds((b * h, t, d), kd.dtype, qr),
-            _sds((b * h, t, d), vd.dtype, qr),
+            _sds((b * hkv, t, d), kd.dtype, qr),
+            _sds((b * hkv, t, d), vd.dtype, qr),
         ],
         scratch=[pltpu.VMEM((bk, d), jnp.float32),
                  pltpu.VMEM((bk, d), jnp.float32)],
-        interp=interp, masked=masked, operands=operands, kend=kend)
+        interp=interp, masked=masked, operands=operands, kend=kend,
+        name="flash_bwd_dkv")
 
-    return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-            dv.reshape(b, h, t, d))
+    return (dq.reshape(b, h, t, d), dk.reshape(b, hkv, t, d),
+            dv.reshape(b, hkv, t, d))
 
 
 def _zero_cts(mask, bias, seed):
@@ -817,57 +940,60 @@ def _zero_cts(mask, bias, seed):
     return dmask, dbias, dseed
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash(qd, kd, vd, mask, bias, seed, causal, scale, dropout, block_q,
-           block_k, interpret):
+           block_k, interpret, window):
     out, _lse = _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale,
-                               dropout, block_q, block_k, interpret)
+                               dropout, block_q, block_k, interpret, window)
     return out
 
 
 def _flash_fwd(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
-               block_q, block_k, interpret):
+               block_q, block_k, interpret, window):
     out, lse = _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale,
-                              dropout, block_q, block_k, interpret)
+                              dropout, block_q, block_k, interpret, window)
     return out, (qd, kd, vd, mask, bias, seed, out, lse)
 
 
-def _flash_bwd(causal, scale, dropout, block_q, block_k, interpret, res,
-               ct):
+def _flash_bwd(causal, scale, dropout, block_q, block_k, interpret, window,
+               res, ct):
     qd, kd, vd, mask, bias, seed, out, lse = res
     dq, dk, dv = _flash_backward(qd, kd, vd, mask, bias, seed, out, lse,
                                  ct, causal, scale, dropout, block_q,
-                                 block_k, interpret)
+                                 block_k, interpret, window=window)
     return (dq, dk, dv) + _zero_cts(mask, bias, seed)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_lse(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
-               block_q, block_k, interpret):
+               block_q, block_k, interpret, window):
     """Flash attention returning (out, lse) — the log-sum-exp output is
     what lets independently-computed attention partials merge exactly
     (ring attention's per-ring-step building block)."""
     return _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale,
-                          dropout, block_q, block_k, interpret)
+                          dropout, block_q, block_k, interpret, window)
 
 
 def _flash_lse_fwd(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, window):
     out, lse = _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale,
-                              dropout, block_q, block_k, interpret)
+                              dropout, block_q, block_k, interpret, window)
     return (out, lse), (qd, kd, vd, mask, bias, seed, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, dropout, block_q, block_k, interpret,
-                   res, cts):
+                   window, res, cts):
     qd, kd, vd, mask, bias, seed, out, lse = res
     ct, dlse = cts
     dq, dk, dv = _flash_backward(qd, kd, vd, mask, bias, seed, out, lse,
                                  ct, causal, scale, dropout, block_q,
-                                 block_k, interpret, dlse=dlse)
+                                 block_k, interpret, dlse=dlse,
+                                 window=window)
     return (dq, dk, dv) + _zero_cts(mask, bias, seed)
 
 
@@ -893,7 +1019,9 @@ def _flash_over_mesh(qd, kd, vd, mi, bi, seed, *static):
         return tuple(names) if n % _mesh.axes_size(mesh, names) == 0 else ()
 
     b_ax = axes_dividing(b, _mesh.current_batch_axes())
-    h_ax = axes_dividing(h, ("tp",) if "tp" in mesh.axis_names else ())
+    # fewer key-value heads than query heads: both must divide
+    h_ax = axes_dividing(kd.shape[1],
+                         ("tp",) if "tp" in mesh.axis_names else ())
     qkv = P(b_ax or None, h_ax or None, None, None)
 
     def local(qd, kd, vd, mi, bi, seed):
@@ -915,7 +1043,7 @@ def _flash_over_mesh(qd, kd, vd, mi, bi, seed, *static):
 
 
 def _entry(fn, q, k, v, causal, scale, block_q, block_k, interpret, mask,
-           bias, dropout, key, name):
+           bias, dropout, key, name, window=None):
     from ..ndarray.ndarray import NDArray
 
     drop = float(dropout or 0.0)
@@ -927,12 +1055,21 @@ def _entry(fn, q, k, v, causal, scale, block_q, block_k, interpret, mask,
             "(npx.flash_attention draws one from the mx.random stream)")
     seed = _seed_words(key) if drop else None
     b, h, t = q.shape[0], q.shape[1], q.shape[2]
+    if k.shape != v.shape or h % k.shape[1] or \
+            k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]:
+        raise ValueError(
+            f"flash_attention takes k and v of one shape (B, Hkv, T, D) "
+            f"with Hkv dividing q's {h} heads; got q {q.shape}, "
+            f"k {k.shape}, v {v.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash_attention's window (>= 1 keys, the query's "
+                         "own among them) needs causal=True")
 
     def f(qd, kd, vd, maskd=None, biasd=None):
         mi = None if maskd is None else _norm_mask(maskd)
         bi = None if biasd is None else _bias_4d(biasd, b, h, t)
         return fn(qd, kd, vd, mi, bi, seed, causal, scale, drop, block_q,
-                  block_k, interpret)
+                  block_k, interpret, window)
 
     args = (q, k, v, mask, bias)
     if any(isinstance(a, NDArray) for a in args):
@@ -942,7 +1079,8 @@ def _entry(fn, q, k, v, causal, scale, block_q, block_k, interpret, mask,
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              block_q=None, block_k=None, interpret=None,
-                             mask=None, bias=None, dropout=0.0, key=None):
+                             mask=None, bias=None, dropout=0.0, key=None,
+                             window=None):
     """`flash_attention` that also returns the per-query log-sum-exp
     (B, H, T) in f32.  Partials over disjoint K/V shards merge exactly:
     ``lse = logaddexp(lse_a, lse_b); out = out_a*exp(lse_a-lse) +
@@ -953,13 +1091,23 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     merge."""
     return _entry(_flash_lse, q, k, v, causal, scale, block_q, block_k,
                   interpret, mask, bias, dropout, key,
-                  "flash_attention_with_lse")
+                  "flash_attention_with_lse", window)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, mask=None, bias=None,
-                    dropout=0.0, key=None):
-    """Blockwise (flash) attention: q/k/v (B, H, T, D) -> (B, H, T, D).
+                    dropout=0.0, key=None, window=None):
+    """Blockwise (flash) attention: q (B, H, T, D), k/v (B, Hkv, T, D)
+    -> (B, H, T, D).
+
+    Grouped-query heads: Hkv divides H and query head i reads key-value
+    head i // (H/Hkv) through the BlockSpec index maps — K and V are
+    never repeated in HBM — and the dk+dv kernel sums over the query
+    heads of a group.  ``window`` (with ``causal``): query t sees key j
+    iff 0 <= t - j < window.  K blocks wholly outside that band run no
+    dot and move no bytes, forward and in both backward kernels (the
+    same skip and fetch-clamp as the causal diagonal's), so at T >>
+    window a layer costs about window/T of a full one's blocks.
 
     Exact attention; the full score matrix is never materialized, in
     forward or backward (both are Pallas kernels streaming K/V blocks —
@@ -1003,4 +1151,4 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     """
     return _entry(_flash_over_mesh, q, k, v, causal, scale, block_q,
                   block_k, interpret, mask, bias, dropout, key,
-                  "flash_attention")
+                  "flash_attention", window)

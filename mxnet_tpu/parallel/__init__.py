@@ -14,8 +14,9 @@ from .recipe import ShardingRecipe, parse_recipe
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
 from .pipeline import pipeline_apply
-from .moe import moe_ffn, init_moe_params, moe_partition_specs, shard_moe_params
-from .layers import MoEFFN, GPipeMLP
+from .moe import (moe_ffn, init_moe_params, moe_partition_specs,
+                  shard_moe_params, route_top_k, routed_experts, expert_loads)
+from .layers import MoEFFN, RoutedExperts, GPipeMLP
 
 __all__ = [
     "make_mesh", "current_mesh", "mesh_scope", "data_sharding",
@@ -23,6 +24,7 @@ __all__ = [
     "global_put", "shard_put",
     "constrain", "ring_attention", "ulysses_attention", "init_distributed",
     "pipeline_apply", "moe_ffn", "init_moe_params", "moe_partition_specs",
-    "shard_moe_params", "MoEFFN", "GPipeMLP",
+    "shard_moe_params", "MoEFFN", "GPipeMLP", "RoutedExperts",
+    "route_top_k", "routed_experts", "expert_loads",
     "ShardingRecipe", "parse_recipe", "RuleCoverage",
 ]

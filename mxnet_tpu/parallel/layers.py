@@ -22,7 +22,7 @@ from ..initializer import Normal, Zero
 from ..ops.invoke import invoke
 from .mesh import PartitionSpec as P
 
-__all__ = ["MoEFFN", "GPipeMLP"]
+__all__ = ["MoEFFN", "RoutedExperts", "GPipeMLP"]
 
 
 class MoEFFN(HybridBlock):
@@ -71,6 +71,83 @@ class MoEFFN(HybridBlock):
             (prefix + r"(w1|w2)$", P(axis_name, None, None)),
             (prefix + r"(b1|b2)$", P(axis_name, None)),
             (prefix + r"router$", P()),
+        ]
+
+
+class RoutedExperts(HybridBlock):
+    """Sparse SwiGLU experts behind a softmax top-k router, as a Gluon
+    layer that is TOLD which experts it holds: ``experts_held`` contiguous
+    experts from ``ep_rank * experts_held``.  The router scores all
+    ``num_experts`` (in f32) and picks ``top_k`` with renormalised
+    weights; the layer computes the part of the result its own experts
+    give (`parallel.moe.routed_experts`: sorted dispatch that drops no
+    row, a grouped matmul).  With ``experts_held == num_experts`` it is
+    the whole layer; on one chip of an ``ep`` group it runs without the
+    exchange, and nothing stands in for it.
+
+    Forward: ``x (B, T, U) -> y (B, T, U)``.  Each training step also
+    writes ``expert_load`` (held,), the rows every held expert received,
+    as auxiliary state (BatchNorm's moving statistics take the same
+    route): no host sync inside the step; `parallel.moe.expert_loads()`
+    reads and publishes it.
+    """
+
+    def __init__(self, units, hidden, num_experts, top_k, experts_held=None,
+                 ep_rank=0, dtype="float32"):
+        super().__init__()
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= ep_rank * held <= num_experts - held:
+            raise ValueError(
+                f"experts {ep_rank * held}..{(ep_rank + 1) * held - 1} are "
+                f"not among {num_experts}")
+        self._top_k = top_k
+        self.first_expert = ep_rank * held
+        std = Normal(0.02)
+        self.router = Parameter("router", shape=(units, num_experts),
+                                dtype=dtype, init=std)
+        self.gate = Parameter("gate", shape=(held, units, hidden),
+                              dtype=dtype, init=std)
+        self.up = Parameter("up", shape=(held, units, hidden), dtype=dtype,
+                            init=std)
+        self.down = Parameter("down", shape=(held, hidden, units),
+                              dtype=dtype, init=std)
+        self.expert_load = Parameter("expert_load", shape=(held,),
+                                     dtype="int32", init=Zero(),
+                                     differentiable=False)
+        from . import moe as _moe
+        _moe._ROUTED_LAYERS.add(self)
+
+    def forward(self, x):
+        from .. import telemetry
+        from ..ops.aux_scope import apply_aux_update
+        from ..ops.invoke import is_training
+        from . import moe as _moe
+
+        top_k, first = self._top_k, self.first_expert
+
+        def f(x, router, gate, up, down):
+            m = x.reshape(-1, x.shape[-1])
+            top_e, top_w = _moe.route_top_k(m, router, top_k)
+            y, load = _moe.routed_experts(m, top_e, top_w, gate, up, down,
+                                          first)
+            return y.reshape(x.shape), load
+
+        # host time only: under a trace this is the trace's, per step none
+        with telemetry.span("moe.route", layer=self.expert_load.name):
+            y, load = invoke(f, (x, self.router.data(), self.gate.data(),
+                                 self.up.data(), self.down.data()),
+                             name="routed_experts")
+        if is_training():
+            apply_aux_update(self.expert_load.data(), load)
+        return y
+
+    @staticmethod
+    def partition_rules(axis_name="ep", prefix=".*"):
+        """Experts over ``axis_name``; the router (it scores every expert
+        on every chip) and the load counter replicated."""
+        return [
+            (prefix + r"(gate|up|down)$", P(axis_name, None, None)),
+            (prefix + r"(router|expert_load)$", P()),
         ]
 
 

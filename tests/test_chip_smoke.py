@@ -30,3 +30,26 @@ def test_bert_flash_builder_runs_a_finite_step():
     lengths = batch[3].asnumpy().sum(axis=1)
     assert ((32 <= lengths) & (lengths <= 64)).all() and lengths.min() < 64
     assert math.isfinite(float(step(*batch, batch_size=2).asnumpy()))
+
+
+def test_decoder_reference_phase_at_toy_widths():
+    """`--decoder-reference` compares the cell's own build with the plain
+    reference; in f32 at toy widths the two agree closely, logits and
+    gradients, and the fp8 control stands far outside that agreement."""
+    import chip_smoke
+    from chipbench import run
+
+    _manifest, _chips, cell, cfg, cfgmod = run.load_cell(chip_smoke.DECODER_CELL)
+    cfg.update(hidden_size=32, head_dim=8, num_attention_heads=4,
+               num_key_value_heads=2, sliding_window=6, num_experts=4,
+               num_experts_routed=16, num_experts_per_tok=2, ep_rank=1,
+               moe_intermediate_size=16, vocab_size=64, dtype="float32")
+    cell.update(batch=2, seq_len=16)
+    out = chip_smoke.decoder_reference_numbers(cfg, cell, cfgmod, block=4)
+    assert out["tokens"] == 16 and out["logits_rms"] > 0
+    assert out["logits_err"]["max"] < 1e-4
+    assert out["fp8_control_logits_err"]["rms"] > 30 * out["logits_err"]["rms"]
+    assert abs(out["loss_system"] - out["loss_reference_f32"]) < 1e-4
+    assert sorted(out["gradients"]) == sorted(chip_smoke.DECODER_GRADS)
+    for name, g in out["gradients"].items():
+        assert g["rel_l2"] < 2e-3 and g["cosine"] > 0.9999, (name, g)

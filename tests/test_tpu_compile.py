@@ -245,6 +245,53 @@ def test_flash_fwd_bwd_compiles(one_chip, dropout):
     assert n == 3  # forward, dq, dk+dv
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window-1024", "full"])
+def test_grouped_query_flash_compiles_at_the_decoder_cells_shapes(one_chip, window):
+    """What `mellum2_12b_a2p5b.sft_t8192_ep4share` launches in a layer: 32
+    query heads over 4 key-value heads of 128, T=8192, bf16, the window
+    layers' tile and the full layers'.  Each kernel carries its name."""
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), BF16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pk.flash_attention(q, k, v, causal=True, window=window,
+                                 interpret=False)
+        return out.astype(F32).sum()
+
+    n, text = _custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert n == 3  # forward, dq, dk+dv
+    assert all(name in text for name in ("flash_fwd", "flash_bwd_dq",
+                                         "flash_bwd_dkv"))
+
+
+def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip):
+    """bf16 rows through the held experts at the published widths and the
+    cell's 16,384 tokens: the grouped matmuls and their gradients are
+    Mosaic `ragged-dot` calls (bf16 operands take the kernels' own
+    precision, not the package's float32 default) over buffers that hold a
+    part's worst case; one straight-line body in a loop over the parts, and
+    nothing scatters."""
+    from mxnet_tpu.parallel import moe
+    n, k, u, f, held = 16384, 8, 2304, 896, 16
+    m = jax.ShapeDtypeStruct((n, u), BF16, sharding=one_chip)
+    top_e = jax.ShapeDtypeStruct((n, k), jnp.int32, sharding=one_chip)
+    top_w = jax.ShapeDtypeStruct((n, k), F32, sharding=one_chip)
+    w_in = jax.ShapeDtypeStruct((held, u, f), BF16, sharding=one_chip)
+    w_out = jax.ShapeDtypeStruct((held, f, u), BF16, sharding=one_chip)
+
+    def loss(m, top_e, top_w, gate, up, down):
+        y, _load = moe.routed_experts(m, top_e, top_w, gate, up, down, 16)
+        return y.astype(F32).sum()
+
+    n_calls, text = _custom_calls(jax.grad(loss, argnums=(0, 2, 3, 4, 5)),
+                                  m, top_e, top_w, w_in, w_in, w_out)
+    assert n_calls == 8 and "ragged-dot" in text   # 2 forward, 2 again + 4 backward
+    assert f"bf16[{moe.PICKS_AT_ONCE},{u}]" in text   # every pick of a part
+    assert f"bf16[{n * k},{u}]" not in text
+    assert " while(" in text and " conditional(" not in text
+    assert " scatter(" not in text
+
+
 def test_stem_kernel_compiles(one_chip):
     """bf16 operands under the package's float32 matmul default: the dot
     needs the kernels' own precision rule to lower at all."""
