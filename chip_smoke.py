@@ -38,6 +38,9 @@ IMAGE = 224
 FLASH_SHAPE = (4, 12, 2048, 64)   # (B, H, T, D) of the BERT T=2048 step
 WINDOW_SHAPE = (32, 4, 128, 1024)    # (H, Hkv, D, window) of a decoder's window layer, at T=2048
 BERT_BATCH, BERT_SEQ = 4, 2048
+# the decoder cell's grouped matmuls: a part's sorted rows and how many of
+# them are live, held experts, hidden size, expert width
+GROUPED_SHAPE = (65536, 35000, 16, 2304, 896)
 
 
 class Failed(Exception):
@@ -122,9 +125,65 @@ def _dense_attention(q, k, v, mask):
     return jnp.einsum("bhqk,bhkd->bhqd", p, vf, precision=hi)
 
 
+def _grouped_matmul_numbers(key):
+    """`ops.grouped_matmul` at the decoder cell's shapes, uneven groups: its
+    result and both cotangents against `lax.ragged_dot`'s (largest error
+    over the live rows, relative to the largest value) and the time of the
+    three together, each way."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import grouped_matmul as gm
+
+    m, live_rows, held, u, f = GROUPED_SHAPE
+    kl, *keys = jax.random.split(key, 7)
+    share = jax.random.gamma(kl, 1.5, (held,))
+    load = jnp.floor(share / share.sum() * live_rows).astype(jnp.int32)
+    live = (jnp.arange(m) < load.sum())[:, None]
+
+    def three(matmul):
+        def run(rows, weights, ct):
+            y, vjp = jax.vjp(lambda a, b: matmul(a, b, load), rows, weights)
+            d_rows, d_weights = vjp(ct)
+            return (jnp.where(live, y, 0), jnp.where(live, d_rows, 0),
+                    d_weights)
+        return jax.jit(run)
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            got = fn(*args)
+        jax.block_until_ready(got)
+        return got, (time.perf_counter() - t0) / 10 * 1e3
+
+    out = {"load": [int(v) for v in load]}
+    for (k, n), (kr, kw, kc) in zip(((u, 2 * f), (f, u)),
+                                    (keys[:3], keys[3:])):
+        rows = jax.random.normal(kr, (m, k), jnp.float32).astype(jnp.bfloat16)
+        weights = (jax.random.normal(kw, (held, k, n), jnp.float32)
+                   * k ** -0.5).astype(jnp.bfloat16)
+        ct = jnp.where(live, jax.random.normal(kc, (m, n), jnp.float32),
+                       0).astype(jnp.bfloat16)
+        got, ms = timed(three(gm.grouped_matmul), rows, weights, ct)
+        want, ragged_ms = timed(three(functools.partial(
+            jax.lax.ragged_dot, precision=jax.lax.Precision.DEFAULT)),
+            rows, weights, ct)
+        errs = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                      - w.astype(jnp.float32)))
+                      / jnp.max(jnp.abs(w.astype(jnp.float32))))
+                for g, w in zip(got, want)]
+        out[f"{k}x{n}"] = {"max_err": dict(zip(
+            ("rows_x_w", "rows_x_wt", "rowst_x_rows"), errs)),
+            "ms": round(ms, 3), "ragged_dot_ms": round(ragged_ms, 3)}
+        check(max(errs) < 1e-2, f"grouped matmul {k}x{n}: {out}")
+    return out
+
+
 def phase_kernels(ctx):
-    """The path's Pallas kernel, flash attention, compiled
-    (`interpret=False` passed, not inferred), against its XLA form."""
+    """The path's Pallas kernels, flash attention and the grouped matmul,
+    compiled (`interpret=False` passed, or the TPU's own choice), against
+    their XLA forms."""
     import jax
     import jax.numpy as jnp
 
@@ -132,6 +191,7 @@ def phase_kernels(ctx):
 
     out = {}
     key = jax.random.key(ctx["seed"])
+    out["grouped_matmul"] = _grouped_matmul_numbers(jax.random.fold_in(key, 3))
 
     # flash attention, the shape and mask the BERT T=2048 step runs
     b, h, t, d = FLASH_SHAPE

@@ -7,10 +7,11 @@ dispatched with a one-hot routing einsum, so XLA's SPMD partitioner
 inserts the all-to-all/all-reduce over ICI — the "annotate shardings, let
 XLA place collectives" recipe rather than hand-written NCCL groups.
 
-`routed_experts` is the sparse form (softmax top-k, sorted dispatch, a
-grouped matmul over the experts held): a chip is told which contiguous
-experts it holds, routes over all of them and computes its own part of
-the result.  `moe_ffn` is the older dense top-1 toy.
+`routed_experts` is the sparse form (softmax top-k, sorted dispatch, the
+grouped matmul of `ops/grouped_matmul.py` over the experts held): a chip is
+told which contiguous experts it holds, routes over all of them and
+computes its own part of the result.  `moe_ffn` is the older dense top-1
+toy.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ..ops import grouped_matmul as _gm
 
 __all__ = ["moe_ffn", "init_moe_params", "moe_partition_specs",
            "shard_moe_params", "route_top_k", "routed_experts",
@@ -110,7 +113,10 @@ def moe_ffn(params, x, capacity_factor=None, router_noise=0.0, key=None):
 # `lax.switch` with scatter-adds, same seed, AdamW 1e-4: 23,753 against 21,905
 # tokens/s, p95 731 against 851 ms; a step of 0.613 against 0.586 s at 138k
 # rows, 0.719 against 0.835 s at 280k, that is 0.78 against 1.7 ms per 1,000
-# rows and no cliff where a capacity ends (my chip runs, PR 32).
+# rows and no cliff where a capacity ends (my chip runs, PR 32; the grouped
+# matmul was `lax.ragged_dot` then).  With `ops/grouped_matmul.py`'s kernels
+# the same two parts take 6.55 GB of temporaries and the cell reads 30,344
+# and 30,416 tokens/s against 23,751 and 23,853 (my chip runs, PR 33).
 PICKS_AT_ONCE = 65536
 
 
@@ -143,18 +149,9 @@ def _rows_through_experts(xs, live, load, gate, up, down):
     """Sorted rows xs (picks, U) through their experts' SwiGLU: two grouped
     matmuls over the rows present, gate and up side by side in the first
     (one pass over xs forward, one cotangent of xs backward)."""
-    # the package's ambient matmul precision is float32, which a bf16 dot
-    # inside a Mosaic kernel cannot take ("Bad lhs type"): bf16 rows take
-    # the MXU's native bf16-multiply / f32-accumulate
-    precision = jax.lax.Precision.DEFAULT if xs.dtype == jnp.bfloat16 \
-        else jax.lax.Precision.HIGHEST
-
-    def grouped(rows, weights):
-        return jax.lax.ragged_dot(rows, weights, load, precision=precision)
-
-    z = grouped(xs, jnp.concatenate([gate, up], axis=-1))
+    z = _gm.grouped_matmul(xs, jnp.concatenate([gate, up], axis=-1), load)
     h = _alive(live, jax.nn.silu(z[:, :gate.shape[-1]]) * z[:, gate.shape[-1]:])
-    return _alive(live, grouped(h, down))
+    return _alive(live, _gm.grouped_matmul(h, down, load))
 
 
 def _alive(live, rows):
@@ -231,10 +228,12 @@ def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0):
 
     in m's dtype, and ``load`` (held,) int32, the rows each held expert
     received.  Picks are sorted by expert, rows of absent experts sort
-    last and are never touched; the matmuls are `lax.ragged_dot` over the
-    sorted rows, which on TPU lowers to a Mosaic grouped matmul that
-    visits only the row tiles present (`ragged-dot` custom calls in the
-    trace).  One straight-line path, run over the tokens in parts of
+    last and are never touched; the matmuls are
+    `ops.grouped_matmul.grouped_matmul` over the sorted rows: Pallas
+    kernels that visit only the row tiles present (`ragged_gmm`,
+    `ragged_gmm_t`, `ragged_tgmm` custom calls in the trace), or
+    `lax.ragged_dot` where a width is no multiple of 128 lanes.  One
+    straight-line path, run over the tokens in parts of
     `PICKS_AT_ONCE`, whose buffers hold a part's worst case (every pick of
     every token on a held expert), so no row is dropped at any imbalance;
     each token gathers its picks' rows back, forward and backward, and

@@ -13,6 +13,7 @@ import mxnet_tpu as mx
 from mxnet_tpu.models import CausalLMLoss, DecoderLM
 from mxnet_tpu.models.decoder import rope_inv_freq
 from mxnet_tpu.models.reference import mellum2 as ref
+from mxnet_tpu.ops import grouped_matmul
 from mxnet_tpu.parallel import moe
 
 ROPE = {"full_attention": {"rope_type": "yarn", "rope_theta": 500000,
@@ -268,12 +269,15 @@ def test_every_pick_finds_its_sorted_row_forward_and_backward(
         onp.testing.assert_allclose(g, w, rtol=1e-3, atol=2e-4)
 
 
-def test_rows_that_no_group_holds_reach_nothing(monkeypatch):
+@pytest.mark.parametrize("u,f", [(8, 4), (128, 128)],
+                         ids=["ragged_dot", "pallas"])
+def test_rows_that_no_group_holds_reach_nothing(monkeypatch, u, f):
     """On the chip the grouped matmul leaves the rows past the last group
     unwritten, forward and backward (my chip run, PR 32: the first step's
     gradients were not finite and the step-guard held every update).  Here
-    a `ragged_dot` that writes NaN there stands in for it."""
-    real = jax.lax.ragged_dot
+    a grouped matmul that writes NaN there stands in for it, around
+    whichever implementation the widths take."""
+    real = grouped_matmul.grouped_matmul
 
     def poison(rows, sizes):
         dead = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
@@ -294,7 +298,7 @@ def test_rows_that_no_group_holds_reach_nothing(monkeypatch):
 
     leaky.defvjp(fwd, bwd)
     rng = onp.random.default_rng(9)
-    n, u, f, k = 24, 8, 4, 2
+    n, k = 24, 2
     m = jnp.asarray(rng.normal(size=(n, u)), jnp.float32)
     router = jnp.asarray(rng.normal(size=(u, 8)), jnp.float32)
     gate, up = (jnp.asarray(rng.normal(size=(2, u, f)), jnp.float32)
@@ -308,8 +312,7 @@ def test_rows_that_no_group_holds_reach_nothing(monkeypatch):
 
     args = (m, router, gate, up, down)
     want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
-    monkeypatch.setattr(jax.lax, "ragged_dot",
-                        lambda a, b, s, precision=None: leaky(a, b, s))
+    monkeypatch.setattr(grouped_matmul, "grouped_matmul", leaky)
     got = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):

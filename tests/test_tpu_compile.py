@@ -264,14 +264,16 @@ def test_grouped_query_flash_compiles_at_the_decoder_cells_shapes(one_chip, wind
                                          "flash_bwd_dkv"))
 
 
-def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip):
+def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip, monkeypatch):
     """bf16 rows through the held experts at the published widths and the
-    cell's 16,384 tokens: the grouped matmuls and their gradients are
-    Mosaic `ragged-dot` calls (bf16 operands take the kernels' own
-    precision, not the package's float32 default) over buffers that hold a
-    part's worst case; one straight-line body in a loop over the parts, and
-    nothing scatters."""
+    cell's 16,384 tokens: the grouped matmuls and their gradients are this
+    repository's Mosaic kernels (`ops/grouped_matmul.py`; none is left on
+    `lax.ragged_dot`, no transposed copy of the weights is made) over
+    buffers that hold a part's worst case; one straight-line body in a loop
+    over the parts, and nothing scatters."""
+    from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(gm._context, "on_tpu", lambda: True)
     n, k, u, f, held = 16384, 8, 2304, 896, 16
     m = jax.ShapeDtypeStruct((n, u), BF16, sharding=one_chip)
     top_e = jax.ShapeDtypeStruct((n, k), jnp.int32, sharding=one_chip)
@@ -281,15 +283,42 @@ def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip):
 
     def loss(m, top_e, top_w, gate, up, down):
         y, _load = moe.routed_experts(m, top_e, top_w, gate, up, down, 16)
-        return y.astype(F32).sum()
+        return (y.astype(F32) ** 2).sum()
 
     n_calls, text = _custom_calls(jax.grad(loss, argnums=(0, 2, 3, 4, 5)),
                                   m, top_e, top_w, w_in, w_in, w_out)
-    assert n_calls == 8 and "ragged-dot" in text   # 2 forward, 2 again + 4 backward
+    assert n_calls == 8 and "ragged-dot" not in text
+    # 2 forward, 2 again + 2 + 2 backward, by the kernels' `name=`
+    calls = re.findall(r"ragged_(\w+?)_*[.\d]* = \S+ custom-call\(", text)
+    assert sorted(calls) == ["gmm"] * 4 + ["gmm_t"] * 2 + ["tgmm"] * 2
     assert f"bf16[{moe.PICKS_AT_ONCE},{u}]" in text   # every pick of a part
     assert f"bf16[{n * k},{u}]" not in text
+    assert f"bf16[{held},{2 * f},{u}]" not in text    # no weights transposed
     assert " while(" in text and " conditional(" not in text
     assert " scatter(" not in text
+
+
+@pytest.mark.parametrize("rows,k,n", [(48, 128, 256), (1040, 256, 4608),
+                                      (4096, 4608, 128)],
+                         ids=["one-small-tile", "columns-split", "k-split"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_grouped_matmul_lowers_at_the_edges_of_its_rule(
+        one_chip, monkeypatch, dtype, rows, k, n):
+    """Every shape `grouped_matmul` sends to its kernels has to lower: rows
+    in multiples of 16 only, N and K past one tile (an accumulator over K)."""
+    from mxnet_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm._context, "on_tpu", lambda: True)
+    a = jax.ShapeDtypeStruct((rows, k), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, k, n), dtype, sharding=one_chip)
+    ct = jax.ShapeDtypeStruct((rows, n), dtype, sharding=one_chip)
+    load = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
+
+    def three(a, w, load, ct):
+        y, vjp = jax.vjp(lambda a, w: gm.grouped_matmul(a, w, load), a, w)
+        return (y,) + vjp(ct)
+
+    n_calls, _ = _custom_calls(three, a, w, load, ct)
+    assert n_calls == 3
 
 
 def test_stem_kernel_compiles(one_chip):
