@@ -4,9 +4,11 @@
     python chip_smoke.py --chips 4   # only the four-chip phase; about 24
                                      # minutes, most of them the classic
                                      # path's small per-device compiles
-    python chip_smoke.py --decoder-reference   # only the decoder against its
-                                     # plain f32 reference at the published
-                                     # widths, one sequence of 8192
+    python chip_smoke.py --decoder-reference [CONFIG]   # only a decoder
+                                     # against its plain f32 reference at the
+                                     # published widths, one sequence of its
+                                     # cell (mellum2_12b_a2p5b: 8192 tokens;
+                                     # kimi_linear_48b_a3b: 16,384)
 
 Drives model zoo -> `Trainer` -> `gluon.FusedTrainStep` on an attached TPU
 through the entry points a user calls, at full width, and checks what comes
@@ -550,8 +552,9 @@ def phase_resnet50_dp4(ctx):
     return out
 
 # ---------------------------------------------------------------------------
-# The decoder cell (mellum2_12b_a2p5b) against its plain f32 reference.
-# Tolerances, each with its reason (readings: my chip runs, PR 32):
+# A decoder cell against its plain f32 reference (`--decoder-reference
+# [CONFIG]`).  Tolerances, each with its reason.
+# mellum2_12b_a2p5b (readings: my chip runs, PR 32):
 #   logits, given the system's picks: rms error <= 0.01 and largest <= 0.05 on
 #     logits of rms 0.96 -- bf16 rounds at 2^-9 relative, through four layers
 #     and the head; read 0.0053 and 0.036.  The reference is GIVEN the
@@ -561,6 +564,10 @@ def phase_resnet50_dp4(ctx):
 #   the control: the reference with its expert weights cast to fp8 (e4m3),
 #     the nearest precision below the stated one, must FAIL the logits'
 #     tolerance -- else the check could not tell the precisions apart.
+# kimi_linear_48b_a3b (readings: my chip runs, PR 34; PERF.md section 6): the
+#   same four limits, five layers deep; the control casts every layer's q
+#   (KDA's k and v too) projection and every SwiGLU's matrices (the dense
+#   layer's, the shared and the routed experts') to fp8.
 LOGITS_RMS_TOL, LOGITS_MAX_TOL = 0.01, 0.05
 GRAD_REL_TOL, GRAD_COS_TOL = 0.10, 0.995
 DECODER_CELL = "mellum2_12b_a2p5b.sft_t8192_ep4share"
@@ -570,26 +577,84 @@ DECODER_GRADS = ("layer0.attend.attention.query.weight",
                  "layer0.experts.down", "layer3.attend.attention.query.weight")
 
 
-def decoder_reference_numbers(cfg, cell, cfgmod, block=512):
+def _mellum_reference_params(p, cfg, _cfgmod):
+    layers = []
+    for l in range(cfg["num_hidden_layers"]):
+        a, e = f"layer{l}.attend.attention.", f"layer{l}.experts."
+        layers.append({
+            "norm1": p[f"layer{l}.attend.norm.gamma"],
+            "wq": p[a + "query.weight"].T, "wk": p[a + "key.weight"].T,
+            "wv": p[a + "value.weight"].T, "wo": p[a + "proj.weight"].T,
+            "norm2": p[f"layer{l}.ffn_norm.gamma"], "router": p[e + "router"],
+            "gate": p[e + "gate"], "up": p[e + "up"], "down": p[e + "down"]})
+    return {"embed": p["embed.weight"], "norm": p["norm.gamma"],
+            "head": p["head.weight"].T, "layers": layers}
+
+
+# per configuration: its cell, the reference's module, how the system's
+# parameters and configuration reach the reference, the parameters whose
+# gradients are compared as (system name, layer, reference key, transposed),
+# and the reference keys the fp8 control casts
+DECODER_REFERENCES = {
+    "mellum2_12b_a2p5b": dict(
+        cell=DECODER_CELL, reference="mellum2", top_k="num_experts_per_tok",
+        params=_mellum_reference_params,
+        config=lambda cfg, cfgmod: dict(
+            cfg, num_experts=cfg["num_experts_routed"],
+            layer_types=cfg["layer_types"][:cfg["num_hidden_layers"]]),
+        grads=[(DECODER_GRADS[0], 0, "wq", True), (DECODER_GRADS[1], 0, "wk", True),
+               (DECODER_GRADS[2], 0, "router", False), (DECODER_GRADS[3], 0, "gate", False),
+               (DECODER_GRADS[4], 0, "up", False), (DECODER_GRADS[5], 0, "down", False),
+               (DECODER_GRADS[6], 3, "wq", True)],
+        fp8=("gate", "up", "down")),
+    "kimi_linear_48b_a3b": dict(
+        cell="kimi_linear_48b_a3b.sft_t16384_ep32share", reference="kimi_linear",
+        top_k="num_experts_per_token", block=256, logits_tol=(0.04, 0.25),
+        params=lambda p, cfg, cfgmod: cfgmod.reference_params(p, cfg),
+        config=lambda cfg, cfgmod: cfgmod.reference_config(cfg),
+        grads=[("layer0.attend.attention.q_proj.weight", 0, "wq", True),
+               ("layer0.attend.attention.k_conv", 0, "conv_k", False),
+               ("layer0.attend.attention.f_b.weight", 0, "wf_b", True),
+               ("layer0.attend.attention.A_log", 0, "A_log", False),
+               ("layer0.attend.attention.b_proj.weight", 0, "wb", True),
+               ("layer0.mlp.down.weight", 0, "down", True),
+               ("layer1.attend.attention.v_proj.weight", 1, "wv", True),
+               ("layer1.experts.router", 1, "router", False),
+               ("layer1.experts.gate", 1, "gate", False),
+               ("layer1.experts.down", 1, "down", False),
+               ("layer1.shared.up.weight", 1, "shared_up", True),
+               ("layer3.attend.attention.q_proj.weight", 3, "wq", True),
+               ("layer3.attend.attention.kv_b.weight", 3, "wkvb", True),
+               ("layer4.attend.attention.g_b.weight", 4, "wg_b", True)],
+        fp8=("wq", "wk", "wv", "gate", "up", "down", "shared_gate",
+             "shared_up", "shared_down")),
+}
+
+
+def decoder_reference_numbers(cfg, cell, cfgmod, block=None,
+                              config="mellum2_12b_a2p5b"):
     """The decoder built by the cell's own `build` on sequence 0 of ring
-    batch 0, against `models/reference/mellum2.py` given the system's
-    picks: logits, loss, the gradients of `DECODER_GRADS` (an attention's,
-    a router's and the held experts' matrices), and the fp8 control."""
+    batch 0, against its plain reference (`models/reference/`) given the
+    system's picks: logits, loss, the gradients of the configuration's
+    `grads` (attentions', a router's and the held experts' matrices), and
+    the fp8 control."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
     import mxnet_tpu as mx
-    from mxnet_tpu.models.reference import mellum2 as ref
     from mxnet_tpu.ndarray.ndarray import NDArray
     from mxnet_tpu.parallel import moe
 
+    spec = DECODER_REFERENCES[config]
+    block = block or spec.get("block", 512)    # positions the reference takes at once
+    ref = importlib.import_module("mxnet_tpu.models.reference." + spec["reference"])
     loss_block, _trainer = cfgmod.build(cfg)
     model = loss_block.model
     ids = NDArray(cfgmod.make_ring(cfg, cell, 1, 0, None)[0][0][0]._data[:1])
-    layers = cfg["num_hidden_layers"]
-    held, rank, top_k = cfg["num_experts"], cfg["ep_rank"], cfg["num_experts_per_tok"]
-    ref_cfg = dict(cfg, num_experts=cfg["num_experts_routed"],
-                   layer_types=cfg["layer_types"][:layers])
+    held, rank, top_k = cfg["num_experts"], cfg["ep_rank"], cfg[spec["top_k"]]
+    ref_cfg = spec["config"](cfg, cfgmod)
     share = dict(experts_held=held, ep_rank=rank, block=block)
 
     # the system, layer by layer through its own blocks, for its picks
@@ -598,32 +663,48 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=512):
         layer = getattr(model, name)
         x = x + layer.attend(x)
         m = layer.ffn_norm(x)
-        e, _w = moe.route_top_k(m._data.reshape(ids.shape[1], -1),
-                                layer.experts.router.data()._data, top_k)
-        picks.append(e.reshape(1, ids.shape[1], top_k))
-        x = x + layer.experts(m)
+        experts = getattr(layer, "experts", None)
+        if experts is None:
+            picks.append(None)
+        else:
+            bias = getattr(experts, "correction_bias", None)
+            e, _w = moe.route_top_k(
+                m._data.reshape(ids.shape[1], -1), experts.router.data()._data,
+                top_k, experts._scoring[0],
+                None if bias is None else bias.data()._data,
+                *experts._scoring[1:])
+            picks.append(e.reshape(1, ids.shape[1], top_k))
+        for part in layer._ffn:
+            x = x + getattr(layer, part)(m)
     got = model.head(model.norm(x))._data.astype(jnp.float32)
 
+    # the compiled loss rounds otherwise than the eager walk and would flip
+    # near-ties of its own: its routers are given the walk's picks, layer
+    # by layer in the order they are traced, as the reference is
+    given = [pk.reshape(-1, top_k) for pk in picks if pk is not None]
+    route, traced = moe.route_top_k, []
+
+    def route_given(*args, **kwargs):
+        traced.append(None)
+        return route(*args, picks=given[(len(traced) - 1) % len(given)], **kwargs)
+
     loss_block.hybridize()
-    with mx.autograd.record():
-        loss = loss_block(ids)
-    loss.backward()
+    moe.route_top_k = route_given
+    try:
+        with mx.autograd.record():
+            loss = loss_block(ids)
+        loss.backward()
+    finally:
+        moe.route_top_k = route
+    check(len(traced) == len(given), f"{len(traced)} routers traced, {len(given)} given picks")
     params = model.collect_params()
     mine = {n: params[n].grad()._data.astype(jnp.float32)
-            for n in DECODER_GRADS}
+            for n, *_ in spec["grads"]}
 
     p = {k: v.data()._data.astype(jnp.float32) for k, v in params.items()}
-    del loss, loss_block, model, params, layer, _trainer   # room for the reference
-    rp = {"embed": p["embed.weight"], "norm": p["norm.gamma"],
-          "head": p["head.weight"].T, "layers": []}
-    for l in range(layers):
-        a, e = f"layer{l}.attend.attention.", f"layer{l}.experts."
-        rp["layers"].append({
-            "norm1": p[f"layer{l}.attend.norm.gamma"],
-            "wq": p[a + "query.weight"].T, "wk": p[a + "key.weight"].T,
-            "wv": p[a + "value.weight"].T, "wo": p[a + "proj.weight"].T,
-            "norm2": p[f"layer{l}.ffn_norm.gamma"], "router": p[e + "router"],
-            "gate": p[e + "gate"], "up": p[e + "up"], "down": p[e + "down"]})
+    del loss, loss_block, model, params, layer, experts, _trainer, x, m  # room for the reference
+    rp = spec["params"](p, cfg, cfgmod)
+    del p
     ref_logits = jax.jit(lambda rp: ref.logits(rp, ids._data, ref_cfg,
                                                picks=picks, **share))
 
@@ -640,32 +721,29 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=512):
         return {"rms": float(jnp.sqrt(jnp.mean(d * d))), "max": float(d.max())}
 
     want = ref_logits(rp)
-    out = {"tokens": int(ids.shape[1]),
+    out = {"config": config, "tokens": int(ids.shape[1]),
            "logits_rms": float(jnp.sqrt(jnp.mean(want * want))),
            "logits_err": errors(got, want),
            "loss_reference_f32": xent(want), "loss_system": xent(got)}
+    # the reference left to its own picks: what the cell's `loss_band` is set from
+    out["loss_reference_f32_own_picks"] = float(jax.jit(
+        lambda rp: ref.loss(rp, ids._data, ref_cfg, **share))(rp))
     out["fp8_control_logits_err"] = errors(ref_logits(dict(rp, layers=[
-        dict(lp, **{k: cast8(lp[k]) for k in ("gate", "up", "down")})
+        dict(lp, **{k: cast8(lp[k]) for k in spec["fp8"] if k in lp})
         for lp in rp["layers"]])), want)
     del got, want
 
     def ref_loss(sub, rp):
         changed = [dict(lp) for lp in rp["layers"]]
-        changed[0].update(sub["first"])
-        changed[-1].update(sub["last"])
+        for (l, key), value in sub.items():
+            changed[l][key] = value
         return ref.loss(dict(rp, layers=changed), ids._data, ref_cfg,
                         picks=picks, **share)
-    g = jax.jit(jax.grad(ref_loss))({
-        "first": {k: rp["layers"][0][k]
-                  for k in ("wq", "wk", "router", "gate", "up", "down")},
-        "last": {"wq": rp["layers"][-1]["wq"]}}, rp)
-    theirs = dict(zip(DECODER_GRADS, (
-        g["first"]["wq"].T, g["first"]["wk"].T, g["first"]["router"],
-        g["first"]["gate"], g["first"]["up"], g["first"]["down"],
-        g["last"]["wq"].T)))
+    g = jax.jit(jax.grad(ref_loss))(
+        {(l, key): rp["layers"][l][key] for _n, l, key, _t in spec["grads"]}, rp)
     out["gradients"] = {}
-    for n in DECODER_GRADS:
-        a, b = mine[n], theirs[n]
+    for n, l, key, transposed in spec["grads"]:
+        a, b = mine[n], g[(l, key)].T if transposed else g[(l, key)]
         out["gradients"][n] = {
             "rel_l2": float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
             "cosine": float(jnp.sum(a * b) / (jnp.linalg.norm(a)
@@ -674,16 +752,21 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=512):
 
 
 def phase_decoder_reference(ctx):
-    """At the published widths and one sequence of the cell's 8192 tokens
-    (the system's forward runs the flash kernels and the grouped matmuls)."""
+    """At the published widths and one sequence of the cell's tokens (the
+    system's forward runs its kernels: flash, the grouped matmuls, KDA's
+    scan)."""
     from chipbench import run
-    _manifest, _chips, cell, cfg, cfgmod = run.load_cell(DECODER_CELL)
-    out = decoder_reference_numbers(cfg, cell, cfgmod)
+    config = ctx.get("decoder_reference") or "mellum2_12b_a2p5b"
+    _manifest, _chips, cell, cfg, cfgmod = run.load_cell(
+        DECODER_REFERENCES[config]["cell"])
+    out = decoder_reference_numbers(cfg, cell, cfgmod, config=config)
     err, ctl = out["logits_err"], out["fp8_control_logits_err"]
-    check(err["rms"] <= LOGITS_RMS_TOL and err["max"] <= LOGITS_MAX_TOL,
+    rms_tol, max_tol = DECODER_REFERENCES[config].get(
+        "logits_tol", (LOGITS_RMS_TOL, LOGITS_MAX_TOL))
+    check(err["rms"] <= rms_tol and err["max"] <= max_tol,
           f"logits against the reference given the system's picks: {out}")
-    check(ctl["rms"] > LOGITS_RMS_TOL or ctl["max"] > LOGITS_MAX_TOL,
-          f"an fp8 cast of the expert weights passes the logits' tolerance: {out}")
+    check(ctl["rms"] > rms_tol or ctl["max"] > max_tol,
+          f"an fp8 cast of the control's weights passes the logits' tolerance: {out}")
     for n, g in out["gradients"].items():
         check(g["rel_l2"] <= GRAD_REL_TOL and g["cosine"] >= GRAD_COS_TOL,
               f"gradient of {n} against the reference: {g}")
@@ -695,8 +778,10 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the four-chip phase (resnet50_dp4)")
-    ap.add_argument("--decoder-reference", action="store_true",
-                    help="run only the decoder cell against its f32 reference")
+    ap.add_argument("--decoder-reference", nargs="?", metavar="CONFIG",
+                    const="mellum2_12b_a2p5b", choices=sorted(DECODER_REFERENCES),
+                    help="run only a decoder cell against its f32 reference "
+                         "(mellum2_12b_a2p5b where no configuration is named)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
@@ -716,7 +801,7 @@ def main():
         [phase_decoder_reference] if args.decoder_reference else
         [phase_kernels, phase_resnet50_train, phase_bert_flash,
          phase_memory_profile])
-    ctx = {"seed": args.seed}
+    ctx = {"seed": args.seed, "decoder_reference": args.decoder_reference}
     ok = True
     for phase in phases:
         row = {"phase": phase.__name__[len("phase_"):], "ok": True}

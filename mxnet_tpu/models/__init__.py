@@ -9,7 +9,8 @@ dp/tp/sp-sharded over a `jax.sharding.Mesh` unchanged.
 """
 from .rnn_lm import RNNModel, rnn_lm_partition_rules
 from .decoder import (
-    RMSNorm, GroupedQueryAttention, DecoderLayer, DecoderLM, CausalLMLoss,
+    RMSNorm, GroupedQueryAttention, KimiDeltaAttention, LatentAttention,
+    SwiGLU, DecoderLayer, DecoderLM, CausalLMLoss,
 )
 from .transformer import (
     MultiHeadAttention,
@@ -28,6 +29,6 @@ __all__ = [
     "MultiHeadAttention", "PositionwiseFFN", "TransformerEncoderLayer",
     "TransformerEncoder", "BertModel", "BertForPretraining",
     "bert_partition_rules", "bert_base", "bert_large",
-    "RMSNorm", "GroupedQueryAttention", "DecoderLayer", "DecoderLM",
-    "CausalLMLoss",
+    "RMSNorm", "GroupedQueryAttention", "KimiDeltaAttention",
+    "LatentAttention", "SwiGLU", "DecoderLayer", "DecoderLM", "CausalLMLoss",
 ]

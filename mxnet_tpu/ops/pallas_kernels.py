@@ -512,7 +512,7 @@ def _kv_head(h, hkv):
 def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
                    block_q, block_k, interpret, window=None):
     b, h, t, d = qd.shape
-    hkv = kd.shape[1]
+    hkv, dv = kd.shape[1], vd.shape[3]     # v's head size may differ from q.k's
     bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret,
                                   window)
     nk = t // bk
@@ -522,7 +522,7 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
 
     qr = qd.reshape(b * h, t, d)
     ktr = kd.reshape(b * hkv, t, d).swapaxes(1, 2)   # (b*hkv, D, T)
-    vr = vd.reshape(b * hkv, t, d)
+    vr = vd.reshape(b * hkv, t, dv)
     kernel = functools.partial(
         _fwd_kernel, scale=sc, causal=causal, block_q=bq, block_k=bk,
         nk=nk, nh=h, masked=masked, has_bias=has_bias,
@@ -537,7 +537,7 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, d, bk),
                      lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
-        pl.BlockSpec((1, bk, d),
+        pl.BlockSpec((1, bk, dv),
                      lambda bh, qi, ki, *r: (kvh(bh), ck(bh, qi, ki, r), 0)),
     ]
     operands = [qr, ktr, vr]
@@ -561,7 +561,7 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
     out, lse = _pallas(
         kernel, (b * h, t // bq, nk), in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh, qi, ki, *r: (bh, qi, 0)),
             # (bh, t, 1) layout: Mosaic requires the last two block dims
             # be (multiple-of-8, multiple-of-128) or span the array, so a
             # 2-D (1, bq) lse block is unlowereable; a trailing unit lane
@@ -569,17 +569,17 @@ def _flash_forward(qd, kd, vd, mask, bias, seed, causal, scale, dropout,
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, *r: (bh, qi, 0)),
         ],
         out_shape=[
-            _sds((b * h, t, d), qd.dtype, qr),
+            _sds((b * h, t, dv), qd.dtype, qr),
             _sds((b * h, t, 1), jnp.float32, qr),
         ],
         scratch=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
-            pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
         ],
         interp=interp, masked=masked, operands=operands, kend=kend,
         name="flash_fwd")
-    return out.reshape(b, h, t, d), lse.reshape(b, h, t)
+    return out.reshape(b, h, t, dv), lse.reshape(b, h, t)
 
 
 # ---------------------------------------------------------------------------
@@ -777,7 +777,7 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
                     scale, dropout, block_q, block_k, interpret, dlse=None,
                     window=None):
     b, h, t, d = qd.shape
-    hkv = kd.shape[1]
+    hkv, dv = kd.shape[1], vd.shape[3]
     group = h // hkv
     bq, bk, sc, interp = _resolve(qd, block_q, block_k, scale, interpret,
                                   window)
@@ -797,8 +797,8 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
 
     qr = qd.reshape(b * h, t, d)
     kr = kd.reshape(b * hkv, t, d)
-    vr = vd.reshape(b * hkv, t, d)
-    dor = ct.reshape(b * h, t, d)
+    vr = vd.reshape(b * hkv, t, dv)
+    dor = ct.reshape(b * h, t, dv)
     qtr = qr.swapaxes(1, 2)                    # (bh, D, T)
     ktr = kr.swapaxes(1, 2)
     vtr = vr.swapaxes(1, 2)
@@ -824,9 +824,9 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
                      lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
         pl.BlockSpec((1, bk, d),
                      lambda bh, qi, ki, *r: (kvh(bh), ck(bh, qi, ki, r), 0)),
-        pl.BlockSpec((1, d, bk),
+        pl.BlockSpec((1, dv, bk),
                      lambda bh, qi, ki, *r: (kvh(bh), 0, ck(bh, qi, ki, r))),
-        pl.BlockSpec((1, bq, d), lambda bh, qi, ki, *r: (bh, qi, 0)),
+        pl.BlockSpec((1, bq, dv), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, *r: (bh, qi, 0)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki, *r: (bh, qi, 0)),
     ]
@@ -880,10 +880,10 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
         pl.BlockSpec((1, bq, d),
                      lambda bh, ki, j, *r: (qh(bh, j), qb(bh, ki, j, r), 0)),
         pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
-        pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
-        pl.BlockSpec((1, d, bq),
+        pl.BlockSpec((1, bk, dv), lambda bh, ki, j, *r: (bh, ki, 0)),
+        pl.BlockSpec((1, dv, bq),
                      lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
-        pl.BlockSpec((1, bq, d),
+        pl.BlockSpec((1, bq, dv),
                      lambda bh, ki, j, *r: (qh(bh, j), qb(bh, ki, j, r), 0)),
         pl.BlockSpec((1, 1, bq),
                      lambda bh, ki, j, *r: (qh(bh, j), 0, qb(bh, ki, j, r))),
@@ -904,7 +904,7 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
     if drop:
         operands.append(seed)
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    dk, dv = _pallas(
+    dk, dvr = _pallas(
         functools.partial(_bwd_dkv_kernel, scale=sc, causal=causal,
                           block_q=bq, block_k=bk, nq=nq, nh=h,
                           masked=masked, has_bias=has_bias, thr=thr,
@@ -913,19 +913,19 @@ def _flash_backward(qd, kd, vd, mask, bias, seed, out, lse, ct, causal,
         (b * hkv, nk, group * nq), in_specs,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, j, *r: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, dv), lambda bh, ki, j, *r: (bh, ki, 0)),
         ],
         out_shape=[
             _sds((b * hkv, t, d), kd.dtype, qr),
-            _sds((b * hkv, t, d), vd.dtype, qr),
+            _sds((b * hkv, t, dv), vd.dtype, qr),
         ],
         scratch=[pltpu.VMEM((bk, d), jnp.float32),
-                 pltpu.VMEM((bk, d), jnp.float32)],
+                 pltpu.VMEM((bk, dv), jnp.float32)],
         interp=interp, masked=masked, operands=operands, kend=kend,
         name="flash_bwd_dkv")
 
     return (dq.reshape(b, h, t, d), dk.reshape(b, hkv, t, d),
-            dv.reshape(b, hkv, t, d))
+            dvr.reshape(b, hkv, t, dv))
 
 
 def _zero_cts(mask, bias, seed):
@@ -1055,10 +1055,10 @@ def _entry(fn, q, k, v, causal, scale, block_q, block_k, interpret, mask,
             "(npx.flash_attention draws one from the mx.random stream)")
     seed = _seed_words(key) if drop else None
     b, h, t = q.shape[0], q.shape[1], q.shape[2]
-    if k.shape != v.shape or h % k.shape[1] or \
-            k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]:
+    if k.shape[:3] != v.shape[:3] or len(v.shape) != 4 or h % k.shape[1] \
+            or k.shape[:1] + k.shape[2:] != q.shape[:1] + q.shape[2:]:
         raise ValueError(
-            f"flash_attention takes k and v of one shape (B, Hkv, T, D) "
+            f"flash_attention takes k (B, Hkv, T, D) and v (B, Hkv, T, Dv) "
             f"with Hkv dividing q's {h} heads; got q {q.shape}, "
             f"k {k.shape}, v {v.shape}")
     if window is not None and (not causal or window < 1):
@@ -1097,8 +1097,10 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, mask=None, bias=None,
                     dropout=0.0, key=None, window=None):
-    """Blockwise (flash) attention: q (B, H, T, D), k/v (B, Hkv, T, D)
-    -> (B, H, T, D).
+    """Blockwise (flash) attention: q (B, H, T, D), k (B, Hkv, T, D),
+    v (B, Hkv, T, Dv) -> (B, H, T, Dv).  v's head size may differ from
+    q.k's (latent attention: 192 and 128); the default ``scale`` is
+    D^-0.5.
 
     Grouped-query heads: Hkv divides H and query head i reads key-value
     head i // (H/Hkv) through the BlockSpec index maps — K and V are

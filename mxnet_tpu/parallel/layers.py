@@ -75,15 +75,22 @@ class MoEFFN(HybridBlock):
 
 
 class RoutedExperts(HybridBlock):
-    """Sparse SwiGLU experts behind a softmax top-k router, as a Gluon
+    """Sparse SwiGLU experts behind a top-k router, as a Gluon
     layer that is TOLD which experts it holds: ``experts_held`` contiguous
     experts from ``ep_rank * experts_held``.  The router scores all
-    ``num_experts`` (in f32) and picks ``top_k`` with renormalised
-    weights; the layer computes the part of the result its own experts
-    give (`parallel.moe.routed_experts`: sorted dispatch that drops no
+    ``num_experts`` (in f32) and picks ``top_k``; the layer computes the
+    part of the result its own experts give
+    (`parallel.moe.routed_experts`: sorted dispatch that drops no
     row, a grouped matmul).  With ``experts_held == num_experts`` it is
     the whole layer; on one chip of an ``ep`` group it runs without the
     exchange, and nothing stands in for it.
+
+    ``scoring`` is `parallel.moe.route_top_k`'s: "softmax" (renormalised
+    probabilities) or "sigmoid", which chooses by score plus
+    ``correction_bias`` (a (num_experts,) buffer that takes no gradient
+    and is no optimizer's to move: zeros unless loaded), weighs by the
+    unbiased score, divides by the picks' sum where ``renormalize`` and
+    multiplies by ``scaling_factor``.
 
     Forward: ``x (B, T, U) -> y (B, T, U)``.  Each training step also
     writes ``expert_load`` (held,), the rows every held expert received,
@@ -93,18 +100,26 @@ class RoutedExperts(HybridBlock):
     """
 
     def __init__(self, units, hidden, num_experts, top_k, experts_held=None,
-                 ep_rank=0, dtype="float32"):
+                 ep_rank=0, dtype="float32", scoring="softmax",
+                 renormalize=True, scaling_factor=1.0):
         super().__init__()
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r}")
         held = num_experts if experts_held is None else experts_held
         if not 0 <= ep_rank * held <= num_experts - held:
             raise ValueError(
                 f"experts {ep_rank * held}..{(ep_rank + 1) * held - 1} are "
                 f"not among {num_experts}")
         self._top_k = top_k
+        self._scoring = (scoring, renormalize, float(scaling_factor))
         self.first_expert = ep_rank * held
         std = Normal(0.02)
         self.router = Parameter("router", shape=(units, num_experts),
                                 dtype=dtype, init=std)
+        if scoring == "sigmoid":
+            self.correction_bias = Parameter(
+                "correction_bias", shape=(num_experts,), dtype="float32",
+                init=Zero(), differentiable=False)
         self.gate = Parameter("gate", shape=(held, units, hidden),
                               dtype=dtype, init=std)
         self.up = Parameter("up", shape=(held, units, hidden), dtype=dtype,
@@ -124,19 +139,23 @@ class RoutedExperts(HybridBlock):
         from . import moe as _moe
 
         top_k, first = self._top_k, self.first_expert
+        scoring, renormalize, scale = self._scoring
 
-        def f(x, router, gate, up, down):
+        def f(x, router, gate, up, down, bias=None):
             m = x.reshape(-1, x.shape[-1])
-            top_e, top_w = _moe.route_top_k(m, router, top_k)
+            top_e, top_w = _moe.route_top_k(m, router, top_k, scoring, bias,
+                                            renormalize, scale)
             y, load = _moe.routed_experts(m, top_e, top_w, gate, up, down,
                                           first)
             return y.reshape(x.shape), load
 
+        args = (x, self.router.data(), self.gate.data(), self.up.data(),
+                self.down.data())
+        if scoring == "sigmoid":
+            args += (self.correction_bias.data(),)
         # host time only: under a trace this is the trace's, per step none
         with telemetry.span("moe.route", layer=self.expert_load.name):
-            y, load = invoke(f, (x, self.router.data(), self.gate.data(),
-                                 self.up.data(), self.down.data()),
-                             name="routed_experts")
+            y, load = invoke(f, args, name="routed_experts")
         if is_training():
             apply_aux_update(self.expert_load.data(), load)
         return y
@@ -147,7 +166,7 @@ class RoutedExperts(HybridBlock):
         on every chip) and the load counter replicated."""
         return [
             (prefix + r"(gate|up|down)$", P(axis_name, None, None)),
-            (prefix + r"(router|expert_load)$", P()),
+            (prefix + r"(router|expert_load|correction_bias)$", P()),
         ]
 
 

@@ -7,7 +7,8 @@ dispatched with a one-hot routing einsum, so XLA's SPMD partitioner
 inserts the all-to-all/all-reduce over ICI — the "annotate shardings, let
 XLA place collectives" recipe rather than hand-written NCCL groups.
 
-`routed_experts` is the sparse form (softmax top-k, sorted dispatch, the
+`routed_experts` is the sparse form (a softmax or sigmoid top-k router
+`route_top_k`, sorted dispatch, the
 grouped matmul of `ops/grouped_matmul.py` over the experts held): a chip is
 told which contiguous experts it holds, routes over all of them and
 computes its own part of the result.  `moe_ffn` is the older dense top-1
@@ -120,14 +121,42 @@ def moe_ffn(params, x, capacity_factor=None, router_noise=0.0, key=None):
 PICKS_AT_ONCE = 65536
 
 
-def route_top_k(m, router, top_k):
-    """Softmax over ALL experts in f32, the `top_k` largest, renormalised
-    to sum to one (`norm_topk_prob`): (experts (N, k) int32, weights
-    (N, k) f32) for activations m (N, U) and router (U, E)."""
-    p = jax.nn.softmax(jnp.dot(m.astype(jnp.float32),
-                               router.astype(jnp.float32)), axis=-1)
-    top_p, top_e = jax.lax.top_k(p, top_k)
-    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+def route_top_k(m, router, top_k, scoring="softmax", bias=None,
+                renormalize=True, scale=1.0, picks=None):
+    """(experts (N, k) int32, weights (N, k) f32) for activations m (N, U)
+    and router (U, E), scored over ALL experts in f32.
+
+    ``scoring="softmax"``: the `top_k` largest probabilities, renormalised
+    to sum to one (`norm_topk_prob`).  ``"sigmoid"``: scores s =
+    sigmoid(m router); the `top_k` largest of s + ``bias`` (E,) are chosen
+    (the correction bias steers the CHOICE only and takes no gradient),
+    their weights are the unbiased s, divided by their sum (+ 1e-20) where
+    ``renormalize``, times ``scale`` (`routed_scaling_factor`).
+
+    ``picks`` (N, k) overrides WHICH experts are chosen — their weights are
+    still this router's — so that two programs whose scores differ in the
+    last bit can be compared on one choice (`chip_smoke.py`)."""
+    logits = jnp.dot(m.astype(jnp.float32), router.astype(jnp.float32))
+    if scoring == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+        if picks is None:
+            top_p, top_e = jax.lax.top_k(p, top_k)
+        else:
+            top_e, top_p = picks, jnp.take_along_axis(p, picks, axis=-1)
+        return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring {scoring!r} is neither softmax nor sigmoid")
+    s = jax.nn.sigmoid(logits)
+    if picks is None:
+        biased = s if bias is None else \
+            s + jax.lax.stop_gradient(bias.astype(jnp.float32))
+        _, top_e = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)
+    else:
+        top_e = picks
+    top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    if renormalize:
+        top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_e, top_s * scale
 
 
 def _sorted_picks(top_e, held, first_expert):

@@ -43,6 +43,25 @@ def _seed_rng(request):
     mx.waitall()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _routed_layers_do_not_outlive_their_module():
+    """`parallel.moe.expert_loads()` reads every LIVE `RoutedExperts` layer,
+    and the benchmark's FLOP counts read it (`chipbench/layer_work.py`).  A
+    model that a test module trained can stay alive past the module (jit and
+    tape caches hold it), and its toy counters then stand in a later
+    module's counts: after each module, collect what can be collected and
+    zero the counters of what is left (zero rows read as "no counter")."""
+    yield
+    import gc
+    import sys
+    gc.collect()
+    moe = sys.modules.get("mxnet_tpu.parallel.moe")
+    for layer in list(moe._ROUTED_LAYERS) if moe else ():
+        load = layer.expert_load
+        if load._data is not None:
+            load.data()._rebind(jax.numpy.zeros(load.shape, "int32"))
+
+
 @pytest.fixture
 def rng():
     """Per-test numpy Generator seeded by the autouse seed fixture."""

@@ -53,3 +53,33 @@ def test_decoder_reference_phase_at_toy_widths():
     assert sorted(out["gradients"]) == sorted(chip_smoke.DECODER_GRADS)
     for name, g in out["gradients"].items():
         assert g["rel_l2"] < 2e-3 and g["cosine"] > 0.9999, (name, g)
+
+
+def test_decoder_reference_phase_takes_the_kimi_linear_configuration():
+    """`--decoder-reference kimi_linear_48b_a3b` at toy widths in f32: the
+    system's five kinds of layer against the literal recurrence, dense MLA
+    and the looped experts, given the system's picks; the fp8 control stands
+    far outside that agreement."""
+    import chip_smoke
+    from chipbench import run
+
+    spec = chip_smoke.DECODER_REFERENCES["kimi_linear_48b_a3b"]
+    _manifest, _chips, cell, cfg, cfgmod = run.load_cell(spec["cell"])
+    cfg.update(hidden_size=32, intermediate_size=48, vocab_size=64,
+               linear_attn_config={"kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+                                   "num_heads": 2, "head_dim": 16,
+                                   "short_conv_kernel_size": 4},
+               num_attention_heads=2, qk_nope_head_dim=16, qk_rope_head_dim=8,
+               v_head_dim=16, kv_lora_rank=12, num_experts=4, num_experts_routed=16,
+               ep_rank=1, num_experts_per_token=2, moe_intermediate_size=16,
+               dtype="float32")
+    cell.update(batch=2, seq_len=32)
+    out = chip_smoke.decoder_reference_numbers(cfg, cell, cfgmod, block=8,
+                                               config="kimi_linear_48b_a3b")
+    assert out["tokens"] == 32 and out["logits_rms"] > 0
+    assert out["logits_err"]["max"] < 1e-4
+    assert out["fp8_control_logits_err"]["rms"] > 30 * out["logits_err"]["rms"]
+    assert abs(out["loss_system"] - out["loss_reference_f32"]) < 1e-4
+    assert sorted(out["gradients"]) == sorted(n for n, *_ in spec["grads"])
+    for name, g in out["gradients"].items():
+        assert g["rel_l2"] < 3e-3 and g["cosine"] > 0.9999, (name, g)

@@ -264,6 +264,59 @@ def test_grouped_query_flash_compiles_at_the_decoder_cells_shapes(one_chip, wind
                                          "flash_bwd_dkv"))
 
 
+def test_two_size_flash_compiles_at_the_latent_attention_cells_shapes(one_chip):
+    """What `kimi_linear_48b_a3b.sft_t16384_ep32share` launches in its MLA
+    layer: 32 heads, q and k 192 wide (128 + 64), v 128 wide, one sequence
+    of 16,384, bf16, full causal.  The three kernels take both sizes: the
+    outputs' and cotangents' shapes say which operand got which."""
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 192), BF16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, 16384, 128), BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pk.flash_attention(q, k, v, causal=True, interpret=False)
+        assert out.shape == v.shape
+        return out.astype(F32).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    assert [g.shape[-1] for g in jax.eval_shape(grad, q, q, v)] == [192, 192, 128]
+    n, text = _custom_calls(grad, q, q, v)
+    assert n == 3  # forward, dq, dk+dv
+    assert all(name in text for name in ("flash_fwd", "flash_bwd_dq",
+                                         "flash_bwd_dkv"))
+
+
+def test_kda_core_compiles_at_the_cells_shapes(one_chip):
+    """KDA's chunked scan at (1, 16384, 32, 128) bf16, forward and backward:
+    a `while` over the four groups of 64 chunks each way and, inside, one
+    over a group's chunks (forward, recomputed in the backward pass, and
+    backward: five in all); a state kept per group and, while a group is
+    differentiated, per chunk of it ([64, 1, 32, 128, 128]), never T/64 of
+    them at once and nothing of T x 128 x 128; the 16 x 16 x 128 ratios of a
+    sub-block are fused into their reductions and never written; the
+    temporaries are under half of what all 256 chunks at once take (5.87
+    GB)."""
+    from mxnet_tpu.ops import linear_attention as la
+    t, h, d = 16384, 32, 128
+    qkv = jax.ShapeDtypeStruct((1, t, h, d), BF16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, t, h, d), F32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, t, h), F32, sharding=one_chip)
+
+    def loss(*args):
+        return (la.kda(*args).astype(F32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, g, beta).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 5 and "tpu_custom_call" not in text
+    assert f"f32[4,1,{h},{d},{d}]" in text                   # a state a group
+    assert f"f32[64,1,{h},{d},{d}]" in text                  # a state a chunk of one group
+    assert f"f32[{t // 64},1,{h},{d},{d}]" not in text
+    assert f"[{t},{h},{d},{d}]" not in text and f"[{t},1,{h},{d},{d}]" not in text
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r"= f32\[[\d,]*16,16,128\]\S* (fusion|multiply|exponential)\(", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip, monkeypatch):
     """bf16 rows through the held experts at the published widths and the
     cell's 16,384 tokens: the grouped matmuls and their gradients are this
