@@ -12,7 +12,8 @@ The block set `models/transformer.py` (a post-norm encoder) lacks:
 attention with the same mask off the TPU and at short T), `DecoderLayer`
 over `parallel.layers.RoutedExperts`, and `DecoderLM`, which takes the
 published ``layer_types`` pattern.  For Kimi-Linear (arXiv:2510.26692):
-`KimiDeltaAttention` on `ops/linear_attention.kda` (a chunked scan) behind
+`KimiDeltaAttention` on `ops/linear_attention.kda` (a chunked scan, its
+first phase a Pallas kernel pair on the TPU) behind
 short causal convolutions, `LatentAttention` (q.k heads of 192, v heads of
 128, on the flash kernel's two head sizes), `SwiGLU`, and a sigmoid router
 with a shared expert.  Trained like every other model here: `Trainer` +
@@ -26,6 +27,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from .. import initializer as init
 from .. import numpy_extension as npx
@@ -556,15 +558,45 @@ class DecoderLM(HybridBlock):
         return self.head(self.norm(x))
 
 
+@jax.custom_vjp
+def _next_token_nll(logits, targets):
+    """Mean over positions of logsumexp(logits) - logits[target], in f32:
+    the cross-entropy with the target picked BEFORE the log-sum-exp, so that
+    no (B, T, vocabulary) f32 log-softmax exists.  Written as one value and
+    its gradient: left to autodiff, the loss VALUE's pick is the last reader
+    of that array (1.34 GB in the Kimi cell), and the scheduler may put it
+    after the whole backward pass."""
+    return _next_token_nll_fwd(logits, targets)[0]
+
+
+def _next_token_nll_fwd(logits, targets):
+    x = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(x, axis=-1)
+    picked = jnp.take_along_axis(x, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - picked), (logits, lse, targets)
+
+
+def _next_token_nll_bwd(res, ct):
+    logits, lse, targets = res
+    # softmax - onehot, from the logits as the model wrote them
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1) \
+        == targets[..., None]
+    d = (p - hit.astype(jnp.float32)) * (ct / lse.size)
+    return d.astype(logits.dtype), onp.zeros(targets.shape, jax.dtypes.float0)
+
+
+_next_token_nll.defvjp(_next_token_nll_fwd, _next_token_nll_bwd)
+
+
 class CausalLMLoss(HybridBlock):
     """Mean next-token cross-entropy of a `DecoderLM`: position t
-    predicts ids[:, t+1]; the log-softmax is taken in f32."""
+    predicts ids[:, t+1]; the log-sum-exp is taken in f32."""
 
     def __init__(self, model):
         super().__init__()
         self.model = model
 
     def forward(self, ids):
-        logits = self.model(ids)[:, :-1]
-        logp = npx.log_softmax(logits.astype("float32"), axis=-1)
-        return -npx.pick(logp, ids[:, 1:], axis=-1).mean()
+        return invoke(_next_token_nll, (self.model(ids)[:, :-1], ids[:, 1:]),
+                      name="next_token_nll")

@@ -3,6 +3,7 @@ flash kernels, the sigmoid router, models/decoder.py's KimiDeltaAttention,
 LatentAttention and SwiGLU) against the plain f32 reference
 (models/reference/kimi_linear.py) on seeded weights, at tiny sizes on the
 CPU: the published layers 1-5 (KDA + dense | KDA, KDA, MLA, KDA + experts)."""
+import functools
 import math
 import os
 
@@ -105,15 +106,126 @@ def test_kda_rejects_a_chunk_that_sub_blocks_do_not_tile():
         la.kda(*kda_inputs(48, False), chunk=24)
 
 
-def test_kda_counts_its_lowering():
+@pytest.mark.parametrize("kernels", [False, True], ids=["chunked_scan", "pallas_chunk"])
+def test_kda_counts_its_lowering(monkeypatch, kernels):
+    """One trace raises exactly one label: the path the first phase took."""
     from mxnet_tpu import telemetry
+    monkeypatch.setattr(la, "_takes_kernels", lambda kd, vd, chunk: kernels)
 
-    def count():
+    def count(path):
         return telemetry.default_registry().get_sample_value(
-            "mxtpu_linear_attention_lowerings", {"path": "chunked_scan"}) or 0.0
-    before = count()
-    la.kda(*kda_inputs(16, False))
-    assert count() == before + 1
+            "mxtpu_linear_attention_lowerings", {"path": path}) or 0.0
+    paths = ("chunked_scan", "pallas_chunk")
+    before = [count(p) for p in paths]
+    jax.eval_shape(lambda *a: la.kda(*a), *kda_inputs(16, False))    # a trace of its own
+    assert [count(p) - b for p, b in zip(paths, before)] == [1.0 - kernels, 1.0 * kernels]
+
+
+def test_the_kernels_are_taken_on_tpu_where_the_shapes_tile(monkeypatch):
+    assert not la._takes_kernels(128, 128, 64)               # this process computes on the CPU
+    monkeypatch.setattr(la._context, "on_tpu", lambda: True)
+    assert la._takes_kernels(128, 128, 64) and la._takes_kernels(256, 128, 16)
+    assert not la._takes_kernels(64, 128, 64) and not la._takes_kernels(128, 192, 64)
+
+
+# ---------------------------------------------------------------------------
+# the first phase's kernel pair, interpreted, against its XLA form
+# ---------------------------------------------------------------------------
+CHUNK = 32     # two sub-blocks: the ratios inside one, the matmul between them
+
+
+def chunk_major(x, chunk=CHUNK):
+    """(B, T, H, ...) -> (T / chunk, B, H, chunk, ...), what `_within_chunks` takes."""
+    b, t = x.shape[:2]
+    x = x.reshape((b, t // chunk, chunk) + x.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+
+def xla_first_phase(*args):
+    return la._within_chunks(*(chunk_major(x) for x in args))
+
+
+def kernel_first_phase(*args):
+    b, t = args[0].shape[:2]                                  # heads side by side on the lanes
+    return la._within_chunks_kernels(*(x.reshape(b, t, -1) for x in args), CHUNK, True)
+
+
+def _outputs_and_gradients(fn):
+    """`fn`'s six outputs and the five gradients of a fixed weighted sum of them, as one jitted
+    program (the decays of a case share it)."""
+    def both(*args):
+        outs = fn(*args)
+        return sum(jnp.sum(jax.random.normal(jax.random.key(7 + i), o.shape) * o.astype(jnp.float32))
+                   for i, o in enumerate(outs)), outs
+    return jax.jit(jax.value_and_grad(both, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+
+_XLA_FIRST_PHASE = _outputs_and_gradients(xla_first_phase)
+_KERNEL_FIRST_PHASE = _outputs_and_gradients(kernel_first_phase)
+
+
+def first_phase_agrees(args, dtype):
+    """All six outputs and all five gradients of the kernel pair against the XLA form's."""
+    (_, want), grads_want = _XLA_FIRST_PHASE(*args)
+    (_, got), grads_got = _KERNEL_FIRST_PHASE(*args)
+    # bf16: an output may fall on the other side of a rounding (one unit in the last place of
+    # eight bits), and a cotangent that was rounded differently moves a gradient by as much
+    rel = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for kind, names, wants, gots in (("output", "W U0 GammaQ B Kdec GammaC".split(), want, got),
+                                     ("gradient", "q k v g beta".split(), grads_want, grads_got)):
+        for name, w, g in zip(names, wants, gots):
+            assert g.shape == w.shape and g.dtype == w.dtype, (kind, name)
+            w, g = onp.asarray(w, onp.float32), onp.asarray(g, onp.float32)
+            assert onp.isfinite(g).all(), (kind, name)
+            tol = rel * (4 if kind == "gradient" else 1)
+            if (kind, name) == ("gradient", "g"):
+                # sums of terms near e^-20 under the strong decay: f32 noise of the other
+                # terms' size beside exact zeros (the recurrence's test allows g the same)
+                tol = max(tol, 2e-3)
+            onp.testing.assert_allclose(g, w, rtol=tol, atol=tol * max(float(onp.abs(w).max()), 1e-30),
+                                        err_msg=f"{kind} {name}")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("chunks,b", [(1, 1), (3, 1), (8, 1), (2, 2)],
+                         ids=["1-chunk", "3-chunks", "8-chunks", "2-sequences"])
+@pytest.mark.parametrize("strong", [False, True], ids=["weak-decay", "strong-decay"])
+def test_the_kernel_pair_equals_the_xla_first_phase(strong, chunks, b, dtype):
+    """`kda_chunk_fwd` / `kda_chunk_bwd` interpreted on the CPU on the tiles the XLA form takes:
+    one head a grid step (h = 1, its inverse alone) and, with two sequences, four heads in two
+    steps of a pair (the inverses side by side; beta's column picked, its gradient summed over
+    the steps)."""
+    first_phase_agrees(kda_inputs(CHUNK * chunks, strong, dtype, b=b, h=1 if b == 1 else 4,
+                                  seed=chunks), dtype)
+
+
+def _sin_loss_and_output(fn):
+    def both(*args):
+        o = fn(*args)
+        return jnp.sum(jnp.sin(3.0 * o)), o
+    return jax.jit(jax.value_and_grad(both, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+
+# one program for both decays: jitted once here, traced under the first case's patches
+_KERNEL_PATH = _sin_loss_and_output(functools.partial(la.kda, chunk=CHUNK))
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["weak-decay", "strong-decay"])
+def test_kda_on_the_kernel_path_equals_the_literal_recurrence(monkeypatch, strong):
+    """`kda` end to end with the first phase in the kernels (interpreted, a pair of heads a grid
+    step), over more chunks than a group holds (180 tokens are padded to six chunks of 32, in
+    three groups of two): the tolerances of the XLA path's test."""
+    monkeypatch.setattr(la, "_takes_kernels", lambda kd, vd, chunk: True)
+    monkeypatch.setattr(la, "_CHUNKS_AT_ONCE", 2)
+    args = kda_inputs(180, strong, h=2, seed=4)
+    (_loss, o), got = _KERNEL_PATH(*args)
+    onp.testing.assert_allclose(o, literal(*args), rtol=1e-4, atol=2e-6)
+    (_loss, _o), want = _sin_loss_and_output(literal)(*args)
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert onp.isfinite(onp.asarray(g)).all(), name
+        scale = float(jnp.abs(w).max())
+        onp.testing.assert_allclose(g, w, rtol=2e-3, err_msg=name,
+                                    atol=(2e-3 if name == "g" else 2e-4) * scale)
 
 
 @pytest.mark.parametrize("fn", [la.causal_conv, ref.short_conv], ids=["system", "reference"])

@@ -107,6 +107,26 @@ def test_logits_and_loss_match_the_reference(held, rank):
         loss, ref.loss(params, jnp.asarray(x), CFG, **share), rtol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_loss_picks_before_the_log_sum_exp_and_means_the_same(dtype):
+    """`CausalLMLoss`'s value and gradient rule against the log-softmax written out: the same
+    mean and the same softmax - onehot, in the logits' dtype, with no array of log-probabilities."""
+    from mxnet_tpu.models import decoder
+    logits = (3.0 * jax.random.normal(jax.random.key(0), (2, 7, 50))).astype(dtype)
+    targets = jax.random.randint(jax.random.key(1), (2, 7), 0, 50)
+
+    def written_out(logits):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
+    want, want_grad = jax.value_and_grad(written_out)(logits)
+    got, got_grad = jax.value_and_grad(decoder._next_token_nll)(logits, targets)
+    onp.testing.assert_allclose(got, want, rtol=1e-6)
+    assert got_grad.dtype == dtype
+    onp.testing.assert_allclose(onp.asarray(got_grad, onp.float32), onp.asarray(want_grad, onp.float32),
+                                rtol=1e-5 if dtype == jnp.float32 else 2.0 ** -7, atol=1e-8)
+    assert "log_softmax" not in str(jax.make_jaxpr(jax.grad(decoder._next_token_nll))(logits, targets))
+
+
 def _system_grads(model, x):
     mod = CausalLMLoss(model)
     mod.hybridize()

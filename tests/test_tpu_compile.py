@@ -285,36 +285,68 @@ def test_two_size_flash_compiles_at_the_latent_attention_cells_shapes(one_chip):
                                          "flash_bwd_dkv"))
 
 
-def test_kda_core_compiles_at_the_cells_shapes(one_chip):
-    """KDA's chunked scan at (1, 16384, 32, 128) bf16, forward and backward:
-    a `while` over the four groups of 64 chunks each way and, inside, one
-    over a group's chunks (forward, recomputed in the backward pass, and
-    backward: five in all); a state kept per group and, while a group is
-    differentiated, per chunk of it ([64, 1, 32, 128, 128]), never T/64 of
-    them at once and nothing of T x 128 x 128; the 16 x 16 x 128 ratios of a
-    sub-block are fused into their reductions and never written; the
-    temporaries are under half of what all 256 chunks at once take (5.87
-    GB)."""
+def test_kda_core_compiles_at_the_cells_shapes(one_chip, monkeypatch):
+    """KDA's core at (1, 16384, 32, 128) bf16, forward and backward, on the
+    kernel path, its operands views of (B, T, H K) arrays as the layer's are:
+    the first phase is `kda_chunk_fwd` (in the forward loop and again where
+    the backward pass recomputes a group) and `kda_chunk_bwd`, which read
+    q, k, v, g where they lie (no copy to chunk-major, none to any other
+    layout); no triangular solve, no f32 [.., 64, 64] tensor and no
+    16 x 16 x 128 ratio is XLA's to write any more.  Still a `while` over the
+    four groups of 64 chunks each way and, inside, one over a group's chunks
+    (forward, recomputed, backward: five in all), a state kept per group
+    and, while a group is differentiated, per chunk of it, never T/64 of
+    them at once; the temporaries are half of the XLA form's 2.22 GB."""
     from mxnet_tpu.ops import linear_attention as la
+    monkeypatch.setattr(la._context, "on_tpu", lambda: True)
     t, h, d = 16384, 32, 128
-    qkv = jax.ShapeDtypeStruct((1, t, h, d), BF16, sharding=one_chip)
-    g = jax.ShapeDtypeStruct((1, t, h, d), F32, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct((1, t, h * d), BF16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, t, h * d), F32, sharding=one_chip)
     beta = jax.ShapeDtypeStruct((1, t, h), F32, sharding=one_chip)
 
-    def loss(*args):
-        return (la.kda(*args).astype(F32) ** 2).sum()
+    def loss(q, k, v, g, beta):
+        q, k, v, g = (x.reshape(1, t, h, d) for x in (q, k, v, g))
+        return (la.kda(q, k, v, g, beta).astype(F32) ** 2).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         qkv, qkv, qkv, g, beta).compile()
     text = compiled.as_text()
-    assert text.count(" while(") == 5 and "tpu_custom_call" not in text
+    calls = re.findall(r"%(kda_chunk_\w+?)[.\d]* = \S.* custom-call\(", text)
+    assert sorted(calls) == ["kda_chunk_bwd", "kda_chunk_fwd", "kda_chunk_fwd"]
+    assert text.count("tpu_custom_call") == 3 and "riangular" not in text
+    assert text.count(" while(") == 5
     assert f"f32[4,1,{h},{d},{d}]" in text                   # a state a group
     assert f"f32[64,1,{h},{d},{d}]" in text                  # a state a chunk of one group
     assert f"f32[{t // 64},1,{h},{d},{d}]" not in text
     assert f"[{t},{h},{d},{d}]" not in text and f"[{t},1,{h},{d},{d}]" not in text
-    entry = text[text.index("ENTRY"):]
-    assert not re.search(r"= f32\[[\d,]*16,16,128\]\S* (fusion|multiply|exponential)\(", entry)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+    assert not re.search(rf"f32\[[\d,]*1,{h},64,64\]", text)   # A, B, the inverse: chunk-major
+    assert not re.search(r"\[[\d,]*16,16,128\]", text)
+    # q, k, v, g and their cotangents stay (B, T, H K): nothing of their size
+    # is copied, transposed or reshaped, whole or a group of 4096 tokens
+    moved = re.findall(r"= (\w+)\[([\d,]+)\]\S* (?:copy|transpose|reshape)\(", text)
+    assert [m for m in moved if math.prod(map(int, m[1].split(","))) >= 4096 * h * d] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
+def test_the_head_and_loss_hold_no_f32_log_softmax(one_chip):
+    """The Kimi cell's head and loss, value and gradient, at (1, 16383,
+    20480) on 2304 units: the target is picked before the log-sum-exp, so no
+    f32 [1, 16383, 20480] array of log-probabilities is written for the loss
+    VALUE to read after the backward pass (2.01 GB of temporaries; 2.68 with
+    the log-softmax left to autodiff, 1.34 of it held through a step)."""
+    from mxnet_tpu.models import decoder
+    t, u, vocab = 16383, 2304, 20480
+    x = jax.ShapeDtypeStruct((1, t, u), BF16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((vocab, u), BF16, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, t), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, ids):
+        return decoder._next_token_nll(jnp.einsum("btu,vu->btv", x, w), ids)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        x, w, ids).compile()
+    assert f"f32[1,{t},{vocab}]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
 
 
 def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip, monkeypatch):
