@@ -8,6 +8,10 @@ the map from the reference (`sxjscience/mxnet`) to this design.  Import as::
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_BEGIN_NS = _time.monotonic_ns()   # the package's first line
+
 import os as _os
 
 # Lock-acquisition witness (tools/lockscan's runtime half): the factory
@@ -20,6 +24,15 @@ if _os.environ.get("MXNET_LOCKSCAN_WITNESS", "") not in ("", "0"):
     _lockwitness.install()
 
 import jax as _jax
+
+# Set-up is on the span record from here (docs/OBSERVABILITY.md section 2):
+# `runtime.import` is this file, first line to last, and what the import
+# does under a span of its own (`runtime.backend_start`, random.py) is its
+# child.  The span machinery starts no backend.
+from . import telemetry as _telemetry
+
+_import_span = _telemetry.span("runtime.import", cat="setup").__enter__()
+_import_span.begin_ns = _IMPORT_BEGIN_NS
 
 # Multi-host bootstrap: when tools/launch.py (or a pod scheduler) provides
 # coordination env vars, wire jax.distributed now — it must run before
@@ -111,6 +124,15 @@ from . import numpy_dispatch  # noqa: E402  (needs np + NDArray above)
 
 # legacy custom-op entry: mx.nd.Custom(data..., op_type="name")
 ndarray.Custom = operator.invoke_custom  # (mx.nd is the same module)
+
+_import_span.__exit__(None, None, None)
+# the interpreter, `import jax` and whatever the host script did before it
+# imported this package (a TPU runtime's start, if it asked for devices)
+_process_start_ns = _telemetry.process_start_ns()
+if _process_start_ns is not None:
+    _telemetry.record_finished("process.before_import", "setup",
+                               _process_start_ns, _IMPORT_BEGIN_NS)
+del _import_span, _process_start_ns
 
 __all__ = [
     "MXNetError", "Context", "cpu", "gpu", "tpu", "NDArray", "nd", "np",

@@ -21,6 +21,7 @@ redirected through a trace-scope override) in ``jax.jit``:
 """
 from __future__ import annotations
 
+import contextlib
 import re
 
 import jax
@@ -35,6 +36,7 @@ from ..ops.invoke import (invoke, is_training, set_recording,
 from ..ops.aux_scope import aux_update_scope
 from .. import initializer as _initializer
 from .. import random as _rng
+from .. import telemetry as _telemetry
 from .parameter import Parameter, DeferredInitializationError, _param_override_scope
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock"]
@@ -44,17 +46,35 @@ def _is_nd(x):
     return isinstance(x, NDArray)
 
 
-def _first_ctx(items):
-    """Context of the first NDArray found in items (one level of
-    list/tuple nesting, covering RNN-style state lists)."""
+def _first_nd(items):
+    """The first NDArray found in items (one level of list/tuple nesting,
+    covering RNN-style state lists)."""
     for a in items:
         if _is_nd(a):
-            return a.ctx
+            return a
         if isinstance(a, (list, tuple)):
             for b in a:
                 if _is_nd(b):
-                    return b.ctx
+                    return b
     return None
+
+
+def _first_ctx(items):
+    """Context of the first NDArray found in items."""
+    first = _first_nd(items)
+    return None if first is None else first.ctx
+
+
+def _trace_span(block):
+    """The span of one block's ``forward`` while jax traces it: a step's
+    (or a hybridized block's) trace split by Gluon block on the span record.
+    Shorter than the ``xla.trace`` floor it is not recorded, and its time
+    stays in the enclosing block's self time."""
+    return _telemetry.trace_span("block.trace", block=block.name,
+                                 cls=type(block).__name__)
+
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Block:
@@ -162,7 +182,6 @@ class Block:
     # -- lifecycle ---------------------------------------------------------
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
-        from .. import telemetry as _telemetry
         if init is None:
             init = _initializer.Uniform()
         params = self.collect_params()
@@ -273,12 +292,18 @@ class Block:
         # classic multi-device data parallelism: parameters resolve their
         # per-context copy through current_context(), so scope it to the
         # input's context (the reference dispatches kernels by data ctx)
-        in_ctx = _first_ctx(args) or _first_ctx(kwargs.values())
+        first = _first_nd(args)
+        if first is None:
+            first = _first_nd(kwargs.values())
+        in_ctx = None if first is None else first.ctx
         # name-scope the forward so ops traced inside land in HLO
         # metadata as "<parent>/<name>/<op>" — the census
         # (mxnet_tpu/analysis/census.py) buckets compiled cost by these
-        # paths.  Outside a trace this is a thread-local push/pop.
-        with jax.named_scope(self.name):
+        # paths.  Outside a trace this is a thread-local push/pop, and the
+        # test on the input is all the span record costs an eager call.
+        traced = first is not None and isinstance(first._data, jax.core.Tracer)
+        with jax.named_scope(self.name), \
+                _trace_span(self) if traced else _NO_SPAN:
             if in_ctx is not None and in_ctx != current_context():
                 with in_ctx:
                     out = self.forward(*args, **kwargs)
@@ -393,10 +418,13 @@ class HybridBlock(Block):
         if not pending:
             return
         # one eager forward infers shapes & finishes deferred init
-        # (reference: deferred compute's shape inference, block.py:994)
+        # (reference: deferred compute's shape inference, block.py:994);
+        # it compiles a small program a shape, so set-up pays for it
         prev_rec = set_recording(False)
         try:
-            self.forward(*args)
+            with _telemetry.span("block.settle_shapes", cat="setup",
+                                 block=self.name, pending=len(pending)):
+                self.forward(*args)
         finally:
             set_recording(prev_rec)
 
@@ -449,7 +477,6 @@ class HybridBlock(Block):
         # retrace watchdog: a steady-state recompile of the hybridized
         # program (shape drift past warmup) is the bug class serving
         # buckets exist to prevent — count it and warn
-        from .. import telemetry as _telemetry
         _telemetry.watchdog().observe(
             jit_fn, name=f"{type(self).__name__}.hybrid_forward",
             scope_root=self.name)
@@ -571,7 +598,8 @@ def _scoped_forward(block, plist, param_datas, key, flat_inputs, treedef,
         training if backward is None else backward)
     try:
         with _param_override_scope(mapping), _rng.key_stream_scope(key), \
-                aux_update_scope() as aux, jax.named_scope(block.name):
+                aux_update_scope() as aux, jax.named_scope(block.name), \
+                _trace_span(block):
             out = block.forward(*args)
     finally:
         set_recording(prev_rec)

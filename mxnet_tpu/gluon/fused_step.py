@@ -298,14 +298,23 @@ class FusedTrainStep:
             return self._call_args(args, batch_size)
 
     def _call_args(self, args, batch_size):
-        if self._plist is None:
-            self._setup(args)
+        flat, treedef = jax.tree_util.tree_flatten(args, is_leaf=_is_nd)
+        treedef_id = _intern_treedef(treedef)
+        if self._jit is None:
+            # what the first call does once: set-up's part of it, under the
+            # first `fused_step.prepare` on the span record
+            with _telemetry.span("fused_step.build", cat="setup") as built:
+                self._setup(args)
+                self._jit = self._build(treedef_id)
+                built.args.update(
+                    params=len(self._plist),
+                    states=sum(len(_as_tuple(self._trainer._states[i]))
+                               for i in self._opt_index))
         trainer = self._trainer
         optimizer = trainer._optimizer
         optimizer.rescale_grad = trainer._scale / batch_size
         plist = self._plist
 
-        flat, treedef = jax.tree_util.tree_flatten(args, is_leaf=_is_nd)
         flat = [a._data if _is_nd(a) else a for a in flat]
         if self._mesh is not None:
             # batch-shard inputs whose leading dim divides over the data
@@ -331,9 +340,6 @@ class FusedTrainStep:
                     return d
                 return self._global_put(d, target)
             flat = [place(d) for d in flat]
-        treedef_id = _intern_treedef(treedef)
-        if self._jit is None:
-            self._jit = self._build(treedef_id)
 
         pd = [p.data()._data for p in plist]
         train_ws = tuple(pd[k] for k in self._train_idx)

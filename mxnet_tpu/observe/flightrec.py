@@ -5,7 +5,9 @@ The recorder is a *sink*, not an instrumentation pass: the taps live in
 the subsystems that already observe the interesting transitions —
 ``telemetry.span`` (every timed region of the program is ONE event that
 holds the whole span — begin, end, parent, step — so the ring is also the
-program's span record from process start; ``spans()`` reads it back),
+program's span record from process start; ``spans()`` reads it back, and
+the spans of set-up, those that began before the first ``fused_step.step``
+span ended, are also held where the ring's wrap does not reach),
 ``resilience.faultline``
 (injections), ``resilience.sentinel`` (straggler demotions, divergence
 trips), ``resilience.elastic`` (reshards, rollbacks, preempt resumes),
@@ -59,12 +61,21 @@ SCHEMA_VERSION = 1
 _SPAN_KEYS = frozenset(("begin_ns", "end_ns", "seconds", "id", "parent", "step"))
 
 
-class SpanList(list):
-    """What `spans()` returns: the spans, and how many events the ring lost."""
+# the span that ends set-up: a span that began before the first of these
+# ended is kept beside the ring, at most `_SETUP_SPANS` of them
+_SETUP_ENDS_WITH = "fused_step.step"
+_SETUP_SPANS = 4096
 
-    def __init__(self, dropped=0):
+
+class SpanList(list):
+    """What `spans()` returns: the spans; ``dropped``, how many events the
+    ring lost that nothing else holds; ``setup_dropped``, how many spans of
+    set-up found their store full (0: the set-up record is whole)."""
+
+    def __init__(self, dropped=0, setup_dropped=0):
         super().__init__()
         self.dropped = dropped
+        self.setup_dropped = setup_dropped
 
 
 class FlightRecorder:
@@ -82,6 +93,7 @@ class FlightRecorder:
                          if enabled is None else bool(enabled))
         self._buf = [None] * self._cap
         self._n = 0
+        self._clear_setup()
         self._rank = 0
         self._generation = 0
         self._step = None
@@ -96,10 +108,22 @@ class FlightRecorder:
         self._append((time.monotonic_ns(), time.time_ns(), self._rank,
                       self._generation, category, name, payload or None))
 
-    def _append(self, ev):
+    def _append(self, ev, setup=False):
         with self._lock:
+            if setup:
+                if len(self._setup) < _SETUP_SPANS:
+                    self._setup.append((self._n, ev))
+                else:
+                    self._setup_dropped += 1
             self._buf[self._n % self._cap] = ev
             self._n += 1
+
+    def _clear_setup(self):
+        # [(the event's number in the ring's count, the event)], and the end
+        # of the first `_SETUP_ENDS_WITH` span (None: set-up is not over)
+        self._setup = []
+        self._setup_dropped = 0
+        self._setup_end = None
 
     def record_span(self, category, name, begin_ns, end_ns=None, **payload):
         """Append one event that is a whole span and return its end on the
@@ -114,8 +138,12 @@ class FlightRecorder:
             payload["seconds"] = (end - begin_ns) * 1e-9
             if end_ns is not None:
                 payload["end_ns"] = end_ns
+            setup_end = self._setup_end
             self._append((now, time.time_ns(), self._rank, self._generation,
-                          category, name, payload))
+                          category, name, payload),
+                         setup=setup_end is None or begin_ns < setup_end)
+            if setup_end is None and name == _SETUP_ENDS_WITH:
+                self._setup_end = end
         return end
 
     # -- context ----------------------------------------------------------
@@ -156,14 +184,23 @@ class FlightRecorder:
             return self._ordered()
 
     def spans(self, name=None):
-        """The span events still in the ring, oldest first, as dicts with
-        ``name``, ``cat``, ``begin_ns``, ``end_ns`` (both on the ``mono_ns``
-        clock), ``id``, ``parent``, ``step`` and ``args``; ``.dropped`` on
-        the list is the number of events of any kind the ring has
-        overwritten, so 0 means the record is whole since process start."""
+        """The spans of set-up (kept beside the ring: those that began
+        before the first ``fused_step.step`` span ended), then the other span
+        events still in the ring, oldest first, as dicts with ``name``,
+        ``cat``, ``begin_ns``, ``end_ns`` (both on the ``mono_ns`` clock),
+        ``id``, ``parent``, ``step`` and ``args``.  ``.dropped`` on the list
+        is the number of events of any kind that the ring has overwritten
+        and set-up's store does not hold, so 0 means the record is whole
+        since process start; ``.setup_dropped`` the spans of set-up that
+        found the store full, so 0 means set-up's is, however long the run."""
         with self._lock:
-            evs, dropped = self._ordered(), max(0, self._n - self._cap)
-        out = SpanList(dropped)
+            evs, lost = self._ordered(), max(0, self._n - self._cap)
+            kept, setup_dropped = list(self._setup), self._setup_dropped
+        held = {number for number, _ev in kept}
+        evs = [ev for _number, ev in kept] + \
+            [ev for number, ev in enumerate(evs, lost) if number not in held]
+        out = SpanList(lost - sum(number < lost for number in held),
+                       setup_dropped)
         for mono, _wall, _rank, _gen, cat, ev_name, payload in evs:
             if not payload or "begin_ns" not in payload or \
                     (name is not None and ev_name != name):
@@ -242,6 +279,7 @@ class FlightRecorder:
                              if enabled is None else bool(enabled))
             self._buf = [None] * self._cap
             self._n = 0
+            self._clear_setup()
             self._generation = 0
             self._step = None
 
@@ -295,6 +333,7 @@ def configure(root=None, capacity=None, enabled=None):
                 _recorder._cap = int(capacity)
                 _recorder._buf = [None] * _recorder._cap
                 _recorder._n = 0
+                _recorder._clear_setup()
             if enabled is not None:
                 _recorder._enabled = bool(enabled)
 

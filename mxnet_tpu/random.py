@@ -16,6 +16,9 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax._src import xla_bridge as _xla_bridge
+
+from . import telemetry as _telemetry
 
 __all__ = ["seed", "new_key", "advance", "key_stream_scope", "uniform",
            "normal", "randint", "host_rng"]
@@ -30,7 +33,15 @@ class _KeyState(threading.local):
         self.host_seeded_with = None
 
 
-_state = _KeyState()
+# the root key is the package's first touch of the XLA backend: importing
+# the package starts the runtime, unless the host script already had
+with _telemetry.span(
+        "runtime.backend_start", cat="setup",
+        already_up=_xla_bridge.backends_are_initialized()) as _started:
+    _state = _KeyState()
+    _started.args.update(platform=jax.default_backend(),
+                         devices=jax.device_count())
+del _started
 
 # process-wide host seed so worker threads created AFTER mx.random.seed()
 # still derive deterministic streams (each thread gets its own Generator,

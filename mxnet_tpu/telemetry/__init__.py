@@ -24,17 +24,21 @@ from .registry import (
     default_registry, counter, gauge, histogram,
     export_prometheus, export_json,
 )
-from .spans import span, step_phase, collective_span, mark_step
+from .spans import (span, record_finished, process_start_ns, step_phase,
+                    collective_span, mark_step)
 from .watchdog import (
     RetraceWatchdog, watchdog, watch_jit, install_compile_listener,
+    trace_span,
 )
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
     "default_registry", "counter", "gauge", "histogram",
     "export_prometheus", "export_json",
-    "span", "step_phase", "collective_span", "mark_step",
+    "span", "record_finished", "process_start_ns", "step_phase",
+    "collective_span", "mark_step",
     "RetraceWatchdog", "watchdog", "watch_jit", "install_compile_listener",
+    "trace_span",
 ]
 
 # the listener only fires on compiles — safe to wire unconditionally

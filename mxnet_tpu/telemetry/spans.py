@@ -20,10 +20,17 @@ flight recorder's ``mono_ns``):
 (histogram / counters) and are otherwise this span.  A span costs one
 clock read at entry and one event at exit — per step, collective or
 batch, never per op.
+
+Set-up is on the same record from the process's first instruction:
+``process_start_ns`` puts the kernel's start stamp on the spans' clock, and
+``record_finished`` takes what was timed before this module could be
+imported (``mxnet_tpu/__init__.py`` records ``process.before_import`` and
+opens ``runtime.import`` with it).
 """
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import time
 
@@ -33,8 +40,8 @@ from .. import observe as _observe
 from .. import profiler as _profiler
 from . import registry as _registry
 
-__all__ = ["span", "record_finished", "step_phase", "collective_span",
-           "mark_step"]
+__all__ = ["span", "record_finished", "process_start_ns", "step_phase",
+           "collective_span", "mark_step"]
 
 _ids = itertools.count(1)       # next() is atomic under the interpreter lock
 _open = threading.local()       # .stack: the spans open on this thread
@@ -58,17 +65,23 @@ class span:
     """Time the ``with`` block as ``name`` under category ``cat``.
 
     ``step`` is the training step the span belongs to; left out, a span
-    takes its parent's.  Further keywords are the span's arguments; code
-    inside the block may add to ``.args`` what it learns there.  After
-    exit ``begin_ns``, ``end_ns`` and ``seconds`` hold the reading."""
+    takes its parent's.  A span shorter than ``floor_s`` seconds is left
+    as ``cancel()`` leaves it: where a region runs by the thousand and
+    only the long ones matter, the short ones stay in their parent's self
+    time and take no slot in the record.  Further keywords are the span's
+    arguments; code inside the block may add to ``.args`` what it learns
+    there.  After exit ``begin_ns``, ``end_ns`` and ``seconds`` hold the
+    reading."""
 
-    __slots__ = ("name", "cat", "step", "args", "id", "parent",
+    __slots__ = ("name", "cat", "step", "floor_s", "args", "id", "parent",
                  "begin_ns", "end_ns", "_annotation")
 
-    def __init__(self, name, cat="step_phase", step=None, **args):
+    def __init__(self, name, cat="step_phase", step=None, floor_s=0.0,
+                 **args):
         self.name = name
         self.cat = cat
         self.step = step
+        self.floor_s = floor_s
         self.args = args
 
     def __enter__(self):
@@ -85,6 +98,11 @@ class span:
         return self
 
     def __exit__(self, *exc):
+        if self.floor_s:
+            self.end_ns = time.monotonic_ns()
+            if self.end_ns - self.begin_ns < self.floor_s * 1e9:
+                self._close(*exc)
+                return False
         self.end_ns = _observe.record_span(
             self.cat, self.name, self.begin_ns, id=self.id,
             parent=self.parent, step=self.step, **self.args)
@@ -118,6 +136,22 @@ def record_finished(name, cat, begin_ns, end_ns, **args):
     _observe.record_span(cat, name, begin_ns, end_ns, id=next(_ids),
                          parent=parent_id, step=step, **args)
     _emit_chrome(name, cat, begin_ns, end_ns, args)
+
+
+def process_start_ns():
+    """When this process started, on the spans' clock, or None where the
+    system does not say: ``/proc/self/stat`` holds the start in ticks since
+    boot, and ``CLOCK_BOOTTIME`` counts from there (to a tick, 10 ms)."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command's name, which may hold spaces
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME) - \
+            ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+    # a sandbox may count its ticks from another boot than its clock does
+    return time.monotonic_ns() - age_ns if age_ns >= 0 else None
 
 
 def _phase_histogram():

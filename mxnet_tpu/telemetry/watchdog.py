@@ -12,7 +12,9 @@ visible through the profiler; here they are first-class metrics:
   and records each as a span ``xla.trace`` / ``xla.lower`` /
   ``xla.compile`` with jax's ``fun_name`` (``telemetry.spans``), so the
   record says which function compiled, when, and under which step (a
-  trace under 10 ms, as every eager op's is, is counted and not recorded);
+  trace under 10 ms, as every eager op's is, is counted and not recorded;
+  ``trace_span`` puts the Gluon blocks' ``block.trace`` spans beneath a
+  trace under the same floor);
 * per-function attribution rides the jit trace-cache size:
   ``RetraceWatchdog.observe(fn, name)`` (called by ``HybridBlock`` and
   ``FusedTrainStep`` after each dispatch, or via the ``watch_jit``
@@ -34,7 +36,7 @@ from . import registry as _registry
 from . import spans as _spans
 
 __all__ = ["RetraceWatchdog", "watchdog", "watch_jit",
-           "install_compile_listener"]
+           "install_compile_listener", "trace_span"]
 
 _log = logging.getLogger("mxnet_tpu.telemetry")
 
@@ -53,6 +55,16 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # set-up 938 of the ring's 1,349 events), and the counters below keep their
 # number and their sum
 _TRACE_SPAN_FLOOR_S = 0.01
+
+
+def trace_span(name, **args):
+    """A span over Python work done while jax traces (a Gluon block's
+    ``forward`` on tracers: ``block.trace``), in the ``xla.*`` spans'
+    category and under the floor of ``xla.trace``: a model's trace runs
+    thousands of them, and a shorter one is not recorded."""
+    return _spans.span(name, cat="compile", floor_s=_TRACE_SPAN_FLOOR_S,
+                       **args)
+
 
 # compiles are seconds-scale events; default sub-ms buckets would be noise
 _COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0, 60.0)

@@ -679,12 +679,16 @@ def test_exhausted_dataloader_probe_leaves_no_span_open(record):
 def test_spans_reports_what_the_ring_dropped():
     from mxnet_tpu.observe import FlightRecorder
     rec = FlightRecorder(capacity=4, enabled=True)
+    # set-up is over (its spans are kept beside the ring: see below)
+    rec.record_span("step_phase", "fused_step.step", begin_ns=0, end_ns=1,
+                    id=100, parent=None, step=1)
     for i in range(6):
-        rec.record_span("unit", f"s{i}", begin_ns=i, id=i, parent=None,
+        rec.record_span("unit", f"s{i}", begin_ns=1 + i, id=i, parent=None,
                         step=None)
     got = rec.spans()
-    assert [s["name"] for s in got] == ["s2", "s3", "s4", "s5"]
-    assert got.dropped == 2
+    assert [s["name"] for s in got] == \
+        ["fused_step.step", "s2", "s3", "s4", "s5"]
+    assert got.dropped == 2 and got.setup_dropped == 0
     # a span reported after the fact keeps its own end
     rec.record_span("unit", "late", begin_ns=10, end_ns=25, id=9,
                     parent=None, step=None)
@@ -855,3 +859,243 @@ def test_block_children_public_iteration():
     names = {n for _s, n, _v in mon.toc()}
     assert any(".0_output" in n for n in names)
     mon.uninstall()
+
+
+# ---------------------------------------------------------------------------
+# set-up on the span record: process start to the first dispatched step
+# ---------------------------------------------------------------------------
+
+_IMPORT_AND_PRINT = """
+import json
+{before}
+import mxnet_tpu
+from mxnet_tpu import observe
+print(json.dumps(observe.spans()))
+"""
+
+
+@pytest.mark.parametrize("before,already_up", [
+    ("", False), ("import jax; jax.devices()", True)])
+def test_the_import_leaves_its_three_spans(before, already_up):
+    """In a process of its own: the record starts with the process."""
+    import subprocess
+    done = subprocess.run(
+        [sys.executable, "-c", _IMPORT_AND_PRINT.format(before=before)],
+        capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    spans = json.loads(done.stdout.strip().splitlines()[-1])
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    (before_import,) = by_name["process.before_import"]
+    (imported,) = by_name["runtime.import"]
+    (started,) = by_name["runtime.backend_start"]
+    assert {s["cat"] for s in (before_import, imported, started)} == {"setup"}
+    assert before_import["parent"] is None and imported["parent"] is None
+    assert before_import["args"] == {} == imported["args"]
+    # process start, then the package's first line, then its last
+    assert before_import["begin_ns"] < before_import["end_ns"] == \
+        imported["begin_ns"] < imported["end_ns"]
+    assert (before_import["end_ns"] - before_import["begin_ns"]) < 120e9
+    # the package's first touch of the backend is inside its import
+    assert started["parent"] == imported["id"]
+    assert imported["begin_ns"] <= started["begin_ns"] <= started["end_ns"] \
+        <= imported["end_ns"]
+    assert started["args"] == {"platform": "cpu", "devices": 8,
+                               "already_up": already_up}
+    # nothing compiled before the span that starts the runtime, unless the
+    # host script had started it
+    first_xla = min(s["begin_ns"] for s in spans if s["name"].startswith("xla."))
+    assert already_up or first_xla >= started["begin_ns"]
+
+
+def test_process_start_is_on_the_spans_clock_or_none(monkeypatch):
+    import builtins
+    import time
+    start = telemetry.process_start_ns()
+    assert start is not None and 0 < time.monotonic_ns() - start < 3600e9 * 24
+    assert abs(telemetry.process_start_ns() - start) < 50e6     # to a tick or two
+
+    def no_proc(path, *a, **kw):
+        raise FileNotFoundError(path)
+    monkeypatch.setattr(builtins, "open", no_proc)
+    assert telemetry.process_start_ns() is None
+
+
+class _Deferred(mx.gluon.HybridBlock):
+    """Two Dense layers whose input widths wait for the first forward."""
+
+    def __init__(self):
+        super().__init__()
+        self.a = nn.Dense(5)
+        self.b = nn.Dense(2)
+
+    def forward(self, x):
+        return self.b(self.a(x))
+
+
+def test_settling_deferred_shapes_is_one_span_and_only_when_pending(record):
+    net = _Deferred()
+    net.initialize()
+    pending = sum(p._deferred_init is not None
+                  for p in net.collect_params().values())
+    assert pending >= 2
+    net.hybridize()
+    x = mx.np.ones((3, 7))
+    net(x)
+    (settled,) = record.spans("block.settle_shapes")
+    assert settled["cat"] == "setup" and settled["parent"] is None
+    assert settled["args"] == {"block": net.name, "pending": pending}
+    # the eager forward's small programs compiled inside it
+    inside = [s for s in record.spans("xla.compile")
+              if s["parent"] == settled["id"]]
+    assert inside and all(settled["begin_ns"] <= s["begin_ns"] <= s["end_ns"]
+                          <= settled["end_ns"] for s in inside)
+    net(x)
+    net.infer_shape(x)
+    assert len(record.spans("block.settle_shapes")) == 1    # nothing pending
+
+
+def test_the_first_prepare_builds_once(record):
+    fused, args = _toy_fused_step()
+    for _ in range(3):
+        fused(*args, batch_size=4)
+    (built,) = record.spans("fused_step.build")
+    first_prepare = record.spans("fused_step.prepare")[0]
+    assert built["parent"] == first_prepare["id"] and built["step"] == 1
+    assert built["cat"] == "setup"
+    assert first_prepare["begin_ns"] <= built["begin_ns"] <= built["end_ns"] \
+        <= first_prepare["end_ns"]
+    # Dense(1): weight and bias, one momentum each
+    assert built["args"] == {"params": 2, "states": 2}
+
+
+def test_block_trace_splits_a_steps_trace_by_block(record):
+    """The `record` fixture takes the floor away: every block's trace shows."""
+    fused, args = _toy_fused_step()
+    fused(*args, batch_size=4)
+    traces = record.spans("block.trace")
+    assert [(s["args"]["cls"], s["cat"]) for s in traces] == \
+        [("Dense", "compile"), ("WithLoss", "compile")]     # recorded at exit
+    dense, with_loss = traces
+    assert dense["parent"] == with_loss["id"] and dense["step"] == 1
+    assert dense["args"]["block"] == fused._block.net.name
+    assert with_loss["begin_ns"] <= dense["begin_ns"] <= dense["end_ns"] \
+        <= with_loss["end_ns"]
+    # the whole of it under the step's outermost trace, inside the launch
+    (launch,) = record.spans("fused_step.launch")
+    (step_trace,) = [s for s in record.spans("xla.trace")
+                     if "fused" in s["args"]["fun_name"]]
+    assert with_loss["parent"] == launch["id"] == step_trace["parent"]
+    assert step_trace["begin_ns"] <= with_loss["begin_ns"] \
+        and with_loss["end_ns"] <= step_trace["end_ns"]
+    # a second step traces nothing, and an eager call is no trace
+    fused(*args, batch_size=4)
+    fused._block(*args)
+    assert len(record.spans("block.trace")) == 2
+
+
+def test_block_trace_under_a_hybridized_blocks_own_trace(record):
+    net = _Deferred()
+    net.initialize()
+    net(mx.np.ones((3, 7)))                 # eager: shapes settle, no trace
+    assert record.spans("block.trace") == []
+    net.hybridize()
+    net(mx.np.ones((3, 7)))
+    traces = record.spans("block.trace")
+    assert [s["args"]["cls"] for s in traces] == ["Dense", "Dense", "_Deferred"]
+    assert {s["parent"] for s in traces[:2]} == {traces[2]["id"]}
+
+
+def test_block_trace_records_nothing_under_the_floor(record, monkeypatch):
+    monkeypatch.setattr(_WATCHDOG, "_TRACE_SPAN_FLOOR_S", float("inf"))
+    fused, args = _toy_fused_step()
+    fused(*args, batch_size=4)
+    assert record.spans("block.trace") == []
+    # the stack is as it was: the step's spans kept their parents
+    (step,) = record.spans("fused_step.step")
+    assert step["parent"] is None
+    assert {s["parent"] for s in record.spans("fused_step.launch")} == {step["id"]}
+    with telemetry.span("after", cat="unit") as after:
+        pass
+    assert after.parent is None
+
+
+def test_a_span_under_its_floor_is_cancelled_and_one_over_it_is_kept(record):
+    import time
+    with telemetry.span("outer", cat="unit") as outer:
+        with telemetry.span("short", cat="unit", floor_s=10.0) as short:
+            with telemetry.span("inside", cat="unit"):
+                pass
+        with telemetry.span("long", cat="unit", floor_s=0.001):
+            time.sleep(0.005)
+    names = [s["name"] for s in record.spans()]
+    assert names == ["inside", "long", "outer"]
+    assert short.end_ns >= short.begin_ns           # the reading is still there
+    (long_,) = record.spans("long")
+    assert long_["parent"] == outer.id and "floor_s" not in long_["args"]
+
+
+def test_setup_spans_outlive_the_ring(record):
+    """10,000 spans after set-up: the ring wraps twice, set-up stays readable
+    and `dropped` counts only what is gone."""
+    fused, args = _toy_fused_step()
+    for _ in range(2):
+        fused(*args, batch_size=4)
+    before = record.spans()
+    assert before.dropped == 0 and before.setup_dropped == 0
+    first_end = record.spans("fused_step.step")[0]["end_ns"]
+    setup = [s for s in before if s["begin_ns"] < first_end]
+    assert {"fused_step.build", "fused_step.prepare", "fused_step.launch",
+            "xla.compile", "block.trace"} <= {s["name"] for s in setup}
+    events_before = len(record.events())
+    capacity = record.snapshot()["capacity"]
+    for k in range(10_000):
+        with telemetry.span("window", cat="unit", k=k):
+            pass
+    after = record.spans()
+    assert after[:len(setup)] == setup              # ahead of the ring's
+    assert [s["args"]["k"] for s in after[len(setup):]] == \
+        list(range(10_000 - capacity, 10_000))
+    # every event the ring lost, less the set-up spans kept beside it
+    assert after.dropped == events_before + 10_000 - capacity - len(setup)
+    assert after.setup_dropped == 0
+    # the dump is the ring's, as it was
+    snap = record.snapshot()
+    assert snap["dropped"] == events_before + 10_000 - capacity
+    assert len(snap["events"]) == capacity
+
+
+def test_the_setup_store_is_bounded_and_says_when_it_is_full(monkeypatch):
+    from mxnet_tpu.observe import FlightRecorder, flightrec
+    monkeypatch.setattr(flightrec, "_SETUP_SPANS", 5)
+    rec = FlightRecorder(capacity=4, enabled=True)
+    for i in range(8):      # no step yet: all of it is set-up
+        rec.record_span("unit", f"s{i}", begin_ns=i, end_ns=i + 1, id=i,
+                        parent=None, step=None)
+    got = rec.spans()
+    assert [s["name"] for s in got] == ["s0", "s1", "s2", "s3", "s4",
+                                        "s5", "s6", "s7"]
+    assert got.setup_dropped == 3 and got.dropped == 0
+    for i in (8, 9):
+        rec.record_span("unit", f"s{i}", begin_ns=i, end_ns=i + 1, id=i,
+                        parent=None, step=None)
+    got = rec.spans()       # s4 is in the store; s5, the ring's alone, went
+    assert [s["name"] for s in got] == ["s0", "s1", "s2", "s3", "s4",
+                                        "s6", "s7", "s8", "s9"]
+    assert got.setup_dropped == 5 and got.dropped == 1
+    # a span that began before the first step ended is set-up's, whenever it ends
+    rec = FlightRecorder(capacity=4, enabled=True)
+    rec.record_span("step_phase", "fused_step.step", begin_ns=10, end_ns=20,
+                    id=1, parent=None, step=1)
+    rec.record_span("unit", "epoch", begin_ns=5, end_ns=90, id=2, parent=None,
+                    step=None)
+    for i in range(6):
+        rec.record_span("unit", f"w{i}", begin_ns=30 + i, end_ns=31 + i,
+                        id=10 + i, parent=None, step=None)
+    got = rec.spans()
+    assert [s["name"] for s in got] == ["fused_step.step", "epoch",
+                                        "w2", "w3", "w4", "w5"]
+    assert got.dropped == 2 and got.setup_dropped == 0
+    rec.reset(capacity=4, enabled=True)
+    assert rec.spans() == [] and rec.spans().setup_dropped == 0
