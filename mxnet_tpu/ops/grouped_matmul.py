@@ -4,10 +4,17 @@ each row belongs to, with `jax.lax.ragged_dot`'s meaning.
     grouped_matmul(rows (M, K), weights (G, K, N), load (G,) int32) -> (M, N)
 
 Rows ``sum(load[:g]) .. sum(load[:g + 1]) - 1`` are group ``g``'s and are
-multiplied by ``weights[g]``; rows past the last group belong to no group
-and stay UNWRITTEN (what lies there may be NaN: the caller's select guards
-them, `parallel/moe.py::_alive`).  Three kernels, one family
-(`ragged_gmm`, `ragged_gmm_t`, `ragged_tgmm` in a device trace):
+multiplied by ``weights[g]``; rows past the last group belong to no group.
+The contract on them, both ways: in a RESULT they stay UNWRITTEN (what lies
+there may be NaN), and in an OPERAND they may hold anything, NaN included,
+forward and backward, and reach no row of a group and no weight (a tile
+past the last group is never read; on a tile that holds a group's boundary
+the rows x W forms compute the foreign rows and store under a row mask,
+and the weights' cotangent masks BOTH operands; `lax.ragged_dot` selects
+likewise: my chip run, PR 37).  The caller makes a dead row's zero where
+it reads the rows back, `parallel/moe.py::_gather_sum`'s select.  Three
+kernels, one family (`ragged_gmm`, `ragged_gmm_t`, `ragged_tgmm` in a
+device trace):
 
 - rows x W: a grid over (column tiles of N, the row tiles PRESENT), K
   whole where it fits.  The visits are computed from `load` (which row

@@ -14,6 +14,8 @@ pipeline story is manual per-layer ctx placement,
 """
 from __future__ import annotations
 
+import math
+
 import numpy as onp
 
 from ..gluon.block import HybridBlock
@@ -81,7 +83,10 @@ class RoutedExperts(HybridBlock):
     ``num_experts`` (in f32) and picks ``top_k``; the layer computes the
     part of the result its own experts give
     (`parallel.moe.routed_experts`: sorted dispatch that drops no
-    row, a grouped matmul).  With ``experts_held == num_experts`` it is
+    row, a grouped matmul; its work follows the rows that landed on the
+    experts held, not the worst case its buffers are sized for, and a pick
+    of an absent expert is made zero by a select where the token gathers
+    its picks back).  With ``experts_held == num_experts`` it is
     the whole layer; on one chip of an ``ep`` group it runs without the
     exchange, and nothing stands in for it.
 
@@ -96,7 +101,8 @@ class RoutedExperts(HybridBlock):
     writes ``expert_load`` (held,), the rows every held expert received,
     as auxiliary state (BatchNorm's moving statistics take the same
     route): no host sync inside the step; `parallel.moe.expert_loads()`
-    reads and publishes it.
+    reads and publishes it, with the share of the step's picks that were
+    rows here (`mxtpu_moe_live_row_share`).
     """
 
     def __init__(self, units, hidden, num_experts, top_k, experts_held=None,
@@ -113,6 +119,9 @@ class RoutedExperts(HybridBlock):
         self._top_k = top_k
         self._scoring = (scoring, renormalize, float(scaling_factor))
         self.first_expert = ep_rank * held
+        # tokens x top_k of the last forward traced: a static shape, which
+        # `parallel.moe.expert_loads()` sets the counted rows against
+        self.picks = 0
         std = Normal(0.02)
         self.router = Parameter("router", shape=(units, num_experts),
                                 dtype=dtype, init=std)
@@ -139,6 +148,7 @@ class RoutedExperts(HybridBlock):
         from . import moe as _moe
 
         top_k, first = self._top_k, self.first_expert
+        self.picks = math.prod(x.shape[:-1]) * top_k
         scoring, renormalize, scale = self._scoring
 
         def f(x, router, gate, up, down, bias=None):

@@ -25,6 +25,7 @@ import numpy as onp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops import grouped_matmul as _gm
+from ..ops.pallas_kernels import _pick_block
 
 __all__ = ["moe_ffn", "init_moe_params", "moe_partition_specs",
            "shard_moe_params", "route_top_k", "routed_experts",
@@ -119,6 +120,21 @@ def moe_ffn(params, x, capacity_factor=None, router_noise=0.0, key=None):
 # the same two parts take 6.55 GB of temporaries and the cell reads 30,344
 # and 30,416 tokens/s against 23,751 and 23,853 (my chip runs, PR 33).
 PICKS_AT_ONCE = 65536
+# What XLA runs on a part's sorted rows (the gather in, SwiGLU and its
+# gradient, the cotangent rows) runs in blocks of this many rows, as many
+# blocks as the live rows fill (`_live_blocks`); fitted to small parts by
+# `_pick_block`, and a multiple of the grouped matmul's row tile
+# (`ops/grouped_matmul.py::_ROWS`).  Picked from runs of both decoder cells
+# (my chip runs, PR 37, TPU v5 lite).  One layer, value and gradient, 16,384
+# tokens, ms at 1024 / 2048 / 4096 / 8192 / 16384 / 32768 rows: 48.21 / 48.12
+# / 47.94 / 46.46 / 47.72 / 54.39 with 35,053 live rows a part in 16 uneven
+# groups (the Mellum cell's shapes; 55.89 before, over the whole buffers),
+# 20.89 / 20.99 / 21.20 / 22.12 / 23.56 / 28.16 with 2,047 live rows a part in
+# 8 groups (the Kimi cell's; 37.50 before): a turn costs some 10 to 20 us
+# beside its rows, a row rounded up 0.1 us.  The cells themselves, one seed
+# each, at 2048 against 8192: Mellum 32,554 against 32,485 tokens/s (30,180
+# before), Kimi 15,220 against 15,179 (14,168 before).
+ROWS_AT_ONCE = 2048
 
 
 def route_top_k(m, router, top_k, scoring="softmax", bias=None,
@@ -174,34 +190,80 @@ def _sorted_picks(top_e, held, first_expert):
     return order // k, order, row_of, load
 
 
-def _rows_through_experts(xs, live, load, gate, up, down):
-    """Sorted rows xs (picks, U) through their experts' SwiGLU: two grouped
+def _fresh(rows, width, dtype):
+    """A whole-part buffer of sorted rows for a loop over the live blocks to
+    fill.  Its content is undefined (`AllocateBuffer` on the TPU, no write
+    pass): the loop writes a block before anything reads it, and a dead
+    row is nobody's.  Made inside a part's body, and the body is no
+    function that jax differentiates (`_routed`'s rules loop over the parts
+    themselves): under `jax.grad` of a `lax.map` over the parts jax hoists
+    what depends on nothing out of the loop, and each part then COPIES the
+    buffer back in, 0.9 ms for 65,536 rows of 2304 (my chip run, PR 37)."""
+    return jax.lax.empty((rows, width), dtype)
+
+
+def _live_blocks(fn, live, *buffers):
+    """The sorted-row work that XLA runs: ``fn(cut, *blocks) -> blocks``
+    over row blocks of `_pick_block(picks, ROWS_AT_ONCE)` rows, block i
+    for i < ceil(live / rows of a block), a loop whose trip count comes from
+    the load.  ``buffers`` are whole-part arrays of sorted rows and keep
+    their worst-case shape; each turn hands `fn` its block of every buffer
+    and writes what `fn` returns back in its place, and ``cut(a)`` is the
+    same rows of any other whole-part array.  Blocks past the last live one
+    are neither read nor written."""
+    picks = buffers[0].shape[0]
+    rows = _pick_block(picks, ROWS_AT_ONCE)
+
+    def turn(i, buffers):
+        def cut(a):
+            return jax.lax.dynamic_slice_in_dim(a, i * rows, rows)
+        blocks = fn(cut, *map(cut, buffers))
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(b, new.astype(b.dtype),
+                                                i * rows, 0)
+            for b, new in zip(buffers, blocks))
+
+    return jax.lax.fori_loop(0, (live + rows - 1) // rows, turn, buffers)
+
+
+def _swiglu(z):
+    f = z.shape[-1] // 2
+    return jax.nn.silu(z[:, :f]) * z[:, f:]
+
+
+def _rows_through_experts(m, tok, live, load, gate, up, down):
+    """The live sorted rows through their experts' SwiGLU: two grouped
     matmuls over the rows present, gate and up side by side in the first
-    (one pass over xs forward, one cotangent of xs backward)."""
-    z = _gm.grouped_matmul(xs, jnp.concatenate([gate, up], axis=-1), load)
-    h = _alive(live, jax.nn.silu(z[:, :gate.shape[-1]]) * z[:, gate.shape[-1]:])
-    return _alive(live, _gm.grouped_matmul(h, down, load))
+    (one pass over the rows forward, one cotangent of them backward), and
+    between the kernels only the live blocks.  Returns the experts' rows
+    and, for the backward rule (a loop of a traced length has no reverse
+    rule, so it takes the pieces), gate | up's rows z and the two matmuls'
+    vjps."""
+    xs, = _live_blocks(lambda cut, _: (m[cut(tok)],), live,
+                       _fresh(tok.shape[0], m.shape[1], m.dtype))
+    z, vjp_in = jax.vjp(lambda a, w: _gm.grouped_matmul(a, w, load),
+                        xs, jnp.concatenate([gate, up], axis=-1))
+    h, = _live_blocks(lambda cut, _: (_swiglu(cut(z)),), live,
+                      _fresh(z.shape[0], gate.shape[-1], z.dtype))
+    out, vjp_out = jax.vjp(lambda a, w: _gm.grouped_matmul(a, w, load),
+                           h, down)
+    return out, z, vjp_in, vjp_out
 
 
-def _alive(live, rows):
-    # Rows past the last held pick belong to no group: the grouped matmul
-    # leaves them unwritten, forward and backward, and what lies there may be
-    # NaN.  Every tensor of sorted rows passes through this SELECT on its way
-    # in and out (never a multiply by 0), so that neither a result nor a
-    # cotangent of a row that does not exist reaches a token or a weight.
-    return jnp.where(live, rows, 0)
-
-
-def _gather_sum(rows, row_of, weights=None):
-    """sum_j weights[t, j] * rows[row_of[t, j]] in f32: (N, U); `rows` are
-    sorted rows that passed `_alive`, so a pick of an absent expert finds
-    zero.  Gathers, one pick at a time (nothing of picks x U is held), never
-    a scatter-add: on the chip a scatter-add of 32,768 rows of 2304 takes
+def _gather_sum(rows, row_of, live, weights=None):
+    """sum_j weights[t, j] * rows[row_of[t, j]] in f32: (N, U), over the
+    picks whose sorted row is live.  THIS is where a row that does not
+    exist becomes zero: a pick of an absent expert sorts past the live rows,
+    where the grouped matmuls write nothing and no loop goes, so what it
+    fetches may be NaN and is SELECTED away (never multiplied by 0).
+    Gathers, one pick at a time (nothing of picks x U is held), never a
+    scatter-add: on the chip a scatter-add of 32,768 rows of 2304 takes
     4.9 ms and its time follows the rows it is GIVEN, live or not (my chip
     run, PR 32)."""
     total = 0.0
     for j in range(row_of.shape[1]):
-        picked = rows[row_of[:, j]].astype(jnp.float32)
+        picked = jnp.where((row_of[:, j] < live)[:, None],
+                           rows[row_of[:, j]], 0).astype(jnp.float32)
         total = total + (picked if weights is None
                          else picked * weights[:, j:j + 1])
     return total
@@ -209,37 +271,63 @@ def _gather_sum(rows, row_of, weights=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _routed(m, top_e, top_w, gate, up, down, first_expert):
+    """(y, load) of every part: m, top_e, top_w are (parts, tokens of a
+    part, ·).  Both rules loop over the parts themselves, one part after
+    another; the backward rule adds the parts' weight gradients up in the
+    weights' dtype, as the transpose of a scan would."""
     return _routed_fwd(m, top_e, top_w, gate, up, down, first_expert)[0]
 
 
 def _routed_fwd(m, top_e, top_w, gate, up, down, first_expert):
-    tok, order, row_of, load = _sorted_picks(top_e, gate.shape[0],
-                                             first_expert)
-    live = (jnp.arange(tok.shape[0], dtype=jnp.int32) < jnp.sum(load))[:, None]
-    out = _rows_through_experts(_alive(live, m[tok]), live, load,
-                                gate, up, down)
-    y = _gather_sum(out, row_of, top_w)
+    def part(args):
+        m, top_e, top_w = args
+        tok, order, row_of, load = _sorted_picks(top_e, gate.shape[0],
+                                                 first_expert)
+        live = jnp.sum(load)
+        out = _rows_through_experts(m, tok, live, load, gate, up, down)[0]
+        y = _gather_sum(out, row_of, live, top_w)
+        return y.astype(m.dtype), load, tok, order, row_of
+
+    y, load, *sorting = jax.lax.map(part, (m, top_e, top_w))
     # the backward pass recomputes the experts' hidden rows from these (the
     # flash kernels' bargain): nothing the size of the sorted rows is kept
-    return (y.astype(m.dtype), load), \
-        (m, top_w, gate, up, down, tok, order, row_of, load)
+    return (y, load), (m, top_w, gate, up, down, load, *sorting)
 
 
 def _routed_bwd(first_expert, res, cts):
-    m, top_w, gate, up, down, tok, order, row_of, load = res
-    live = (jnp.arange(tok.shape[0], dtype=jnp.int32) < jnp.sum(load))[:, None]
-    out, vjp = jax.vjp(
-        lambda xs, *w: _rows_through_experts(xs, live, load, *w),
-        _alive(live, m[tok]), gate, up, down)
-    dy = _alive(live, cts[0][tok]).astype(jnp.float32)    # of each sorted row
-    # the weight of a pick multiplies its row: its gradient is found on the
-    # sorted side and each pick fetches its own
-    dw = jnp.sum(out.astype(jnp.float32) * dy, axis=-1)[row_of]
-    dout = top_w.reshape(-1)[order][:, None] * dy
-    dxs, dgate, dup, ddown = vjp(dout.astype(out.dtype))
-    dm = _gather_sum(_alive(live, dxs), row_of).astype(m.dtype)
-    return (dm, onp.zeros(row_of.shape, jax.dtypes.float0), dw,
-            dgate, dup, ddown)
+    m, top_w, gate, up, down, *sorting = res
+
+    def part(dweights, args):
+        m, top_w, dy, load, tok, order, row_of = args
+        live = jnp.sum(load)
+        out, z, vjp_in, vjp_out = _rows_through_experts(m, tok, live, load,
+                                                        gate, up, down)
+
+        def into_rows(cut, out, _):
+            d = dy[cut(tok)].astype(jnp.float32)          # of each sorted row
+            # the weight of a pick multiplies its row: its gradient is found
+            # on the sorted side and each pick fetches its own
+            return (top_w.reshape(-1)[cut(order)][:, None] * d,
+                    jnp.sum(out.astype(jnp.float32) * d, axis=-1))
+
+        # each cotangent takes the place of the rows it is the cotangent of
+        dout, dw = _live_blocks(into_rows, live, out,
+                                jnp.zeros(tok.shape, jnp.float32))
+        dh, ddown = vjp_out(dout)
+        dz, = _live_blocks(lambda cut, z: jax.vjp(_swiglu, z)[1](cut(dh)),
+                           live, z)
+        dxs, dboth = vjp_in(dz)
+        dm = _gather_sum(dxs, row_of, live).astype(m.dtype)
+        f = gate.shape[-1]
+        sums = tuple(a + b for a, b in zip(
+            dweights, (dboth[..., :f], dboth[..., f:], ddown)))
+        return sums, (dm, jnp.where(row_of < live, dw[row_of], 0))
+
+    dweights, (dm, dw) = jax.lax.scan(
+        part, tuple(jnp.zeros_like(w) for w in (gate, up, down)),
+        (m, top_w, cts[0], *sorting))
+    return (dm, onp.zeros(sorting[-1].shape, jax.dtypes.float0), dw,
+            *dweights)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -266,21 +354,52 @@ def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0):
     `PICKS_AT_ONCE`, whose buffers hold a part's worst case (every pick of
     every token on a held expert), so no row is dropped at any imbalance;
     each token gathers its picks' rows back, forward and backward, and
-    nothing scatters.  With ``held == num_experts`` this is
-    the whole layer; nothing stands in for absent chips or their exchange.
-    Differentiable in m, top_w and the weights; the backward pass
-    recomputes the hidden rows.
+    nothing scatters.
+
+    The work follows the LIVE rows, ``sum(load)`` of a part, a prefix of
+    its buffers: the kernels visit the tiles present, and what XLA runs
+    between them (the gather in, SwiGLU and its gradient, the cotangent
+    rows, the pick weights' gradient) runs in `_live_blocks`, a loop over
+    blocks of `ROWS_AT_ONCE` rows whose trip count comes from ``load``.
+    Rows past the live ones are undefined in every sorted-row tensor (a
+    kernel writes none, no loop visits a dead block, the tail of the block
+    that holds the boundary is whatever the arithmetic made of it) and may
+    be NaN.  The zero of a pick whose expert is absent is made in ONE place,
+    where a token gathers its picks back (`_gather_sum`, and the pick
+    weights' gradient beside it): a select on ``row_of < sum(load)``, never
+    a multiply, so that neither a result nor a cotangent of a row that does
+    not exist reaches a token or a weight.  The grouped matmuls take
+    operands with such rows (`ops/grouped_matmul.py`).
+
+    With ``held == num_experts`` this is the whole layer (every row live,
+    every block visited); nothing stands in for absent chips or their
+    exchange.  Differentiable in m, top_w and the weights; the backward
+    pass recomputes the hidden rows, and its rule is written out (a loop of
+    a traced length has no reverse rule).
     """
+    return _routed_in_parts(
+        m, top_e, top_w, gate, up, down, first_expert,
+        (PICKS_AT_ONCE, ROWS_AT_ONCE, _fresh, _gm.grouped_matmul,
+         _gm._context.on_tpu()))
+
+
+@functools.partial(jax.jit, static_argnames=("first_expert", "reads"))
+def _routed_in_parts(m, top_e, top_w, gate, up, down, first_expert, reads):
+    """`routed_experts`, jitted so that a model's routed layers of one shape
+    share one trace and one lowering (four in either decoder cell's step:
+    the loops over the live blocks made each 0.3 s longer to trace; my chip
+    runs, PR 37).  ``reads`` is everything the trace reads from the modules
+    that is no argument, and part of the jit's key for that alone: a test
+    that swaps one of them is traced again."""
+    del reads
     n = m.shape[0]
     parts = -(-top_e.size // PICKS_AT_ONCE)
     while n % parts:
         parts += 1
 
-    def part(args):
-        return _routed(*args, gate, up, down, first_expert)
-
-    y, load = jax.lax.map(part, tuple(
-        a.reshape(parts, n // parts, a.shape[-1]) for a in (m, top_e, top_w)))
+    y, load = _routed(*(a.reshape(parts, n // parts, a.shape[-1])
+                        for a in (m, top_e, top_w)),
+                      gate, up, down, first_expert)
     return y.reshape(m.shape), jnp.sum(load, axis=0)
 
 
@@ -289,12 +408,15 @@ _ROUTED_LAYERS = weakref.WeakSet()
 
 
 def expert_loads():
-    """[{"layer": name, "first_expert": i, "rows": [per held expert]}] of
-    every live `RoutedExperts` layer, from the counter its last step
-    wrote.  The step never waits for the host: the counters leave it as
-    auxiliary state, and THIS call is the read — it also publishes the
-    `mxtpu_moe_*` gauges and one `moe.load` flight-recorder event per
-    layer."""
+    """[{"layer": name, "first_expert": i, "rows": [per held expert],
+    "live_row_share": rows / picks}] of every live `RoutedExperts` layer,
+    from the counter its last step wrote.  The step never waits for the
+    host: the counters leave it as auxiliary state, and THIS call is the
+    read — it also publishes the `mxtpu_moe_*` gauges and one `moe.load`
+    flight-recorder event per layer.  ``live_row_share`` is the share of
+    the step's picks (tokens x top-k, the sorted rows' buffers) that
+    landed on an expert held here: how much of the buffers
+    `routed_experts`' loops over the live row blocks had to visit."""
     from .. import observe, telemetry
     layers = sorted((l for l in _ROUTED_LAYERS
                      if l.expert_load._data is not None),   # initialised
@@ -306,15 +428,22 @@ def expert_loads():
     skew_g = telemetry.gauge(
         "mxtpu_moe_load_max_over_mean", "most loaded held expert over the "
         "mean held expert, last step", labelnames=("layer",))
+    share_g = telemetry.gauge(
+        "mxtpu_moe_live_row_share", "rows on the experts held here over "
+        "the picks routed (tokens x top-k), last step",
+        labelnames=("layer",))
     out = []
     for layer, load in zip(layers, loads):
         name, load = layer.expert_load.name, [int(v) for v in load]
         total = sum(load)
+        share = total / layer.picks if layer.picks else 0.0
         rows_g.labels(layer=name).set(total)
         skew_g.labels(layer=name).set(
             max(load) * len(load) / total if total else 0.0)
+        share_g.labels(layer=name).set(share)
         observe.record("moe", "moe.load", layer=name, rows=load,
-                       first_expert=layer.first_expert)
+                       first_expert=layer.first_expert,
+                       live_row_share=share)
         out.append({"layer": name, "first_expert": layer.first_expert,
-                    "rows": load})
+                    "rows": load, "live_row_share": share})
     return out

@@ -289,15 +289,76 @@ def test_every_pick_finds_its_sorted_row_forward_and_backward(
         onp.testing.assert_allclose(g, w, rtol=1e-3, atol=2e-4)
 
 
+@pytest.mark.parametrize("dtype,u,f", [(jnp.float32, 8, 4),
+                                       (jnp.bfloat16, 128, 128)],
+                         ids=["f32-tiny", "bf16-128"])
+@pytest.mark.parametrize("live", [0, 1, 8, 9, 48],
+                         ids=["no-pick-held", "one-row", "exactly-one-block",
+                              "one-row-past-a-block", "every-pick-held"])
+def test_the_loops_over_the_live_blocks_give_the_dense_layer(
+        live, dtype, u, f, monkeypatch):
+    """The sorted-row work runs in blocks of `ROWS_AT_ONCE` rows, as many as
+    the live rows fill: 48 picks in blocks of 8, with 0, 1, 1, 2 and all 6
+    blocks live.  Result and the gradients in the activations, the pick
+    weights and every expert matrix against the dense equation in f32; with
+    no pick held the loops make no turn and everything is exactly zero."""
+    monkeypatch.setattr(moe, "ROWS_AT_ONCE", 8)
+    rng = onp.random.default_rng(13)
+    n, held, k, first = 24, 3, 2, 2
+    m = jnp.asarray(rng.normal(size=(n, u)), dtype)
+    gate, up = (jnp.asarray(rng.normal(size=(held, u, f)) * u ** -0.5, dtype)
+                for _ in range(2))
+    down = jnp.asarray(rng.normal(size=(held, f, u)) * f ** -0.5, dtype)
+    picks = rng.choice([0, 1, 5, 6, 7], size=n * k)               # absent
+    picks[rng.permutation(n * k)[:live]] = first + rng.integers(0, held, live)
+    top_e = jnp.asarray(picks.reshape(n, k), jnp.int32)
+    top_w = jnp.asarray(rng.random((n, k)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(n, u)), jnp.float32)
+    _y, load = moe.routed_experts(m, top_e, top_w, gate, up, down, first)
+    assert int(jnp.sum(load)) == live
+
+    def both(fn, *args):
+        def loss(*a):
+            y = fn(*a)
+            return jnp.sum(y.astype(jnp.float32) * ct), y
+        (_l, y), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        return [onp.asarray(v, onp.float32) for v in (y,) + grads]
+
+    got = both(lambda m, w, *e: moe.routed_experts(
+        m, top_e, w, *e, first_expert=first)[0], m, top_w, gate, up, down)
+    with jax.default_matmul_precision("highest"):
+        want = both(lambda m, w, *e: _dense_routed(m, top_e, w, *e, first),
+                    *(jnp.asarray(a, jnp.float32)
+                      for a in (m, top_w, gate, up, down)))
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    for g, w in zip(got, want):
+        if live == 0:
+            assert not g.any() and not w.any()
+        onp.testing.assert_allclose(g, w, rtol=tol,
+                                    atol=tol * max(onp.abs(w).max(), 1e-6))
+
+
+@pytest.mark.parametrize("rows_at_once", [65536, 8],
+                         ids=["boundary-block", "whole-dead-blocks"])
 @pytest.mark.parametrize("u,f", [(8, 4), (128, 128)],
                          ids=["ragged_dot", "pallas"])
-def test_rows_that_no_group_holds_reach_nothing(monkeypatch, u, f):
+def test_rows_that_no_group_holds_reach_nothing(monkeypatch, u, f,
+                                                rows_at_once):
     """On the chip the grouped matmul leaves the rows past the last group
     unwritten, forward and backward (my chip run, PR 32: the first step's
-    gradients were not finite and the step-guard held every update).  Here
-    a grouped matmul that writes NaN there stands in for it, around
-    whichever implementation the widths take."""
+    gradients were not finite and the step-guard held every update), and
+    the buffers the loops over the live blocks fill start out undefined.
+    Here a grouped matmul that writes NaN there stands in for the first,
+    around whichever implementation the widths take, and buffers that start
+    as NaN for the second: with all 48 picks in one block the dead rows are
+    the tail of the block that holds the boundary, with blocks of 8 whole
+    blocks besides, which no loop visits; NaN lies in every dead row of
+    every sorted-row tensor, operands of the grouped matmuls included."""
     real = grouped_matmul.grouped_matmul
+    monkeypatch.setattr(moe, "ROWS_AT_ONCE", rows_at_once)
+    monkeypatch.setattr(moe, "_fresh", lambda rows, width, dtype: jnp.full(
+        (rows, width), jnp.nan, dtype))
 
     def poison(rows, sizes):
         dead = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
@@ -331,7 +392,12 @@ def test_rows_that_no_group_holds_reach_nothing(monkeypatch, u, f):
         return jnp.sum(y * y)
 
     args = (m, router, gate, up, down)
-    want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+    with monkeypatch.context() as clean:
+        clean.setattr(moe, "_fresh", lambda rows, width, dtype: jnp.zeros(
+            (rows, width), dtype))
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+    held = moe.route_top_k(m, router, k)[0]
+    assert 8 < int(jnp.sum((held == 2) | (held == 3))) < 40   # dead blocks
     monkeypatch.setattr(grouped_matmul, "grouped_matmul", leaky)
     got = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
     for g, w in zip(jax.tree_util.tree_leaves(got),
@@ -410,3 +476,29 @@ def test_whole_layer_routes_every_pick():
                                           batch_size=B)
     rows = model.layer0.experts.expert_load.data().asnumpy()
     assert rows.sum() == B * T * CFG["num_experts_per_tok"]
+
+
+def test_expert_loads_publishes_the_live_share_of_the_picks():
+    """Half the tokens pick experts 0 and 2, half 0 and 1; the layer holds
+    2 and 3: a quarter of the step's picks are rows here, and
+    `expert_loads()` says so in what it returns, in the
+    `mxtpu_moe_live_row_share` gauge and on the `moe.load` event."""
+    from mxnet_tpu import observe, telemetry
+    from mxnet_tpu.parallel import RoutedExperts
+    layer = RoutedExperts(8, 4, 8, 2, experts_held=2, ep_rank=1)
+    layer.initialize()
+    router = onp.zeros((8, 8), "float32")
+    router[0, [0, 2]], router[1, [0, 1]] = (5.0, 4.0), (5.0, 4.0)
+    layer.router.set_data(mx.np.array(router))
+    x = onp.zeros((4, 10, 8), "float32")
+    x[:2, :, 0], x[2:, :, 1] = 1.0, 1.0
+    with mx.autograd.record():
+        layer(mx.np.array(x))
+    name = layer.expert_load.name
+    mine, = [l for l in moe.expert_loads() if l["layer"] == name]
+    assert mine["rows"] == [20, 0] and layer.picks == 80
+    assert mine["live_row_share"] == 0.25
+    assert telemetry.default_registry().get_sample_value(
+        "mxtpu_moe_live_row_share", {"layer": name}) == 0.25
+    assert any(e[4:6] == ("moe", "moe.load") and e[6]["layer"] == name
+               and e[6]["live_row_share"] == 0.25 for e in observe.events())

@@ -349,17 +349,31 @@ def test_the_head_and_loss_hold_no_f32_log_softmax(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
 
 
-def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip, monkeypatch):
-    """bf16 rows through the held experts at the published widths and the
-    cell's 16,384 tokens: the grouped matmuls and their gradients are this
+# held experts, F, and a limit on this layer's `temp_size_in_bytes`.  The tree
+# before PR 37, whose XLA work ran over the whole buffers, read 1,746,002,944
+# and 1,743,809,024.  The Kimi cell's shapes are held to that (1,727,043,072
+# now; that cell stands at 15.6 of 16.9 GB); the Mellum cell's read 1,798,396,416
+# standing alone and are held to that, while its whole step reads what it did
+# (5,367,403,520 against 5,367,086,080; Kimi's 7,888,997,376 against
+# 8,040,897,536: scratch compiles of the steps for the described chip, PR 37)
+@pytest.mark.parametrize("held,f,temp_limit", [(16, 896, 1800000000),
+                                               (8, 1024, 1743809024)],
+                         ids=["mellum-16x896", "kimi-8x1024"])
+def test_routed_experts_lower_to_grouped_matmul_kernels(
+        one_chip, monkeypatch, held, f, temp_limit):
+    """bf16 rows through the held experts at the two decoder cells' widths
+    and 16,384 tokens: the grouped matmuls and their gradients are this
     repository's Mosaic kernels (`ops/grouped_matmul.py`; none is left on
     `lax.ragged_dot`, no transposed copy of the weights is made) over
     buffers that hold a part's worst case; one straight-line body in a loop
-    over the parts, and nothing scatters."""
+    over the parts, and nothing scatters.  What XLA runs on the sorted rows
+    between the kernels runs in loops over the live blocks: outside a
+    kernel a whole-part tensor of sorted rows is only ever a loop's carried
+    buffer, updated in place a block at a time."""
     from mxnet_tpu.ops import grouped_matmul as gm
     from mxnet_tpu.parallel import moe
     monkeypatch.setattr(gm._context, "on_tpu", lambda: True)
-    n, k, u, f, held = 16384, 8, 2304, 896, 16
+    n, k, u = 16384, 8, 2304
     m = jax.ShapeDtypeStruct((n, u), BF16, sharding=one_chip)
     top_e = jax.ShapeDtypeStruct((n, k), jnp.int32, sharding=one_chip)
     top_w = jax.ShapeDtypeStruct((n, k), F32, sharding=one_chip)
@@ -370,17 +384,30 @@ def test_routed_experts_lower_to_grouped_matmul_kernels(one_chip, monkeypatch):
         y, _load = moe.routed_experts(m, top_e, top_w, gate, up, down, 16)
         return (y.astype(F32) ** 2).sum()
 
-    n_calls, text = _custom_calls(jax.grad(loss, argnums=(0, 2, 3, 4, 5)),
-                                  m, top_e, top_w, w_in, w_in, w_out)
-    assert n_calls == 8 and "ragged-dot" not in text
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 2, 3, 4, 5))).lower(
+        m, top_e, top_w, w_in, w_in, w_out).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 8 and "ragged-dot" not in text
     # 2 forward, 2 again + 2 + 2 backward, by the kernels' `name=`
     calls = re.findall(r"ragged_(\w+?)_*[.\d]* = \S+ custom-call\(", text)
     assert sorted(calls) == ["gmm"] * 4 + ["gmm_t"] * 2 + ["tgmm"] * 2
-    assert f"bf16[{moe.PICKS_AT_ONCE},{u}]" in text   # every pick of a part
+    picks = moe.PICKS_AT_ONCE
+    assert f"bf16[{picks},{u}]" in text               # every pick of a part
     assert f"bf16[{n * k},{u}]" not in text
     assert f"bf16[{held},{2 * f},{u}]" not in text    # no weights transposed
     assert " while(" in text and " conditional(" not in text
     assert " scatter(" not in text
+    # every instruction of every computation, fused ones included, whose
+    # result is one whole-part array of sorted rows
+    comps = census._parse_computations(text)[0]
+    whole = re.compile(rf"\w+\[{picks},({u}|{2 * f}|{f})\]")
+    made_by = [i.opcode for body in comps.values() for i in body
+               if whole.match(i.result)]
+    assert set(made_by) <= {"parameter", "get-tuple-element", "custom-call",
+                            "fusion", "dynamic-update-slice"}, set(made_by)
+    rows = moe._pick_block(picks, moe.ROWS_AT_ONCE)
+    assert f"bf16[{rows},{u}]" in text and f"bf16[{rows},{2 * f}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp_limit
 
 
 @pytest.mark.parametrize("rows,k,n", [(48, 128, 256), (1040, 256, 4608),
