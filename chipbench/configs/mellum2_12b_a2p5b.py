@@ -4,14 +4,19 @@ fourth; a softmax top-8 router over 64 experts of which this chip holds 16)
 with next-token cross-entropy under AdamW in one `FusedTrainStep`, built
 from mellum2_12b_a2p5b.json: one chip's share of an ep4 group.
 
-The weights and ring batch 0 come from the file's `weights_seed`, NOT from the
-runner's `--seed` (which draws batches 1-3): how many routed rows land on the
-held experts follows the router's weights, so a cell whose weights followed
-the seed would do another amount of work per seed (PERF_LEDGER, PR 27: refused
-for that), and the first warm-up loss is one number for every seed.
+The weights and every ring batch come from the file's `weights_seed`, NOT from
+the runner's `--seed`, and the ring's order is fixed: how many routed rows land
+on the held experts follows the router's weights, which training moves step by
+step, and the batch, and the routed layer's loops run one trip per live block
+of rows, so a cell whose weights, batches or order of batches followed the seed
+would do another amount of work per seed (PERF.md, section 6).  The seed draws
+the order of the sequences inside batches 1-3: each step then sees the same
+tokens, routes the same rows and trains the same loss, and the first warm-up
+loss is one number for every seed.
 """
 import jax
 import jax.numpy as jnp
+import numpy
 
 # Model FLOPs of one training step (2 per multiply-add, backward = 2 x
 # forward, nothing recomputed counted).  Per token 6 * N_dense, N_dense =
@@ -54,16 +59,20 @@ def make_ring(cfg, cell, chips, seed, sharding):
     """`ring` batches of `batch` sequences of `seq_len` ids, uniform over the
     vocabulary slice, made on the device: [((ids,), tokens)].  Batch 0 (the
     warm-up batch, whose first loss `correct` checks against the reference's)
-    from `weights_seed`, the others from `--seed`."""
+    from `weights_seed`, the others from `weights_seed + 1`, each with its
+    sequences in an order drawn from `--seed`."""
     from mxnet_tpu.ndarray.ndarray import NDArray
     n, shape = cell["ring"], (cell["batch"] * chips, cell["seq_len"])
 
-    def make(first, rest):
-        return [jax.random.randint(k, shape, 0, cfg["vocab_size"], jnp.int32)
+    def make(first, rest, orders):
+        made = [jax.random.randint(k, shape, 0, cfg["vocab_size"], jnp.int32)
                 for k in [first] + list(jax.random.split(rest, n - 1))]
+        return made[:1] + [ids[order] for ids, order in zip(made[1:], orders)]
 
+    rng = numpy.random.default_rng(seed)
+    orders = numpy.stack([rng.permutation(shape[0]) for _ in range(n - 1)])
     made = jax.jit(make, out_shardings=sharding)(
-        jax.random.key(cfg["weights_seed"]), jax.random.key(seed))
+        jax.random.key(cfg["weights_seed"]), jax.random.key(cfg["weights_seed"] + 1), orders)
     return [((NDArray(ids),), shape[0] * shape[1]) for ids in made]
 
 

@@ -3,8 +3,7 @@ Pallas kernels: `mxtpu_linear_attention_lowerings{path="pallas_chunk"}` over
 the counter's every path, one count a trace of `ops.linear_attention.kda`.
 Under 100 a layer fell back to the XLA form in silence, which a slow kernel
 would not show.  None where nothing was traced or the program has no counter.
-It reads in the Kimi cell alone, whose list of metrics an existing test holds
-fixed: the entry in BENCHMARK.json is a `benchmark` PR's to add (PERF.md section 7)."""
+It reads in the cells whose configuration has Kimi Delta Attention."""
 COUNTER, KERNEL_PATH = "mxtpu_linear_attention_lowerings", "pallas_chunk"
 
 

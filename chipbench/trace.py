@@ -94,10 +94,6 @@ class Trace:
                          self.busy(chip, lambda n: not is_collective(n)))
         return 100.0 * total(alone) / self.window_s
 
-    def busy_share_pct(self, keep, chip=0):
-        """Share of device-busy time in operations that `keep` picks."""
-        return 100.0 * total(self.busy(chip, keep)) / total(self.busy(chip))
-
     def span_median_ms(self, name):
         d = [e - s for n, s, e in self.spans if n == name]
         return 1e3 * statistics.median(d) if d else None

@@ -1,20 +1,21 @@
 """CPU-only checks of the benchmark harness under chipbench/: the manifest
 resolves to files, the trace arithmetic on a hand-made event list, the lag-2
-window on a fake clock, the valid-token count, and a toy-size rehearsal of a
-whole run.  Nothing here needs the chip or describes a topology."""
+window on a fake clock, the valid-token count, a toy-size rehearsal of a
+whole run, and a rehearsal of an addition to the benchmark by new files and
+appends alone.  Nothing here needs the chip or describes a topology."""
+import copy
 import json
 import os
-import re
+import shutil
 
 import pytest
 
+import manifest_checks as checks
 from chipbench import run, trace
 
 ROOT = run.ROOT
 HERE = os.path.dirname(os.path.abspath(__file__))
 MANIFEST = run.load_json(ROOT, "BENCHMARK.json")
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 TINY_BERT = dict(vocab_size=97, hidden_size=32, num_hidden_layers=2, num_attention_heads=2,
                  intermediate_size=64, max_position_embeddings=32)
 TINY_CELL = dict(batch=4, seq_len=16, valid_lengths=[8, 16], ring=2, trace_steps=4,
@@ -22,32 +23,16 @@ TINY_CELL = dict(batch=4, seq_len=16, valid_lengths=[8, 16], ring=2, trace_steps
 
 
 def test_manifest_names_units_and_arrows():
-    metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
-    cells = [w["name"] for w in MANIFEST["workloads"]]
-    names = [m["name"] for m in metrics] + cells + [c["name"] for c in MANIFEST["configs"]]
-    assert all(NAME.match(n) for n in names) and len(set(names)) == len(names)
-    assert all(UNIT.match(m["unit"]) and m["better"] in ("lower", "higher") for m in metrics)
-    assert sorted(MANIFEST["paths"]) == ["chipbench", "tests/chipbench_tests"]
-    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) <= 1
-    for cell in cells:   # every cell: setup_s, another end-to-end metric, a per-layer one
-        e2e = {m["name"] for m in run.metrics_of(MANIFEST, "end_to_end", cell)}
-        assert "setup_s" in e2e and len(e2e) >= 2
-        layer = run.metrics_of(MANIFEST, "per_layer", cell)
-        assert layer and all(m["moves"] in e2e for m in layer)
+    checks.names_units_and_arrows(MANIFEST)
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_every_per_layer_entry_lists_its_cells():
+    checks.per_layer_entries_list_their_cells(MANIFEST)
+
+
+@pytest.mark.parametrize("cell", checks.cells(MANIFEST))
 def test_cell_resolves_to_its_files(cell):
-    manifest, chips, cell_file, cfg, cfgmod = run.load_cell(cell)
-    assert cell_file["name"] == cell and chips in (1, 4)
-    assert {"batch", "ring", "loss_band", "why"} <= set(cell_file)
-    assert cfg["throughput_metric"] in {m["name"] for m in
-                                        run.metrics_of(manifest, "end_to_end", cell)}
-    assert all(hasattr(cfgmod, f) for f in ("build", "make_ring", "flops_per_step",
-                                            "FLOP_CONVENTION"))
-    for m in run.metrics_of(manifest, "per_layer", cell):
-        reader = run.load_py(os.path.join(run.HERE, "layer_metrics", m["name"] + ".py"))
-        assert callable(reader.read)
+    checks.cell_files(MANIFEST, cell)
 
 
 @pytest.fixture(scope="module")
@@ -74,8 +59,6 @@ def test_trace_busy_union_and_idle_share(fixture, made):
 def test_trace_exposed_collective_and_kernel_share(fixture, made):
     want = fixture["expect"]
     assert made.exposed_collective_pct(0) == pytest.approx(want["exposed_collective_pct"])
-    assert made.busy_share_pct(trace.is_custom_call, 0) == \
-        pytest.approx(want["custom_call_busy_share_pct"])
     assert made.span_median_ms("chipbench.dispatch") == pytest.approx(want["dispatch_median_ms"])
 
 
@@ -103,10 +86,8 @@ def test_labels_from_the_hlo_text_the_trace_gives():
 
 
 def test_layer_metric_readers_return_nothing_where_nothing_is(made):
-    def reader(name):
-        return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
+    reader = checks.reader
     plain = trace.Trace([[("fusion f32[8]", 0.0, 1.0)]], [])
-    assert reader("pallas_time_pct.img").read(plain, plain.spans, {}) is None
     assert reader("collective_exposed_pct.img").read(plain, plain.spans, {}) is None
     assert reader("dispatch_ms.img").read(plain, plain.spans, {}) is None
     assert reader("collective_exposed_pct.img").read(made, made.spans, {}) == pytest.approx(5.0)
@@ -189,8 +170,86 @@ def test_toy_rehearsal_of_a_whole_run(monkeypatch, capsys):
     out = run.measure(name, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_BERT),
                       bert, seed=2 ** 31 + 5, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
+    assert set(out["metrics"]) == checks.end_to_end_of(manifest, name)
     assert all(v["value"] > 0 for v in out["metrics"].values())
     assert out["device"]["platform"] == "cpu"
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
+
+
+NEW_CELL = "toy_lm.short"
+NEW_METRIC = "toy_idle_pct.tok"
+NEW_READER = '''"""A made-up per-layer metric: device 0's idle share of the window."""
+
+
+def read(trace, spans, cell):
+    return trace.idle_pct(0)
+'''
+
+
+def write_json(value, *parts):
+    with open(os.path.join(*parts), "w") as f:
+        json.dump(value, f, indent=1)
+
+
+def checkout_with_an_addition(root, addition):
+    """A checkout at `root` with the benchmark's files as they are and, for an
+    `addition`, one configuration, one cell and one per-layer metric added by
+    new files and appends alone; the toy BERT's builder serves as the new
+    configuration's.  For "no_workloads_list" the metric's entry has no
+    `workloads` key.  Returns the checkout's manifest."""
+    here = os.path.join(root, "chipbench")
+    shutil.copytree(run.HERE, here, ignore=shutil.ignore_patterns("__pycache__"))
+    manifest = copy.deepcopy(MANIFEST)
+    if addition != "nothing":
+        bert = run.load_json(run.HERE, "configs", "bert_base.json")
+        write_json(dict(bert, name="toy_lm", **TINY_BERT), here, "configs", "toy_lm.json")
+        shutil.copy(os.path.join(run.HERE, "configs", "bert_base.py"),
+                    os.path.join(here, "configs", "toy_lm.py"))
+        cell = run.load_json(run.HERE, "workloads", "bert_base.phase1_t128.json")
+        write_json(dict(cell, name=NEW_CELL, config="toy_lm", **TINY_CELL),
+                   here, "workloads", NEW_CELL + ".json")
+        with open(os.path.join(here, "layer_metrics", NEW_METRIC + ".py"), "w") as f:
+            f.write(NEW_READER)
+        manifest["configs"].append({
+            "name": "toy_lm", "source": "a made-up configuration of this test",
+            "file": "chipbench/configs/toy_lm.json", "reduced": [], "why": "a rehearsal"})
+        manifest["workloads"].append({"name": NEW_CELL, "config": "toy_lm", "traffic": "short",
+                                      "chips": 1, "why": "a rehearsal"})
+        for m in manifest["end_to_end"] + manifest["per_layer"]:
+            if m["name"] == "tokens_per_s" or m["name"] in checks.SETUP:
+                m["workloads"].append(NEW_CELL)
+        metric = {"name": NEW_METRIC, "unit": "%", "better": "lower", "source": "device_trace",
+                  "layer": "device (v5e)", "moves": "tokens_per_s", "workloads": [NEW_CELL]}
+        if addition == "no_workloads_list":
+            del metric["workloads"]
+        manifest["per_layer"].append(metric)
+    write_json(manifest, root, "BENCHMARK.json")
+    return manifest
+
+
+@pytest.mark.parametrize("addition", ["nothing", "cell_config_metric", "no_workloads_list"])
+def test_an_addition_takes_new_files_and_appends(tmp_path, monkeypatch, addition):
+    """Every manifest check holds of the benchmark plus a configuration, a cell
+    and a metric that came by new files and appends to lists, no file that is
+    there edited; a metric with no `workloads` list fails them."""
+    manifest = checkout_with_an_addition(str(tmp_path), addition)
+    monkeypatch.setattr(run, "ROOT", str(tmp_path))
+    monkeypatch.setattr(run, "HERE", str(tmp_path / "chipbench"))
+    if addition == "no_workloads_list":
+        with pytest.raises(AssertionError, match=f"{NEW_METRIC} has no `workloads` list"):
+            checks.every_check(manifest)
+        return
+    checks.every_check(manifest)
+    if addition == "nothing":
+        return
+    # the runner takes the new cell, with the end-to-end metrics it was appended to
+    monkeypatch.setattr(run, "PLATFORM", "cpu")
+    monkeypatch.setattr(run, "peaks_of", lambda kind: {"bf16_flops_per_s": 1e12})
+    loaded = run.load_cell(NEW_CELL)
+    out = run.measure(NEW_CELL, *loaded, seed=2 ** 31 + 13, seconds=0.3, traced=False)
+    assert out["correct"] and set(out["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
+    layer = {m["name"] for m in run.metrics_of(manifest, "per_layer", NEW_CELL)}
+    assert layer == set(checks.SETUP) | {NEW_METRIC}
+    made = trace.Trace([[("fusion f32[8]", 0.0, 1.0), ("fusion f32[8]", 3.0, 4.0)]], [])
+    assert checks.reader(NEW_METRIC).read(made, made.spans, loaded[2]) == pytest.approx(50.0)

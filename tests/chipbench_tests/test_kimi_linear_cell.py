@@ -79,16 +79,19 @@ def test_configuration_states_its_cut(loaded):
 def test_the_manifest_lists_the_cell_under_the_metrics_it_reports(loaded):
     manifest = loaded[0]
     ends = {m["name"] for m in run.metrics_of(manifest, "end_to_end", CELL)}
-    assert ends == {"tokens_per_s", "step_ms_p95", "setup_s"}
+    assert ends >= {"tokens_per_s", "step_ms_p95", "setup_s"}
     layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
-    assert layers == {
+    assert layers >= {
         "dispatch_ms.tok", "device_idle_pct.tok", "setup_compile_s", "setup_programs",
         "setup_step_programs", "setup_initialize_s", "step_mfu_pct.tok",
         "flash_roofline_pct.tok", "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok",
-        "kda_roofline_pct.tok", "kda_step_share_pct.tok"}
+        "kda_roofline_pct.tok", "kda_step_share_pct.tok", "gmm_kernel_path_pct.tok",
+        "kda_kernel_path_pct.tok", "setup_import_s", "setup_trace_s", "setup_lower_s",
+        "setup_backend_s", "setup_cache_misses", "setup_settle_s", "setup_trace_self_max_s",
+        "setup_unaccounted_s"}
     for name, better in (("kda_roofline_pct.tok", "higher"), ("kda_step_share_pct.tok", "lower")):
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s"
+        assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s"
         assert entry["source"] == "device_trace" and entry["better"] == better
         assert entry["layer"] == "ops and kernels (ops/nn.py, ops/pallas_kernels.py)"
         assert callable(reader(name).read)
@@ -142,7 +145,8 @@ def test_toy_rehearsal_of_a_whole_run(loaded, seed, monkeypatch, capsys):
     out = run.measure(CELL, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_CFG),
                       mod, seed=seed, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
+    assert set(out["metrics"]) == {m["name"] for m in
+                                   run.metrics_of(manifest, "end_to_end", CELL)}
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
     # one number for every seed: the weights and ring batch 0 are the file's

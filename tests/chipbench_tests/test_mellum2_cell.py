@@ -84,10 +84,13 @@ def test_weights_and_the_first_batch_do_not_follow_the_seed(loaded):
     rings = {seed: [x[0][0].asnumpy() for x in mod.make_ring(cfg, cell, 1, seed, None)]
              for seed in (1, 2, 2 ** 31 + 7)}
     first = rings[1]
-    for seed, ring in rings.items():  # batch 0 is the file's, batches 1-3 the seed's
+    moved = 0
+    for seed, ring in rings.items():  # every batch is the file's; the seed orders the rows of 1-3
         assert onp.array_equal(ring[0], first[0])
-        if seed != 1:
-            assert not any(onp.array_equal(x, y) for x in ring[1:] for y in first)
+        for x, y in zip(ring[1:], first[1:]):
+            assert sorted(map(tuple, x)) == sorted(map(tuple, y))
+            moved += not onp.array_equal(x, y)
+    assert moved > 0
     a = mod.make_ring(cfg, cell, 1, 1, None)
     assert [onp.array_equal(x[0][0].asnumpy(), y) for x, y in zip(a, first)] == [True] * 4   # the same seed, the same ring
     assert [w for _args, w in a] == [32] * 4
@@ -103,7 +106,8 @@ def test_toy_rehearsal_of_a_whole_run(loaded, seed, monkeypatch, capsys):
     out = run.measure(CELL, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_CFG),
                       mod, seed=seed, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
+    assert set(out["metrics"]) == {m["name"] for m in
+                                   run.metrics_of(manifest, "end_to_end", CELL)}
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
     # one number for every seed: the weights and ring batch 0 are the file's

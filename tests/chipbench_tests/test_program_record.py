@@ -2,22 +2,16 @@
 metric resolves to a reader, reads a hand-made record, returns nothing where the
 reading would be wrong, and reads the record a real (toy) run left."""
 import json
-import os
 
 import pytest
 
+import manifest_checks as checks
 from chipbench import program_record, run, trace
 
 MANIFEST = run.load_json(run.ROOT, "BENCHMARK.json")
-NEW = ("step_prepare_ms.img", "step_prepare_ms.tok", "step_launch_ms.img",
-       "step_launch_ms.tok", "setup_compile_s", "setup_programs", "setup_step_programs",
-       "setup_initialize_s")
-SETUP_LAYER = "set-up (gluon/block.py, gluon/fused_step.py, telemetry/watchdog.py)"
+NEW = checks.PROGRAM
 MS = 1_000_000   # ns
-
-
-def reader(name):
-    return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
+reader = checks.reader
 
 
 def span(name, begin_ms, end_ms, id, parent=None, step=None, **args):
@@ -64,26 +58,12 @@ def dispatches(n):
 
 @pytest.mark.parametrize("name", NEW)
 def test_every_new_metric_resolves_to_a_reader(name):
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    assert callable(reader(name).read)
-    cells = {w["name"] for w in MANIFEST["workloads"]}
-    assert set(entry["workloads"]) <= cells and entry["better"] == "lower"
-    if name.startswith("setup_"):
-        assert entry["moves"] == "setup_s" and entry["layer"] == SETUP_LAYER
-    else:
-        img = name.endswith(".img")
-        assert entry["moves"] == ("images_per_s" if img else "tokens_per_s")
-        assert all(w.startswith("resnet50_v1" if img else "bert_base")
-                   for w in entry["workloads"])
-        assert entry["layer"] == "step (gluon/fused_step.py)"
+    checks.program_entry(MANIFEST, name)
 
 
 def test_what_the_benchmark_had_is_still_there():
-    """PR 23's per-layer metrics keep their names, ahead of the new ones."""
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    old = ["dispatch_ms.img", "collective_exposed_pct.img", "pallas_time_pct.img",
-           "device_idle_pct.img", "dispatch_ms.tok", "device_idle_pct.tok"]
-    assert names[:6] == old and min(names.index(n) for n in NEW) >= 6
+    """The first per-layer metrics keep their names and order, ahead of the new ones."""
+    checks.order_kept(MANIFEST)
 
 
 def test_record_of_a_hand_made_span_list():

@@ -1,35 +1,23 @@
 """The readers of set-up's span record (`chipbench/setup_record.py`) and of the
 two kernel-path counters, on the CPU: each resolves to its entry, reads a
 hand-made record, returns nothing on a record from before these spans, and
-reads the record that a toy run left from the process's start.
-
-The Kimi cell is on none of the new entries' lists, and `kda_kernel_path_pct.tok`,
-which reads in that cell alone, has a reader and no entry yet:
-`test_kimi_linear_cell.py` holds that cell's per-layer metrics to an exact set, and
-a file the benchmark has is a `benchmark` PR's to edit (PERF.md section 7)."""
+reads the record that a toy run left from the process's start.  The set-up
+readers list every cell; each kernel-path counter lists the cell of its kernels
+(`manifest_checks.py`)."""
 import json
-import os
 import subprocess
 import sys
 
 import pytest
 
+import manifest_checks as checks
 from chipbench import run, setup_record, trace
 
 MANIFEST = run.load_json(run.ROOT, "BENCHMARK.json")
-SETUP = ("setup_import_s", "setup_trace_s", "setup_lower_s", "setup_backend_s",
-         "setup_cache_misses", "setup_settle_s", "setup_trace_self_max_s",
-         "setup_unaccounted_s")
+SETUP = checks.SETUP
 PATHS = ("kda_kernel_path_pct.tok", "gmm_kernel_path_pct.tok")
-LISTED = SETUP + PATHS[1:]
-KIMI = "kimi_linear_48b_a3b.sft_t16384_ep32share"
-SETUP_LAYER = "set-up (gluon/block.py, gluon/fused_step.py, telemetry/watchdog.py)"
-OPS_LAYER = "ops and kernels (ops/nn.py, ops/pallas_kernels.py)"
 MS = 1_000_000   # ns
-
-
-def reader(name):
-    return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
+reader = checks.reader
 
 
 def span(name, begin_ms, end_ms, id, parent=None, step=None, **args):
@@ -116,27 +104,14 @@ def observed(monkeypatch, spans, dropped=0, setup_dropped=0):
 
 @pytest.mark.parametrize("name", SETUP + PATHS)
 def test_every_entry_resolves_to_a_reader_in_its_layer(name):
-    assert callable(reader(name).read)
-    if name not in LISTED:
-        assert not [m for m in MANIFEST["per_layer"] if m["name"] == name]
-        return
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    cells = [w["name"] for w in MANIFEST["workloads"] if w["name"] != KIMI]
     if name in SETUP:
-        assert entry["layer"] == SETUP_LAYER and entry["moves"] == "setup_s"
-        assert entry["better"] == "lower" and entry["workloads"] == cells
-        assert entry["unit"] == ("programs" if name == "setup_cache_misses" else "s")
-        assert entry["source"] == ("program_counter" if name == "setup_cache_misses"
-                                   else "program_span")
+        checks.setup_entry(MANIFEST, name)
     else:
-        assert entry["layer"] == OPS_LAYER and entry["moves"] == "tokens_per_s"
-        assert entry["better"] == "higher" and entry["source"] == "program_counter"
-        assert entry["workloads"] == [c for c in cells if c.startswith("mellum2")]
+        checks.kernel_path_entry(MANIFEST, name)
 
 
 def test_the_new_entries_are_appended_and_nothing_else_moved():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[20:] == list(LISTED) and len(names) == 29
+    checks.order_kept(MANIFEST)
 
 
 @pytest.mark.parametrize("name", SETUP)
@@ -209,7 +184,7 @@ def test_kernel_path_share_reads_the_lowering_counter(monkeypatch, name, counter
 
 
 TOY_RUN = """
-import json, os
+import json, os, sys
 from chipbench import run, trace
 run.PLATFORM = "cpu"
 run.peaks_of = lambda kind: {"bf16_flops_per_s": 1e12}
@@ -225,8 +200,7 @@ from mxnet_tpu import observe
 made = trace.Trace([[("fusion f32[8]", 0.0, 1.0)]],
                    [("chipbench.dispatch", k, k + 0.5) for k in range(out["attempted"])])
 read = {}
-for m in [m["name"] for m in manifest["per_layer"][20:]] + \
-        ["kda_kernel_path_pct.tok", "setup_compile_s", "setup_programs"]:
+for m in sys.argv[1:]:
     reader = run.load_py(os.path.join(run.HERE, "layer_metrics", m + ".py"))
     read[m] = reader.read(made, made.spans, cell)
 spans = observe.spans()
@@ -245,7 +219,8 @@ print(json.dumps({"correct": out["correct"], "read": read, "setup_s":
 def toy_run():
     """A toy rehearsal of a whole untraced run in a process of its own, so the
     record runs from that process's start; then every new reader against it."""
-    done = subprocess.run([sys.executable, "-c", TOY_RUN], cwd=run.ROOT, timeout=600,
+    names = SETUP + PATHS + ("setup_compile_s", "setup_programs")
+    done = subprocess.run([sys.executable, "-c", TOY_RUN, *names], cwd=run.ROOT, timeout=600,
                           capture_output=True, text=True)
     assert done.returncode == 0, done.stderr[-3000:]
     return json.loads(done.stdout.strip().splitlines()[-1])
