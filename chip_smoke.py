@@ -568,6 +568,11 @@ def phase_resnet50_dp4(ctx):
 #   same four limits, five layers deep; the control casts every layer's q
 #   (KDA's k and v too) projection and every SwiGLU's matrices (the dense
 #   layer's, the shared and the routed experts') to fp8.
+# xing4_29b_a4b (readings on a TPU v5 lite, PERF.md section 6): Kimi's limits,
+#   both heads held to them;
+#   read: main head rms 0.0132, max 0.088; MTP head 0.0110, 0.072; gradients
+#   1.0-1.6%, cosine >= 0.99987; the control (every MLA projection and SwiGLU
+#   matrix in fp8) 0.150 and 1.04, outside both logits limits.
 LOGITS_RMS_TOL, LOGITS_MAX_TOL = 0.01, 0.05
 GRAD_REL_TOL, GRAD_COS_TOL = 0.10, 0.995
 DECODER_CELL = "mellum2_12b_a2p5b.sft_t8192_ep4share"
@@ -589,6 +594,20 @@ def _mellum_reference_params(p, cfg, _cfgmod):
             "gate": p[e + "gate"], "up": p[e + "up"], "down": p[e + "down"]})
     return {"embed": p["embed.weight"], "norm": p["norm.gamma"],
             "head": p["head.weight"].T, "layers": layers}
+
+
+def _plain_walk(model, ids, _cfgmod):
+    """((the logits,), [(layer, its FFN's normed input)]) of a `DecoderLM`
+    with one residual stream, its blocks called one after another."""
+    x, inputs = model.embed(ids), []
+    for name in model._layer_names:
+        layer = getattr(model, name)
+        x = x + layer.attend(x)
+        m = layer.ffn_norm(x)
+        inputs.append((layer, m))
+        for part in layer._ffn:
+            x = x + getattr(layer, part)(m)
+    return (model.head(model.norm(x)),), inputs
 
 
 # per configuration: its cell, the reference's module, how the system's
@@ -628,6 +647,21 @@ DECODER_REFERENCES = {
                ("layer4.attend.attention.g_b.weight", 4, "wg_b", True)],
         fp8=("wq", "wk", "wv", "gate", "up", "down", "shared_gate",
              "shared_up", "shared_down")),
+    "xing4_29b_a4b": dict(
+        cell="xing4_29b_a4b.sft_t8192_ep8share", reference="xing4",
+        top_k="num_experts_per_tok", held="n_routed_experts", block=256,
+        logits_tol=(0.04, 0.25),
+        walk=lambda model, ids, cfgmod: cfgmod.walk(model, ids),
+        params=lambda p, cfg, cfgmod: cfgmod.reference_params(p, cfg),
+        config=lambda cfg, cfgmod: cfgmod.reference_config(cfg),
+        grads=[("layer1.attend.hc.phi", 1, ("hc_attn", "phi"), False),
+               ("layer0.attend.attention.q_a.weight", 0, "wqa", True),
+               ("layer2.attend.attention.kv_a.weight", 2, "wkva", True),
+               ("layer1.experts.router", 1, "router", False),
+               ("layer1.experts.gate", 1, "gate", False),
+               ("layer3.ffn_hc.phi", 3, ("hc_ffn", "phi"), False)],
+        fp8=("wqa", "wqb", "wkva", "wkvb", "wo", "gate", "up", "down",
+             "shared_gate", "shared_up", "shared_down")),
 }
 
 
@@ -653,16 +687,15 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=None,
     loss_block, _trainer = cfgmod.build(cfg)
     model = loss_block.model
     ids = NDArray(cfgmod.make_ring(cfg, cell, 1, 0, None)[0][0][0]._data[:1])
-    held, rank, top_k = cfg["num_experts"], cfg["ep_rank"], cfg[spec["top_k"]]
+    held, rank = cfg[spec.get("held", "num_experts")], cfg["ep_rank"]
+    top_k = cfg[spec["top_k"]]
     ref_cfg = spec["config"](cfg, cfgmod)
     share = dict(experts_held=held, ep_rank=rank, block=block)
 
     # the system, layer by layer through its own blocks, for its picks
-    x, picks = model.embed(ids), []
-    for name in model._layer_names:
-        layer = getattr(model, name)
-        x = x + layer.attend(x)
-        m = layer.ffn_norm(x)
+    heads, inputs = spec.get("walk", _plain_walk)(model, ids, cfgmod)
+    picks = []
+    for layer, m in inputs:
         experts = getattr(layer, "experts", None)
         if experts is None:
             picks.append(None)
@@ -674,15 +707,15 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=None,
                 None if bias is None else bias.data()._data,
                 *experts._scoring[1:])
             picks.append(e.reshape(1, ids.shape[1], top_k))
-        for part in layer._ffn:
-            x = x + getattr(layer, part)(m)
-    got = model.head(model.norm(x))._data.astype(jnp.float32)
+    got = [h._data.astype(jnp.float32) for h in heads]
 
     # the compiled loss rounds otherwise than the eager walk and would flip
     # near-ties of its own: its routers are given the walk's picks, layer
     # by layer in the order they are traced, as the reference is
     given = [pk.reshape(-1, top_k) for pk in picks if pk is not None]
     route, traced = moe.route_top_k, []
+    for layer, _m in inputs:    # each traced for itself, not through a like layer's trace
+        layer.like = ()
 
     def route_given(*args, **kwargs):
         traced.append(None)
@@ -702,11 +735,14 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=None,
             for n, *_ in spec["grads"]}
 
     p = {k: v.data()._data.astype(jnp.float32) for k, v in params.items()}
-    del loss, loss_block, model, params, layer, experts, _trainer, x, m  # room for the reference
+    del loss, loss_block, model, params, heads, inputs, layer, experts, m, _trainer  # room
     rp = spec["params"](p, cfg, cfgmod)
     del p
-    ref_logits = jax.jit(lambda rp: ref.logits(rp, ids._data, ref_cfg,
-                                               picks=picks, **share))
+
+    def as_heads(out):      # a configuration with a prediction head gives two
+        return out if isinstance(out, tuple) else (out,)
+    ref_logits = jax.jit(lambda rp: as_heads(ref.logits(rp, ids._data, ref_cfg,
+                                                        picks=picks, **share)))
 
     def cast8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
@@ -716,34 +752,52 @@ def decoder_reference_numbers(cfg, cell, cfgmod, block=None,
         return float(-jnp.mean(jnp.take_along_axis(
             logp, ids._data[:, 1:, None], axis=-1)))
 
-    def errors(lg, want):
-        d = jnp.abs(lg - want)
-        return {"rms": float(jnp.sqrt(jnp.mean(d * d))), "max": float(d.max())}
+    def errors(lgs, wants):
+        """The main head's errors; a second head's (its positions that
+        predict a token, T - 1 of them) under `mtp_logits_err`."""
+        out = {}
+        for name, lg, want in zip(("logits_err", "mtp_logits_err"), lgs, wants):
+            d = jnp.abs(lg[:, :want.shape[1]] - want)
+            out[name] = {"rms": float(jnp.sqrt(jnp.mean(d * d))), "max": float(d.max())}
+        return out
 
     want = ref_logits(rp)
     out = {"config": config, "tokens": int(ids.shape[1]),
-           "logits_rms": float(jnp.sqrt(jnp.mean(want * want))),
-           "logits_err": errors(got, want),
-           "loss_reference_f32": xent(want), "loss_system": xent(got)}
+           "logits_rms": float(jnp.sqrt(jnp.mean(want[0] * want[0]))),
+           **errors(got, want),
+           "loss_reference_f32": xent(want[0]), "loss_system": xent(got[0])}
     # the reference left to its own picks: what the cell's `loss_band` is set from
     out["loss_reference_f32_own_picks"] = float(jax.jit(
         lambda rp: ref.loss(rp, ids._data, ref_cfg, **share))(rp))
-    out["fp8_control_logits_err"] = errors(ref_logits(dict(rp, layers=[
-        dict(lp, **{k: cast8(lp[k]) for k in spec["fp8"] if k in lp})
-        for lp in rp["layers"]])), want)
+    def fp8(lp):
+        return dict(lp, **{k: cast8(lp[k]) for k in spec["fp8"] if k in lp})
+    control = dict(rp, layers=[fp8(lp) for lp in rp["layers"]])
+    out["fp8_control_logits_err"] = errors(ref_logits(control), want)["logits_err"]
     del got, want
+    if "mtp" in rp:
+        control["mtp"] = dict(rp["mtp"], layer=fp8(rp["mtp"]["layer"]))
+    # the band's other reading: the reference in the precision below the stated one
+    out["loss_fp8_control_own_picks"] = float(jax.jit(
+        lambda rp: ref.loss(rp, ids._data, ref_cfg, **share))(control))
+    del control
 
-    def ref_loss(sub, rp):
+    def ref_loss(sub, rp):      # sub[j]: the j-th compared parameter's value
         changed = [dict(lp) for lp in rp["layers"]]
-        for (l, key), value in sub.items():
-            changed[l][key] = value
+        for j, (_n, l, key, _t) in enumerate(spec["grads"]):
+            if isinstance(key, tuple):       # a parameter inside a group
+                changed[l][key[0]] = dict(changed[l][key[0]], **{key[1]: sub[j]})
+            else:
+                changed[l][key] = sub[j]
         return ref.loss(dict(rp, layers=changed), ids._data, ref_cfg,
                         picks=picks, **share)
+
+    def leaf(lp, key):
+        return lp[key[0]][key[1]] if isinstance(key, tuple) else lp[key]
     g = jax.jit(jax.grad(ref_loss))(
-        {(l, key): rp["layers"][l][key] for _n, l, key, _t in spec["grads"]}, rp)
+        [leaf(rp["layers"][l], key) for _n, l, key, _t in spec["grads"]], rp)
     out["gradients"] = {}
-    for n, l, key, transposed in spec["grads"]:
-        a, b = mine[n], g[(l, key)].T if transposed else g[(l, key)]
+    for j, (n, l, key, transposed) in enumerate(spec["grads"]):
+        a, b = mine[n], g[j].T if transposed else g[j]
         out["gradients"][n] = {
             "rel_l2": float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
             "cosine": float(jnp.sum(a * b) / (jnp.linalg.norm(a)
@@ -760,11 +814,14 @@ def phase_decoder_reference(ctx):
     _manifest, _chips, cell, cfg, cfgmod = run.load_cell(
         DECODER_REFERENCES[config]["cell"])
     out = decoder_reference_numbers(cfg, cell, cfgmod, config=config)
-    err, ctl = out["logits_err"], out["fp8_control_logits_err"]
+    print(json.dumps({"phase": "decoder_reference", "numbers": out}), flush=True)
+    ctl = out["fp8_control_logits_err"]
     rms_tol, max_tol = DECODER_REFERENCES[config].get(
         "logits_tol", (LOGITS_RMS_TOL, LOGITS_MAX_TOL))
-    check(err["rms"] <= rms_tol and err["max"] <= max_tol,
-          f"logits against the reference given the system's picks: {out}")
+    for head in ("logits_err", "mtp_logits_err"):
+        err = out.get(head, {"rms": 0.0, "max": 0.0})
+        check(err["rms"] <= rms_tol and err["max"] <= max_tol,
+              f"{head} against the reference given the system's picks: {out}")
     check(ctl["rms"] > rms_tol or ctl["max"] > max_tol,
           f"an fp8 cast of the control's weights passes the logits' tolerance: {out}")
     for n, g in out["gradients"].items():

@@ -10,7 +10,8 @@ dp/tp/sp-sharded over a `jax.sharding.Mesh` unchanged.
 from .rnn_lm import RNNModel, rnn_lm_partition_rules
 from .decoder import (
     RMSNorm, GroupedQueryAttention, KimiDeltaAttention, LatentAttention,
-    SwiGLU, DecoderLayer, DecoderLM, CausalLMLoss,
+    SwiGLU, HyperConnection, DecoderLayer, MultiTokenPrediction, DecoderLM,
+    CausalLMLoss,
 )
 from .transformer import (
     MultiHeadAttention,
@@ -30,5 +31,6 @@ __all__ = [
     "TransformerEncoder", "BertModel", "BertForPretraining",
     "bert_partition_rules", "bert_base", "bert_large",
     "RMSNorm", "GroupedQueryAttention", "KimiDeltaAttention",
-    "LatentAttention", "SwiGLU", "DecoderLayer", "DecoderLM", "CausalLMLoss",
+    "LatentAttention", "SwiGLU", "HyperConnection", "DecoderLayer",
+    "MultiTokenPrediction", "DecoderLM", "CausalLMLoss",
 ]

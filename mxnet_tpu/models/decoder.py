@@ -16,14 +16,18 @@ published ``layer_types`` pattern.  For Kimi-Linear (arXiv:2510.26692):
 first phase a Pallas kernel pair on the TPU) behind
 short causal convolutions, `LatentAttention` (q.k heads of 192, v heads of
 128, on the flash kernel's two head sizes), `SwiGLU`, and a sigmoid router
-with a shared expert.  Trained like every other model here: `Trainer` +
-`gluon.FusedTrainStep`, one donated program a step.  The plain f32
-references they are held to are `models/reference/mellum2.py` and
-`models/reference/kimi_linear.py`.
+with a shared expert.  For Xing4.0: `LatentAttention` with a q latent and
+YaRN-rotated shared channels, mHC's residual streams (`HyperConnection`,
+arXiv:2512.24880) and a `MultiTokenPrediction` module (DeepSeek-V3).
+Trained like every other model here: `Trainer` + `gluon.FusedTrainStep`,
+one donated program a step.  The plain f32 references they are held to are
+`models/reference/mellum2.py`, `models/reference/kimi_linear.py` and
+`models/reference/xing4.py`.
 """
 from __future__ import annotations
 
 import math
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +47,8 @@ from .transformer import FLASH_AUTO_MIN_T_TRAINING, _flash_shape_ok
 
 __all__ = ["RMSNorm", "rope_inv_freq", "GroupedQueryAttention",
            "KimiDeltaAttention", "LatentAttention", "SwiGLU",
-           "DecoderLayer", "DecoderLM", "CausalLMLoss"]
+           "HyperConnection", "DecoderLayer", "MultiTokenPrediction",
+           "DecoderLM", "CausalLMLoss", "mtp_losses"]
 
 ATTENTION_KINDS = ("sliding_attention", "full_attention", "kda",
                    "latent_attention")
@@ -329,27 +334,42 @@ class KimiDeltaAttention(HybridBlock):
 
 
 class LatentAttention(HybridBlock):
-    """Multi-head latent attention (DeepSeek-V2's MLA) WITHOUT positions
-    (`mla_use_nope`), in the expanded form training uses (no weight
-    absorption): q = W_q x in heads of ``qk_nope + qk_rope``; [c | k_r] =
-    W_kva x with c the ``kv_lora_rank`` latent; [k_nope | v] = W_kvb
-    RMSNorm(c) per head; k = [k_nope | k_r], the one k_r shared by every
-    head; causal softmax attention scaled by (qk_nope + qk_rope)^-1/2; y =
-    W_o of the heads' ``v_head_dim`` outputs.  The "rope" part of q and k
-    keeps its name and width and is never rotated.
+    """Multi-head latent attention (DeepSeek-V2's MLA) in the expanded form
+    training uses (no weight absorption): q = W_q x in heads of ``qk_nope +
+    qk_rope``; [c | k_r] = W_kva x with c the ``kv_lora_rank`` latent;
+    [k_nope | v] = W_kvb RMSNorm(c) per head; k = [k_nope | k_r], the one
+    k_r shared by every head; causal softmax attention scaled by
+    ``softmax_scale`` ((qk_nope + qk_rope)^-1/2 where None); y = W_o of the
+    heads' ``v_head_dim`` outputs.
+
+    ``q_lora_rank`` puts q through a latent of its own: q = W_qb
+    RMSNorm(W_qa x).  ``rope`` (one ``rope_parameters`` entry) rotates the
+    ``qk_rope`` channels of q and the shared k_r by position (rotate-half,
+    `rope_inv_freq`'s table); without it they keep their name and width and
+    are never rotated (Kimi-Linear's `mla_use_nope`).
 
     ``use_flash`` as `GroupedQueryAttention`'s: the Pallas kernel takes the
-    two head sizes (q.k 192, v 128 in Kimi-Linear)."""
+    two head sizes (q.k 192, v 128)."""
 
     def __init__(self, units, num_heads, qk_nope_head_dim, qk_rope_head_dim,
                  v_head_dim, kv_lora_rank, epsilon=1e-5, dtype="float32",
-                 use_flash="auto"):
+                 use_flash="auto", q_lora_rank=None, rope=None,
+                 softmax_scale=None):
         super().__init__()
         self._shape = (num_heads, qk_nope_head_dim, qk_rope_head_dim,
                        v_head_dim, kv_lora_rank)
         self._use_flash = use_flash
-        self.q_proj = _dense(
-            num_heads * (qk_nope_head_dim + qk_rope_head_dim), units, dtype)
+        self._rope = None if rope is None else \
+            rope_inv_freq(qk_rope_head_dim, rope)
+        self._scale = softmax_scale or \
+            (qk_nope_head_dim + qk_rope_head_dim) ** -0.5
+        q_width = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+        if q_lora_rank is None:
+            self.q_proj = _dense(q_width, units, dtype)
+        else:
+            self.q_a = _dense(q_lora_rank, units, dtype)
+            self.q_norm = RMSNorm(q_lora_rank, epsilon, dtype)
+            self.q_b = _dense(q_width, q_lora_rank, dtype)
         self.kv_a = _dense(kv_lora_rank + qk_rope_head_dim, units, dtype)
         self.kv_norm = RMSNorm(kv_lora_rank, epsilon, dtype)
         self.kv_b = _dense(num_heads * (qk_nope_head_dim + v_head_dim),
@@ -358,15 +378,15 @@ class LatentAttention(HybridBlock):
 
     @staticmethod
     def partition_rules(axis_name="tp", prefix=".*"):
-        """Heads over ``axis_name`` (q and the up-projection by their
-        outputs, the output projection by its input); the down-projection
-        and the latent's norm, shared by every head, replicated."""
+        """Heads over ``axis_name`` (q and the up-projections by their
+        outputs, the output projection by its input); the down-projections
+        and the latents' norms, shared by every head, replicated."""
         return [
-            (prefix + r"(q_proj|kv_b)\.weight$",
+            (prefix + r"(q_proj|q_b|kv_b)\.weight$",
              PartitionSpec(axis_name, None)),
             (prefix + r"o_proj\.weight$", PartitionSpec(None, axis_name)),
-            (prefix + r"kv_a\.weight$", PartitionSpec()),
-            (prefix + r"kv_norm\.gamma$", PartitionSpec()),
+            (prefix + r"(q_a|kv_a)\.weight$", PartitionSpec()),
+            (prefix + r"(q_norm|kv_norm)\.gamma$", PartitionSpec()),
         ]
 
     def _flash_now(self, t):
@@ -375,9 +395,23 @@ class LatentAttention(HybridBlock):
     def forward(self, x):
         b, t, _ = x.shape
         h, dn, dr, dv, rank = self._shape
-        scale = (dn + dr) ** -0.5
-        q = self.q_proj(x).reshape(b, t, h, dn + dr)
+        scale = self._scale
+        q = self.q_proj(x) if hasattr(self, "q_proj") else \
+            self.q_b(self.q_norm(self.q_a(x)))
+        q = q.reshape(b, t, h, dn + dr)
         latent = self.kv_a(x)
+        if self._rope is not None:
+            inv_freq, factor = self._rope
+
+            def rotary(q, latent):
+                k_r = _rotate(latent[:, :, None, rank:], inv_freq, factor)
+                return (jnp.concatenate(
+                    [q[..., :dn], _rotate(q[..., dn:], inv_freq, factor)],
+                    axis=-1),
+                    jnp.concatenate([latent[..., :rank], k_r[:, :, 0]],
+                                    axis=-1))
+
+            q, latent = invoke(rotary, (q, latent), name="mla_rotary")
         kv = self.kv_b(self.kv_norm(latent[..., :rank])).reshape(
             b, t, h, dn + dv)
 
@@ -444,6 +478,117 @@ class _PreNormAttention(HybridBlock):
         return self.attention(self.norm(x))
 
 
+def sinkhorn(logits, iters, eps):
+    """Sinkhorn-Knopp onto the doubly stochastic matrices, f32 (..., n, n):
+    exp(logits) normalised over each row, then over each column, ``iters``
+    times each.  The first row normalisation is a softmax (the row's max
+    subtracted: the same ratios, finite for any logits); every later
+    denominator is the sum + ``eps``."""
+    m = jax.nn.softmax(logits, axis=-1)
+    m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    for _ in range(iters - 1):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def _stream_sum(x, weights):
+    """sum_j weights[..., j] * x[..., j, :] in f32: (B, T, n, U) streams
+    to one (B, T, U) row, as n broadcast multiply-adds (XLA fuses them into
+    one pass over x; an einsum would make a matmul of width n)."""
+    return sum(weights[..., j, None] * x[:, :, j].astype(jnp.float32)
+               for j in range(x.shape[2]))
+
+
+def _hc_combine(x, y, post, res):
+    """H_res X + H_post^T y: the streams after a sublayer whose output is y
+    (B, T, U), in x's dtype."""
+    n = x.shape[2]
+    mixed = jnp.stack([_stream_sum(x, res[:, :, i]) for i in range(n)],
+                      axis=2)
+    return (mixed + post[..., None] * y.astype(jnp.float32)[:, :, None]) \
+        .astype(x.dtype)
+
+
+class HyperConnection(HybridBlock):
+    """One sublayer's mixes of ``streams`` residual streams (mHC,
+    arXiv:2512.24880).  For X (B, T, n, U):
+
+        x^   = RMSNorm(vec X) with a gain of n*U
+        z    = x^ phi                              (n + n + n^2 logits)
+        H_pre  = sigmoid(alpha_0 z_pre + b_pre)          (n,)
+        H_post = 2 sigmoid(alpha_1 z_post + b_post)      (n,)
+        H_res  = `sinkhorn`(clamp(alpha_2 z_res + b_res))  (n, n)
+
+    and returns (H_pre X (B, T, U) in X's dtype, H_post, H_res) in f32;
+    the sublayer's output y goes back in by `_hc_combine`: X <- H_res X +
+    H_post^T y.  The norm's per-row scale is applied after the projection
+    (a scalar per row commutes with it) and the gain folded into phi, so
+    that no normalised copy of X is written.  At the initial alpha and b
+    the mixes are uniform: every stream gets the mean of the streams plus
+    y, a plain residual on n equal copies."""
+
+    def __init__(self, units, streams, iters=20, eps=1e-6,
+                 clamp=(-30.0, 30.0), epsilon=1e-6, dtype="float32"):
+        super().__init__()
+        width = 2 * streams + streams * streams
+        self._mix = (streams, iters, eps, tuple(float(c) for c in clamp),
+                     epsilon)
+        self.gamma = Parameter("gamma", shape=(streams * units,),
+                               dtype=dtype, init=init.One())
+        self.phi = Parameter("phi", shape=(streams * units, width),
+                             dtype=dtype, init=init.Normal(0.02))
+        self.alpha = Parameter("alpha", shape=(3,), dtype="float32",
+                               init=init.Constant(0.01))
+        self.bias = Parameter("bias", shape=(width,), dtype="float32",
+                              init=init.Zero())
+
+    @staticmethod
+    def partition_rules(axis_name="tp", prefix=".*"):
+        """Replicated: every chip mixes its own tokens' streams."""
+        return [(prefix + r"(gamma|phi|alpha|bias)$", PartitionSpec())]
+
+    def forward(self, x):
+        from .. import telemetry
+        n, iters, eps, (lo, hi), norm_eps = self._mix
+        b, t = x.shape[:2]
+
+        def mixes(x, gamma, phi, alpha, bias):
+            flat = x.reshape(b, t, -1)
+            xf = flat.astype(jnp.float32)
+            scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                  + norm_eps)
+            w = (gamma.astype(jnp.float32)[:, None]
+                 * phi.astype(jnp.float32)).astype(phi.dtype)
+            z = jnp.dot(flat, w, preferred_element_type=jnp.float32) * scale
+            pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+            post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n]
+                                        + bias[n:2 * n])
+            res = sinkhorn(jnp.clip(alpha[2] * z[..., 2 * n:] + bias[2 * n:],
+                                    lo, hi).reshape(b, t, n, n), iters, eps)
+            return _stream_sum(x, pre).astype(x.dtype), post, res
+
+        # host time only: under a trace this is the trace's, per step none
+        with telemetry.span("mhc.trace", layer=self.phi.name, tokens=b * t):
+            return invoke(mixes, (x, self.gamma.data(), self.phi.data(),
+                                  self.alpha.data(), self.bias.data()),
+                          name="mhc_mixes")
+
+
+class _MixedAttention(_PreNormAttention):
+    """The attention half on ``hc``'s streams: X -> H_res X + H_post^T
+    Attention(RMSNorm(H_pre X)), streams in and out."""
+
+    def __init__(self, units, attention, epsilon, dtype, hc):
+        super().__init__(units, attention, epsilon, dtype)
+        self.hc = hc
+
+    def forward(self, x):
+        u, post, res = self.hc(x)
+        return invoke(_hc_combine, (x, super().forward(u), post, res),
+                      name="mhc_combine")
+
+
 class DecoderLayer(HybridBlock):
     """h + Attention(RMSNorm(h)), then h + FFN(RMSNorm(h)).
 
@@ -452,19 +597,41 @@ class DecoderLayer(HybridBlock):
     The FFN is ``experts`` (a `RoutedExperts`) plus ``shared`` (a `SwiGLU`
     on every token, or None), or ``mlp`` (a dense `SwiGLU`).
 
+    ``hc_mult`` = n changes the residual path to mHC's: the layer takes and
+    gives n streams (B, T, n, U), and each half is a sublayer under a
+    `HyperConnection` of its own (``hc``: its ``iters``, ``eps`` and
+    ``clamp``): X <- H_res X + H_post^T F(H_pre X).
+
     ``remat`` recomputes the attention half in the backward pass from the
     layer's input (q, k, v, a kernel's output and log-sum-exp, the scan's
     per-chunk states are not kept); the experts keep only their input and
-    picks by themselves (`parallel.moe.routed_experts`)."""
+    picks by themselves (`parallel.moe.routed_experts`).  Under mHC it
+    recomputes the whole layer from its input streams (`run`): a layer then
+    keeps n x U a token, where its halves' mixes, FFN and output would keep
+    several times that; and a layer built as an earlier one was (``like``,
+    which `DecoderLM` sets) runs through that one's trace."""
 
     def __init__(self, units, attention, experts=None, shared=None, mlp=None,
-                 epsilon=1e-6, dtype="float32", remat=False):
+                 epsilon=1e-6, dtype="float32", remat=False, hc_mult=None,
+                 hc=None):
         super().__init__()
         if (experts is None) == (mlp is None) or (mlp and shared):
             raise ValueError("a layer's FFN is routed experts (with or "
                              "without a shared expert) or one dense mlp")
         self._remat = remat
-        self.attend = _PreNormAttention(units, attention, epsilon, dtype)
+        self._streams = hc_mult
+        # the layer whose trace this one runs through, read when it first
+        # runs: () for its own; a tuple, since a Block attribute is a child
+        self.like = ()
+        if hc_mult is None:
+            self.attend = _PreNormAttention(units, attention, epsilon, dtype)
+        else:
+            def mixes():
+                return HyperConnection(units, hc_mult, epsilon=epsilon,
+                                       dtype=dtype, **(hc or {}))
+            self.attend = _MixedAttention(units, attention, epsilon, dtype,
+                                          mixes())
+            self.ffn_hc = mixes()
         self.ffn_norm = RMSNorm(units, epsilon, dtype)
         if mlp is not None:
             self.mlp = mlp
@@ -475,13 +642,113 @@ class DecoderLayer(HybridBlock):
         self._ffn = ("mlp",) if mlp is not None else \
             ("experts", "shared") if shared is not None else ("experts",)
 
+    def run(self, x):
+        """The layer on x, recomputed whole in the backward pass where it is
+        on mHC's streams under ``remat``, through the trace of ``like``."""
+        if self._streams is None or not self._remat:
+            return self(x)
+        like = self.like[0] if self.like else self
+        y = npx.remat(self, like=like)(x)
+        if like is not self and "experts" in self._ffn:
+            # set as a layer is traced, and `parallel.moe.expert_loads` reads it
+            self.experts.picks = like.experts.picks
+        return y
+
     def forward(self, x):
-        attend = npx.remat(self.attend) if self._remat else self.attend
-        x = x + attend(x)
-        m = self.ffn_norm(x)
-        for name in self._ffn:
-            x = x + getattr(self, name)(m)
-        return x
+        if self._streams is None:
+            attend = npx.remat(self.attend) if self._remat else self.attend
+            x = x + attend(x)
+            m = self.ffn_norm(x)
+            for name in self._ffn:
+                x = x + getattr(self, name)(m)
+            return x
+        x = self.attend(x)
+        u, post, res = self.ffn_hc(x)
+        m = self.ffn_norm(u)
+        y = getattr(self, self._ffn[0])(m)
+        for name in self._ffn[1:]:
+            y = y + getattr(self, name)(m)
+        return invoke(_hc_combine, (x, y, post, res), name="mhc_combine")
+
+
+def _expand(x, n):
+    """n copies of x (B, T, U) as the streams (B, T, n, U)."""
+    return invoke(lambda x: jnp.broadcast_to(
+        x[:, :, None], x.shape[:2] + (n,) + x.shape[2:]), (x,),
+        name="mhc_expand")
+
+
+def _merge(x):
+    """The streams (B, T, n, U) summed to one row, in x's dtype."""
+    return invoke(lambda x: jnp.sum(x.astype(jnp.float32), axis=2)
+                  .astype(x.dtype), (x,), name="mhc_merge")
+
+
+class MultiTokenPrediction(HybridBlock):
+    """DeepSeek-V3's multi-token prediction module (arXiv:2412.19437 §2.2),
+    depth 1: from the main model's final state h_t (before its norm) and
+    the embedding of token t+1,
+
+        h' = W_eh [RMSNorm(h_t) ; RMSNorm(Emb(t+1))]
+
+    through one decoder ``layer`` (on ``streams`` mHC streams where given:
+    h' expanded, the layer, the streams summed), then RMSNorm; the model's
+    shared head turns that into logits for token t+2.
+
+    ``mtp_loss`` (1,) holds the last training step's cross-entropy of this
+    head as auxiliary state (`CausalLMLoss` writes it; no host sync inside
+    the step); `mtp_losses()` reads it and publishes ``mxtpu_mtp_loss``."""
+
+    def __init__(self, units, layer, streams=None, epsilon=1e-6,
+                 dtype="float32"):
+        super().__init__()
+        self._streams = streams
+        self.hnorm = RMSNorm(units, epsilon, dtype)
+        self.enorm = RMSNorm(units, epsilon, dtype)
+        self.eh_proj = _dense(units, 2 * units, dtype)
+        self.layer = layer
+        self.norm = RMSNorm(units, epsilon, dtype)
+        self.mtp_loss = Parameter("mtp_loss", shape=(1,), dtype="float32",
+                                  init=init.Zero(), differentiable=False)
+        _MTP_MODULES.add(self)
+
+    def forward(self, h, e):
+        from .. import telemetry
+        b, t = h.shape[:2]
+        # host time only: under a trace this is the trace's, per step none
+        with telemetry.span("mtp.trace", layer=self.eh_proj.weight.name,
+                            tokens=b * t):
+            x = self.eh_proj(invoke(
+                lambda a, c: jnp.concatenate([a, c], axis=-1),
+                (self.hnorm(h), self.enorm(e)), name="mtp_concat"))
+            if self._streams is None:
+                return self.norm(self.layer.run(x))
+            return self.norm(_merge(self.layer.run(_expand(x, self._streams))))
+
+
+# every live prediction module, for `mtp_losses`
+_MTP_MODULES = weakref.WeakSet()
+
+
+def mtp_losses():
+    """{module's `mtp_loss` name: the cross-entropy its head had on the last
+    training step} of every live `MultiTokenPrediction`, read from the
+    auxiliary state the step wrote; publishes the ``mxtpu_mtp_loss`` gauge
+    and one ``mtp.loss`` flight-recorder event a module."""
+    from .. import observe, telemetry
+    mods = sorted((m for m in _MTP_MODULES if m.mtp_loss._data is not None),
+                  key=lambda m: m.mtp_loss.name)
+    values = jax.device_get([m.mtp_loss.data()._data for m in mods])
+    gauge = telemetry.gauge(
+        "mxtpu_mtp_loss", "cross-entropy of the multi-token prediction "
+        "head (token t+2), last training step", labelnames=("layer",))
+    out = {}
+    for mod, value in zip(mods, values):
+        name, value = mod.mtp_loss.name, float(value[0])
+        gauge.labels(layer=name).set(value)
+        observe.record("mtp", "mtp.loss", layer=name, loss=value)
+        out[name] = value
+    return out
 
 
 class DecoderLM(HybridBlock):
@@ -501,6 +768,15 @@ class DecoderLM(HybridBlock):
     ``vocab_size`` may be one chip's share of a deployment (see
     `RoutedExperts`; a sliced vocabulary is simply a smaller one).
     ``remat`` recomputes each layer's attention half in the backward pass.
+
+    ``hc_mult`` = n (with ``hc``, `HyperConnection`'s arguments) runs every
+    layer on mHC's n residual streams: the embedding enters as n copies,
+    and the streams are summed before the final norm.  ``mtp`` (``weight``
+    λ) adds a `MultiTokenPrediction` module whose layer is of the last
+    layer's kinds, sharing the embedding and the head; the model then
+    returns (logits, the prediction head's logits for token t+2), the
+    latter at every position (the last one's "next token" is token 0 and
+    feeds no loss), and `CausalLMLoss` adds λ times their cross-entropy.
     """
 
     def __init__(self, vocab_size, units, layer_types, num_heads=None,
@@ -509,17 +785,16 @@ class DecoderLM(HybridBlock):
                  top_k=None, experts_held=None, ep_rank=0, epsilon=1e-6,
                  dtype="float32", use_flash="auto", remat=False,
                  mlp_layer_types=None, dense_hidden=None, shared_hidden=None,
-                 router=None, kda=None, mla=None):
+                 router=None, kda=None, mla=None, hc_mult=None, hc=None,
+                 mtp=None):
         super().__init__()
         self._layer_names = []
+        self._streams = hc_mult
         self.embed = nn.Embedding(vocab_size, units, dtype=dtype,
                                   weight_initializer=init.Normal(0.02))
         ffn_kinds = mlp_layer_types or ["sparse"] * len(layer_types)
-        for i, (kind, ffn) in enumerate(zip(layer_types, ffn_kinds)):
-            if kind not in ATTENTION_KINDS:
-                raise ValueError(f"layer_types[{i}] = {kind!r}")
-            if ffn not in FFN_KINDS:
-                raise ValueError(f"mlp_layer_types[{i}] = {ffn!r}")
+
+        def make_layer(kind, ffn):
             if kind == "kda":
                 attention = KimiDeltaAttention(units, epsilon=epsilon,
                                                dtype=dtype, **kda)
@@ -541,21 +816,53 @@ class DecoderLM(HybridBlock):
                         dtype=dtype, **(router or {})),
                     "shared": SwiGLU(units, shared_hidden, dtype)
                     if shared_hidden else None}
-            setattr(self, f"layer{i}", DecoderLayer(
-                units, attention, epsilon=epsilon, dtype=dtype, remat=remat,
-                **parts))
+            return DecoderLayer(units, attention, epsilon=epsilon,
+                                dtype=dtype, remat=remat, hc_mult=hc_mult,
+                                hc=hc, **parts)
+
+        for i, (kind, ffn) in enumerate(zip(layer_types, ffn_kinds)):
+            if kind not in ATTENTION_KINDS:
+                raise ValueError(f"layer_types[{i}] = {kind!r}")
+            if ffn not in FFN_KINDS:
+                raise ValueError(f"mlp_layer_types[{i}] = {ffn!r}")
+            setattr(self, f"layer{i}", make_layer(kind, ffn))
             self._layer_names.append(f"layer{i}")
         self.norm = RMSNorm(units, epsilon, dtype)
         self.head = nn.Dense(vocab_size, flatten=False, use_bias=False,
                              weight_initializer=init.Normal(0.02),
                              dtype=dtype, in_units=units)
         self.layer_kinds = [(k, f) for k, f in zip(layer_types, ffn_kinds)]
+        self.mtp_weight = None
+        layers = [getattr(self, name) for name in self._layer_names]
+        if mtp is not None:
+            self.mtp_weight = float(mtp["weight"])
+            self.mtp = MultiTokenPrediction(
+                units, make_layer(layer_types[-1], ffn_kinds[-1]), hc_mult,
+                epsilon, dtype)
+            layers.append(self.mtp.layer)
+        first = {}      # layers of one kind share the first one's trace
+        for kinds, layer in zip(self.layer_kinds + self.layer_kinds[-1:],
+                                layers):
+            if first.setdefault(kinds, layer) is not layer:
+                layer.like = (first[kinds],)
+
+    def hidden(self, ids):
+        """The final state before the norm (B, T, U), the streams summed
+        under mHC, and the embedding of ids (B, T, U)."""
+        e = self.embed(ids)
+        x = e if self._streams is None else _expand(e, self._streams)
+        for name in self._layer_names:
+            x = getattr(self, name).run(x)
+        return (x if self._streams is None else _merge(x)), e
 
     def forward(self, ids):
-        x = self.embed(ids)
-        for name in self._layer_names:
-            x = getattr(self, name)(x)
-        return self.head(self.norm(x))
+        h, e = self.hidden(ids)
+        logits = self.head(self.norm(h))
+        if self.mtp_weight is None:
+            return logits
+        after = invoke(lambda e: jnp.roll(e, -1, axis=1), (e,),
+                       name="mtp_next_embedding")
+        return logits, self.head(self.mtp(h, after))
 
 
 @jax.custom_vjp
@@ -591,12 +898,30 @@ _next_token_nll.defvjp(_next_token_nll_fwd, _next_token_nll_bwd)
 
 class CausalLMLoss(HybridBlock):
     """Mean next-token cross-entropy of a `DecoderLM`: position t
-    predicts ids[:, t+1]; the log-sum-exp is taken in f32."""
+    predicts ids[:, t+1]; the log-sum-exp is taken in f32.  A model with a
+    multi-token prediction module adds λ (its ``mtp_weight``) times that
+    head's mean cross-entropy over positions 0..T-3, position t predicting
+    ids[:, t+2], and writes that cross-entropy to the module's
+    ``mtp_loss`` in training."""
 
     def __init__(self, model):
         super().__init__()
         self.model = model
 
     def forward(self, ids):
-        return invoke(_next_token_nll, (self.model(ids)[:, :-1], ids[:, 1:]),
+        out = self.model(ids)
+        if self.model.mtp_weight is None:
+            return invoke(_next_token_nll, (out[:, :-1], ids[:, 1:]),
+                          name="next_token_nll")
+        from ..ops.aux_scope import apply_aux_update
+        from ..ops.invoke import is_training
+        logits, ahead = out
+        main = invoke(_next_token_nll, (logits[:, :-1], ids[:, 1:]),
                       name="next_token_nll")
+        extra = invoke(_next_token_nll, (ahead[:, :-2], ids[:, 2:]),
+                       name="mtp_nll")
+        if is_training():
+            apply_aux_update(self.model.mtp.mtp_loss.data(), invoke(
+                lambda v: jax.lax.stop_gradient(v).reshape(1), (extra,),
+                name="mtp_loss_state"))
+        return main + self.model.mtp_weight * extra

@@ -292,8 +292,7 @@ class NDArray:
             raise ValueError(f"invalid grad_req {grad_req!r}")
         self._node = None  # leaves are detached from any previous graph
         if stype in (None, "default"):
-            self._grad = NDArray(jnp.zeros(self.shape, self.dtype),
-                                 ctx=self._ctx)
+            self._grad = _GradBuffer(self.shape, self.dtype, self._ctx)
         elif stype == "row_sparse":
             from . import sparse as _sparse
             self._grad = _sparse.zeros("row_sparse", self.shape, self.dtype)
@@ -796,6 +795,43 @@ class NDArray:
 
 
 _iv.set_ndarray_class(NDArray)
+
+
+class _GradBuffer(NDArray):
+    """The gradient buffer `NDArray.attach_grad` gives: zeros, made when they
+    are first read.  A backward pass with ``grad_req='write'`` only rebinds
+    it, and `gluon.FusedTrainStep`, whose gradients live inside its compiled
+    step, never touches it, so until something reads it the buffer holds no
+    device memory."""
+
+    def __init__(self, shape, dtype, ctx):
+        self._zeros = (tuple(shape), dtype)
+        self._buf = None
+        self._ctx = ctx
+        self._grad = None
+        self._grad_req = "null"
+        self._node = None
+        self._node_idx = 0
+        self._version = 0
+
+    @property
+    def _data(self):
+        if self._buf is None:
+            self._buf = jnp.zeros(*self._zeros)
+        return self._buf
+
+    @_data.setter
+    def _data(self, value):
+        self._buf = value
+
+    @property
+    def shape(self):
+        return self._zeros[0] if self._buf is None else tuple(self._buf.shape)
+
+    @property
+    def dtype(self):
+        return onp.dtype(self._zeros[1] if self._buf is None else
+                         self._buf.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -95,7 +95,8 @@ class RoutedExperts(HybridBlock):
     ``correction_bias`` (a (num_experts,) buffer that takes no gradient
     and is no optimizer's to move: zeros unless loaded), weighs by the
     unbiased score, divides by the picks' sum where ``renormalize`` and
-    multiplies by ``scaling_factor``.
+    multiplies by ``scaling_factor``.  ``picks_at_once`` is the size of the
+    parts the tokens' picks go through in (`parallel.moe.routed_experts`).
 
     Forward: ``x (B, T, U) -> y (B, T, U)``.  Each training step also
     writes ``expert_load`` (held,), the rows every held expert received,
@@ -107,7 +108,7 @@ class RoutedExperts(HybridBlock):
 
     def __init__(self, units, hidden, num_experts, top_k, experts_held=None,
                  ep_rank=0, dtype="float32", scoring="softmax",
-                 renormalize=True, scaling_factor=1.0):
+                 renormalize=True, scaling_factor=1.0, picks_at_once=None):
         super().__init__()
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}")
@@ -118,6 +119,7 @@ class RoutedExperts(HybridBlock):
                 f"not among {num_experts}")
         self._top_k = top_k
         self._scoring = (scoring, renormalize, float(scaling_factor))
+        self._picks_at_once = picks_at_once
         self.first_expert = ep_rank * held
         # tokens x top_k of the last forward traced: a static shape, which
         # `parallel.moe.expert_loads()` sets the counted rows against
@@ -147,7 +149,8 @@ class RoutedExperts(HybridBlock):
         from ..ops.invoke import is_training
         from . import moe as _moe
 
-        top_k, first = self._top_k, self.first_expert
+        top_k, first, at_once = self._top_k, self.first_expert, \
+            self._picks_at_once
         self.picks = math.prod(x.shape[:-1]) * top_k
         scoring, renormalize, scale = self._scoring
 
@@ -156,7 +159,7 @@ class RoutedExperts(HybridBlock):
             top_e, top_w = _moe.route_top_k(m, router, top_k, scoring, bias,
                                             renormalize, scale)
             y, load = _moe.routed_experts(m, top_e, top_w, gate, up, down,
-                                          first)
+                                          first, at_once)
             return y.reshape(x.shape), load
 
         args = (x, self.router.data(), self.gate.data(), self.up.data(),

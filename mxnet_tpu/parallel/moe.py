@@ -119,6 +119,8 @@ def moe_ffn(params, x, capacity_factor=None, router_noise=0.0, key=None):
 # matmul was `lax.ragged_dot` then).  With `ops/grouped_matmul.py`'s kernels
 # the same two parts take 6.55 GB of temporaries and the cell reads 30,344
 # and 30,416 tokens/s against 23,751 and 23,853 (my chip runs, PR 33).
+# A layer may take parts of its own size (`routed_experts(picks_at_once=)`),
+# where a model's other state leaves no room for these buffers.
 PICKS_AT_ONCE = 65536
 # What XLA runs on a part's sorted rows (the gather in, SwiGLU and its
 # gradient, the cotangent rows) runs in blocks of this many rows, as many
@@ -333,7 +335,8 @@ def _routed_bwd(first_expert, res, cts):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0):
+def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0,
+                   picks_at_once=None):
     """The part of a routed SwiGLU layer that the experts held here give.
 
     m (N, U) activations; top_e/top_w (N, k) from `route_top_k`; gate, up
@@ -350,9 +353,10 @@ def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0):
     kernels that visit only the row tiles present (`ragged_gmm`,
     `ragged_gmm_t`, `ragged_tgmm` custom calls in the trace), or
     `lax.ragged_dot` where a width is no multiple of 128 lanes.  One
-    straight-line path, run over the tokens in parts of
-    `PICKS_AT_ONCE`, whose buffers hold a part's worst case (every pick of
-    every token on a held expert), so no row is dropped at any imbalance;
+    straight-line path, run over the tokens in parts of ``picks_at_once``
+    picks (`PICKS_AT_ONCE` where None), whose buffers hold a part's worst
+    case (every pick of every token on a held expert), so no row is dropped
+    at any imbalance;
     each token gathers its picks' rows back, forward and backward, and
     nothing scatters.
 
@@ -379,8 +383,8 @@ def routed_experts(m, top_e, top_w, gate, up, down, first_expert=0):
     """
     return _routed_in_parts(
         m, top_e, top_w, gate, up, down, first_expert,
-        (PICKS_AT_ONCE, ROWS_AT_ONCE, _fresh, _gm.grouped_matmul,
-         _gm._context.on_tpu()))
+        (picks_at_once or PICKS_AT_ONCE, ROWS_AT_ONCE, _fresh,
+         _gm.grouped_matmul, _gm._context.on_tpu()))
 
 
 @functools.partial(jax.jit, static_argnames=("first_expert", "reads"))
@@ -390,10 +394,10 @@ def _routed_in_parts(m, top_e, top_w, gate, up, down, first_expert, reads):
     the loops over the live blocks made each 0.3 s longer to trace; my chip
     runs, PR 37).  ``reads`` is everything the trace reads from the modules
     that is no argument, and part of the jit's key for that alone: a test
-    that swaps one of them is traced again."""
-    del reads
+    that swaps one of them is traced again; its first is the part's
+    picks."""
     n = m.shape[0]
-    parts = -(-top_e.size // PICKS_AT_ONCE)
+    parts = -(-top_e.size // reads[0])
     while n % parts:
         parts += 1
 

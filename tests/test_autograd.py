@@ -192,3 +192,31 @@ def test_nondiff_passthrough():
         y = (x * 2).sum()
     y.backward()
     assert_almost_equal(x.grad, onp.full(3, 2.0))
+
+
+def test_a_gradient_buffer_holds_no_array_until_it_is_read():
+    """A buffer that a backward pass writes, or that nothing touches (a
+    compiled step keeps its gradients inside), never makes its zeros; one
+    that is read first gives zeros, and ``grad_req='add'`` adds to them."""
+    import jax.numpy as jnp
+    a, b, c = (mx.nd.array(onp.ones((3, 4))) for _ in range(3))
+    for x in (a, b, c):
+        x.attach_grad()
+    assert all(x.grad._buf is None for x in (a, b, c))
+    assert c.grad.shape == (3, 4) and c.grad.dtype == onp.float32
+    assert c.grad._buf is None                     # shape and dtype read none
+    with mx.autograd.record():
+        y = (a * 3.0).sum()
+    y.backward()
+    onp.testing.assert_allclose(a.grad.asnumpy(), 3.0)
+    assert b.grad._buf is None
+    b.attach_grad(grad_req="add")
+    for _ in range(2):
+        with mx.autograd.record():
+            z = (b * 2.0).sum()
+        z.backward()
+    onp.testing.assert_allclose(b.grad.asnumpy(), 4.0)
+    assert not c.grad.asnumpy().any() and c.grad._buf is not None
+    h = mx.nd.array(onp.ones(5), dtype=jnp.bfloat16)
+    h.attach_grad()
+    assert h.grad.dtype == jnp.bfloat16 and h.grad.asnumpy().dtype == jnp.bfloat16

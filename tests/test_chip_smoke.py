@@ -83,3 +83,31 @@ def test_decoder_reference_phase_takes_the_kimi_linear_configuration():
     assert sorted(out["gradients"]) == sorted(n for n, *_ in spec["grads"])
     for name, g in out["gradients"].items():
         assert g["rel_l2"] < 3e-3 and g["cosine"] > 0.9999, (name, g)
+
+
+def test_decoder_reference_phase_takes_the_xing4_configuration():
+    """`--decoder-reference xing4_29b_a4b` at toy widths in f32: the system's
+    mHC streams, q-latent rotary MLA, routed layers and MTP head against the
+    plain reference given the system's picks, both heads' logits and the
+    gradients of a mix's phi, q_a, kv_a, a router and the held experts' gate;
+    the fp8 control stands far outside that agreement."""
+    import chip_smoke
+    from chipbench import run
+
+    spec = chip_smoke.DECODER_REFERENCES["xing4_29b_a4b"]
+    _manifest, _chips, cell, cfg, cfgmod = run.load_cell(spec["cell"])
+    cfg.update(hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
+               kv_lora_rank=12, q_lora_rank=20, qk_nope_head_dim=16, qk_rope_head_dim=8,
+               v_head_dim=16, num_attention_heads=2, n_routed_experts=2, router_experts=8,
+               ep_rank=1, num_experts_per_tok=2, vocab_size=64, dtype="float32")
+    cell.update(batch=2, seq_len=32)
+    out = chip_smoke.decoder_reference_numbers(cfg, cell, cfgmod, block=8,
+                                               config="xing4_29b_a4b")
+    assert out["tokens"] == 32 and out["logits_rms"] > 0
+    assert out["logits_err"]["max"] < 1e-4 and out["mtp_logits_err"]["max"] < 1e-4
+    assert out["fp8_control_logits_err"]["rms"] > 30 * out["logits_err"]["rms"]
+    assert abs(out["loss_system"] - out["loss_reference_f32"]) < 1e-4
+    assert out["loss_reference_f32_own_picks"] > out["loss_reference_f32"]   # + 0.1 CE_mtp
+    assert sorted(out["gradients"]) == sorted(n for n, *_ in spec["grads"])
+    for name, g in out["gradients"].items():
+        assert g["rel_l2"] < 3e-3 and g["cosine"] > 0.9999, (name, g)
