@@ -26,6 +26,7 @@ one donated program a step.  The plain f32 references they are held to are
 """
 from __future__ import annotations
 
+import functools
 import math
 import weakref
 
@@ -39,6 +40,7 @@ from ..context import on_tpu
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
+from ..ops import hyper_connection as _hc
 from ..ops import linear_attention as _la
 from ..ops.invoke import invoke
 from ..parallel.layers import RoutedExperts
@@ -494,15 +496,21 @@ def sinkhorn(logits, iters, eps):
 
 def _stream_sum(x, weights):
     """sum_j weights[..., j] * x[..., j, :] in f32: (B, T, n, U) streams
-    to one (B, T, U) row, as n broadcast multiply-adds (XLA fuses them into
-    one pass over x; an einsum would make a matmul of width n)."""
+    to one (B, T, U) row, as n broadcast multiply-adds (an einsum would make
+    a matmul of width n).  XLA does not fuse them into one pass over x: it
+    writes each stream's slice as a fusion of its own and keeps f32 stream
+    tensors (PERF.md section 5), which is why the TPU takes the kernels of
+    `ops/hyper_connection.py` where the shapes allow."""
     return sum(weights[..., j, None] * x[:, :, j].astype(jnp.float32)
                for j in range(x.shape[2]))
 
 
 def _hc_combine(x, y, post, res):
     """H_res X + H_post^T y: the streams after a sublayer whose output is y
-    (B, T, U), in x's dtype."""
+    (B, T, U), in x's dtype; `mhc_post_fwd` and `mhc_post_bwd` where
+    `ops.hyper_connection.takes_kernels`."""
+    if _hc.takes_kernels(x.shape[-1]):
+        return _hc.combine(x, y, post, res)
     n = x.shape[2]
     mixed = jnp.stack([_stream_sum(x, res[:, :, i]) for i in range(n)],
                       axis=2)
@@ -522,11 +530,30 @@ class HyperConnection(HybridBlock):
 
     and returns (H_pre X (B, T, U) in X's dtype, H_post, H_res) in f32;
     the sublayer's output y goes back in by `_hc_combine`: X <- H_res X +
-    H_post^T y.  The norm's per-row scale is applied after the projection
-    (a scalar per row commutes with it) and the gain folded into phi, so
-    that no normalised copy of X is written.  At the initial alpha and b
-    the mixes are uniform: every stream gets the mean of the streams plus
-    y, a plain residual on n equal copies."""
+    H_post^T y (`around` does both about a sublayer).  The norm's per-row
+    scale is applied after the projection (a scalar per row commutes with
+    it) and the gain folded into phi, so that no normalised copy of X is
+    written.  At the initial alpha and b the mixes are uniform: every stream
+    gets the mean of the streams plus y, a plain residual on n equal copies.
+
+    Two implementations of one mathematics, chosen from platform and shapes
+    alone (`ops.hyper_connection.takes_kernels`: on TPU, U a multiple of 128
+    lanes; ``mxtpu_hyperconnection_lowerings{path}`` counts each trace of the
+    mixes under the one it took):
+
+    - "xla": the norm, the projection, H_pre X and the combine as XLA ops,
+      differentiated by autodiff.  Every platform but TPU, every other
+      shape, and the tests' reference.  XLA writes each stream's slice as a
+      fusion of its own and keeps f32 copies of the streams (PERF.md section
+      5).
+    - "pallas": the pre-mix (norm, projection, H_pre X) and the combine are
+      the Mosaic kernel pairs `mhc_pre_fwd` / `mhc_pre_bwd` and
+      `mhc_post_fwd` / `mhc_post_bwd`, each reading a tile of tokens' n
+      streams once into VMEM and writing the streams' dtype back; under
+      `around` the combine's backward writes no dX of its own, the pre-mix's
+      backward adds H_res^T dX' in the pass that writes dX.  H_post and H_res
+      (the sigmoid, the clamp, Sinkhorn) stay XLA ops on the logits in both.
+    """
 
     def __init__(self, units, streams, iters=20, eps=1e-6,
                  clamp=(-30.0, 30.0), epsilon=1e-6, dtype="float32"):
@@ -549,30 +576,58 @@ class HyperConnection(HybridBlock):
         return [(prefix + r"(gamma|phi|alpha|bias)$", PartitionSpec())]
 
     def forward(self, x):
+        return self._mixes(x)[:3]
+
+    def around(self, x, sublayer):
+        """X <- H_res X + H_post^T sublayer(H_pre X), streams in and out;
+        ``sublayer`` maps NDArrays (B, T, U) to (B, T, U)."""
+        u, post, res, streams = self._mixes(x)
+        y = sublayer(u)
+        if _hc.takes_kernels(x.shape[-1]):
+            return invoke(functools.partial(_hc.combine, folded=True),
+                          (streams, y, post, res), name="mhc_combine")
+        return invoke(_hc_combine, (x, y, post, res), name="mhc_combine")
+
+    def _mixes(self, x):
+        """(H_pre X, H_post, H_res, and on the kernel path the streams for
+        `ops.hyper_connection.combine`'s fold, None on the XLA path)."""
         from .. import telemetry
         n, iters, eps, (lo, hi), norm_eps = self._mix
         b, t = x.shape[:2]
+        kernels = _hc.takes_kernels(x.shape[-1])
+        telemetry.counter(
+            "mxtpu_hyperconnection_lowerings", "mHC stream mixes traced, by "
+            "the implementation taken", labelnames=("path",)
+        ).labels(path="pallas" if kernels else "xla").inc()
+
+        def post_and_res(z, alpha, bias):
+            post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n]
+                                        + bias[n:2 * n])
+            res = sinkhorn(jnp.clip(alpha[2] * z[..., 2 * n:] + bias[2 * n:],
+                                    lo, hi).reshape(z.shape[:-1] + (n, n)),
+                           iters, eps)
+            return post, res
 
         def mixes(x, gamma, phi, alpha, bias):
+            w = (gamma.astype(jnp.float32)[:, None]
+                 * phi.astype(jnp.float32)).astype(phi.dtype)
+            if kernels:
+                return _hc.mixes(x, w, alpha, bias, post_and_res, norm_eps)
             flat = x.reshape(b, t, -1)
             xf = flat.astype(jnp.float32)
             scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                                   + norm_eps)
-            w = (gamma.astype(jnp.float32)[:, None]
-                 * phi.astype(jnp.float32)).astype(phi.dtype)
             z = jnp.dot(flat, w, preferred_element_type=jnp.float32) * scale
             pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
-            post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n]
-                                        + bias[n:2 * n])
-            res = sinkhorn(jnp.clip(alpha[2] * z[..., 2 * n:] + bias[2 * n:],
-                                    lo, hi).reshape(b, t, n, n), iters, eps)
-            return _stream_sum(x, pre).astype(x.dtype), post, res
+            return (_stream_sum(x, pre).astype(x.dtype),
+                    *post_and_res(z, alpha, bias))
 
         # host time only: under a trace this is the trace's, per step none
         with telemetry.span("mhc.trace", layer=self.phi.name, tokens=b * t):
-            return invoke(mixes, (x, self.gamma.data(), self.phi.data(),
-                                  self.alpha.data(), self.bias.data()),
-                          name="mhc_mixes")
+            out = invoke(mixes, (x, self.gamma.data(), self.phi.data(),
+                                 self.alpha.data(), self.bias.data()),
+                         name="mhc_mixes")
+        return tuple(out) if kernels else (*out, None)
 
 
 class _MixedAttention(_PreNormAttention):
@@ -584,9 +639,7 @@ class _MixedAttention(_PreNormAttention):
         self.hc = hc
 
     def forward(self, x):
-        u, post, res = self.hc(x)
-        return invoke(_hc_combine, (x, super().forward(u), post, res),
-                      name="mhc_combine")
+        return self.hc.around(x, super().forward)
 
 
 class DecoderLayer(HybridBlock):
@@ -644,15 +697,19 @@ class DecoderLayer(HybridBlock):
 
     def run(self, x):
         """The layer on x, recomputed whole in the backward pass where it is
-        on mHC's streams under ``remat``, through the trace of ``like``."""
+        on mHC's streams under ``remat``, through the trace of ``like``.  The
+        streams cross that boundary flat, (B, T, n U): on the TPU a
+        (B, T, n, U) tensor is laid out in tiles of n x 128 and the kernels'
+        flat rows in tiles of 8 tokens, so every crossing in the 4-D form
+        was a relayout of the streams each way."""
         if self._streams is None or not self._remat:
             return self(x)
         like = self.like[0] if self.like else self
-        y = npx.remat(self, like=like)(x)
+        y = npx.remat(self, like=like)(x.reshape(x.shape[:2] + (-1,)))
         if like is not self and "experts" in self._ffn:
             # set as a layer is traced, and `parallel.moe.expert_loads` reads it
             self.experts.picks = like.experts.picks
-        return y
+        return y.reshape(x.shape)
 
     def forward(self, x):
         if self._streams is None:
@@ -662,26 +719,36 @@ class DecoderLayer(HybridBlock):
             for name in self._ffn:
                 x = x + getattr(self, name)(m)
             return x
-        x = self.attend(x)
-        u, post, res = self.ffn_hc(x)
-        m = self.ffn_norm(u)
-        y = getattr(self, self._ffn[0])(m)
-        for name in self._ffn[1:]:
-            y = y + getattr(self, name)(m)
-        return invoke(_hc_combine, (x, y, post, res), name="mhc_combine")
+
+        def ffn(u):
+            m = self.ffn_norm(u)
+            y = getattr(self, self._ffn[0])(m)
+            for name in self._ffn[1:]:
+                y = y + getattr(self, name)(m)
+            return y
+
+        shape = x.shape     # (B, T, n U) from `run`, else (B, T, n, U)
+        x = x.reshape(shape[:2] + (self._streams, -1))
+        return self.ffn_hc.around(self.attend(x), ffn).reshape(shape)
 
 
 def _expand(x, n):
-    """n copies of x (B, T, U) as the streams (B, T, n, U)."""
-    return invoke(lambda x: jnp.broadcast_to(
-        x[:, :, None], x.shape[:2] + (n,) + x.shape[2:]), (x,),
-        name="mhc_expand")
+    """n copies of x (B, T, U) as the streams (B, T, n, U), written as the
+    flat row (B, T, n U) that `DecoderLayer.run` and the kernels read."""
+    return invoke(lambda x: jnp.concatenate([x] * n, axis=-1).reshape(
+        x.shape[:2] + (n,) + x.shape[2:]), (x,), name="mhc_expand")
 
 
 def _merge(x):
-    """The streams (B, T, n, U) summed to one row, in x's dtype."""
-    return invoke(lambda x: jnp.sum(x.astype(jnp.float32), axis=2)
-                  .astype(x.dtype), (x,), name="mhc_merge")
+    """The streams (B, T, n, U) summed to one row, in x's dtype, read as the
+    flat row (B, T, n U)."""
+    def merge(x):
+        b, t, n, units = x.shape
+        flat = x.reshape(b, t, n * units).astype(jnp.float32)
+        return sum(flat[..., j * units:(j + 1) * units]
+                   for j in range(n)).astype(x.dtype)
+
+    return invoke(merge, (x,), name="mhc_merge")
 
 
 class MultiTokenPrediction(HybridBlock):

@@ -349,6 +349,51 @@ def test_the_head_and_loss_hold_no_f32_log_softmax(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
 
 
+def test_mhc_passes_compile_at_the_xing_cells_shapes(one_chip, monkeypatch):
+    """One mHC sublayer of the Xing cell, 8,192 tokens on four streams of
+    3,584 in bf16, value and gradient, the streams flat as `DecoderLayer.run`
+    hands them over: the pre-mix and the combine are `mhc_pre_fwd` /
+    `mhc_pre_bwd` and `mhc_post_fwd` / `mhc_post_bwd`, once each; XLA writes
+    no f32 stream-sized tensor and moves none between layouts (no copy, no
+    transpose, no physical reshape), and the combine's backward writes no dX
+    of its own (the fold): 430 MB of temporaries, about the kernels' own
+    outputs."""
+    from mxnet_tpu.models import decoder
+    from mxnet_tpu.ops import hyper_connection as hc
+    monkeypatch.setattr(hc._context, "on_tpu", lambda: True)
+    t, n, u = 8192, 4, 3584
+
+    def post_and_res(z, alpha, bias):
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n] + bias[n:2 * n])
+        res = decoder.sinkhorn(jnp.clip(alpha[2] * z[..., 2 * n:] + bias[2 * n:], -30.0,
+                                        30.0).reshape(z.shape[:-1] + (n, n)), 20, 1e-6)
+        return post, res
+
+    def sublayer(flat, w, alpha, bias):
+        x = flat.reshape(1, t, n, u)
+        h, post, res, streams = hc.mixes(x, w, alpha, bias, post_and_res, 1e-6)
+        out = hc.combine(streams, h * 2, post, res, folded=True)
+        return (out.reshape(flat.shape).astype(F32) ** 2).sum()
+
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip) for shape, dt in (
+        ((1, t, n * u), BF16), ((n * u, 2 * n + n * n), BF16), ((3,), F32),
+        ((2 * n + n * n,), F32))]
+    compiled = jax.jit(jax.grad(sublayer, argnums=(0, 1, 2, 3))).lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(mhc_\w+?)[.\d]* = \S.* custom-call\(", text)
+    assert sorted(calls) == ["mhc_post_bwd", "mhc_post_fwd", "mhc_pre_bwd", "mhc_pre_fwd"]
+    comps, entry, _, _ = census._parse_computations(text)
+    big = [(m.group(1), i.opcode) for i in comps[entry]
+           for m in [re.match(r"(\w+)\[([\d,]+)\]", i.result)]
+           if m and math.prod(map(int, m.group(2).split(","))) == t * n * u]
+    assert not [op for _dt, op in big if op in ("copy", "transpose", "reshape")], big
+    assert not [op for dt, op in big if dt == "f32"], big
+    # the streams' gradient is written once, by `mhc_pre_bwd`; the loss's own
+    # cotangent of X' is the one other stream-sized value XLA writes
+    assert sum(op == "fusion" for _dt, op in big) <= 1, big
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e8
+
+
 # held experts, F, and a limit on this layer's `temp_size_in_bytes`.  The tree
 # before PR 37, whose XLA work ran over the whole buffers, read 1,746,002,944
 # and 1,743,809,024.  The Kimi cell's shapes are held to that (1,727,043,072
