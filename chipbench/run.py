@@ -103,6 +103,18 @@ def is_correct(compiles_in_window, failed, warmup_losses, band):
             and lo <= warmup_losses[0] <= hi and warmup_losses[-1] < warmup_losses[0])
 
 
+def compared(compiles_in_window, failed, warmup_losses, band):
+    """Each number that `is_correct` compares, beside its limit, in that order."""
+    lo, hi = band["first"]
+    return {"compiles_in_window": {"value": compiles_in_window, "limit": "== 0"},
+            "failed_steps": {"value": failed, "limit": "== 0"},
+            "nonfinite_warmup_losses": {"value": sum(not math.isfinite(v) for v in warmup_losses),
+                                        "limit": "== 0"},
+            "first_warmup_loss": {"value": warmup_losses[0], "limit": [lo, hi]},
+            "warmup_loss_change": {"value": warmup_losses[-1] - warmup_losses[0],
+                                   "limit": "< 0"}}
+
+
 def program_facts(compiled):
     """What the timed step's program holds, as `chip_smoke.compiled_step` reads it."""
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -245,6 +257,7 @@ def measure(name, manifest, chips, cell, cfg, cfgmod, seed, seconds, traced):
                     "setup_s": begin - _T0}
         for m in metrics_of(manifest, "end_to_end", name):
             result["metrics"][m["name"]] = {"value": measured[m["name"]], "unit": m["unit"]}
+    result["compared"] = compared(in_window, failed, warm, cell["loss_band"])   # the last key
     return result
 
 
@@ -259,6 +272,8 @@ def main(argv=None):
     seconds = args.seconds if args.seconds is not None else loaded[0]["run_seconds"]
     result = measure(args.workload, *loaded, args.seed, seconds, bool(args.trace))
     print(json.dumps(result), flush=True)
+    for what, c in result["compared"].items():   # the last lines of standard error
+        print(f"compared {what} {c['value']!r} limit {c['limit']}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -21,7 +21,8 @@ SETUP = ("setup_import_s", "setup_trace_s", "setup_lower_s", "setup_backend_s",
          "setup_unaccounted_s")
 # kernel-path counters, each with a cell that must report it
 KERNEL_PATHS = {"gmm_kernel_path_pct.tok": "mellum2_12b_a2p5b.sft_t8192_ep4share",
-                "kda_kernel_path_pct.tok": "kimi_linear_48b_a3b.sft_t16384_ep32share"}
+                "kda_kernel_path_pct.tok": "kimi_linear_48b_a3b.sft_t16384_ep32share",
+                "mhc_kernel_path_pct.tok": "xing4_29b_a4b.sft_t8192_ep8share"}
 # readers of the program's span record (chipbench/program_record.py)
 PROGRAM = ("step_prepare_ms.img", "step_prepare_ms.tok", "step_launch_ms.img",
            "step_launch_ms.tok", "setup_compile_s", "setup_programs", "setup_step_programs",
@@ -30,17 +31,31 @@ PROGRAM = ("step_prepare_ms.img", "step_prepare_ms.tok", "step_launch_ms.img",
 FIRST = ("dispatch_ms.img", "collective_exposed_pct.img", "device_idle_pct.img",
          "dispatch_ms.tok", "device_idle_pct.tok")
 # the entries appended after those readers, in the order they came
-APPENDED = SETUP + ("gmm_kernel_path_pct.tok", "kda_kernel_path_pct.tok")
+APPENDED = SETUP + ("gmm_kernel_path_pct.tok", "kda_kernel_path_pct.tok", "mhc_step_share_pct.tok",
+                    "mhc_roofline_pct.tok", "mhc_kernel_path_pct.tok")
 
 
 def cells(manifest):
     return [w["name"] for w in manifest["workloads"]]
 
 
-def entry(manifest, name):
-    found = [m for m in manifest["per_layer"] if m["name"] == name]
-    assert len(found) == 1, f"BENCHMARK.json has {len(found)} per_layer entries named {name}"
+def listed_once(entries, name):
+    """The one entry of a manifest list named `name`."""
+    found = [e for e in entries if e["name"] == name]
+    assert len(found) == 1, f"BENCHMARK.json has {len(found)} entries named {name}"
     return found[0]
+
+
+def entry(manifest, name):
+    return listed_once(manifest["per_layer"], name)
+
+
+def one_line(entry, *keys):
+    """Each of the entry's `keys` is 1 to 200 characters on one line, with no tab."""
+    for k in keys:
+        text = entry[k]
+        assert 0 < len(text) <= 200 and "\n" not in text and "\t" not in text, \
+            f"{entry['name']}: `{k}` is not one line of at most 200 characters"
 
 
 def reader(name):

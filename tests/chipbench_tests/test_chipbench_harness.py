@@ -2,8 +2,12 @@
 resolves to files, the trace arithmetic on a hand-made event list, the lag-2
 window on a fake clock, the valid-token count, a toy-size rehearsal of a
 whole run, and a rehearsal of an addition to the benchmark by new files and
-appends alone.  Nothing here needs the chip or describes a topology."""
+appends alone, on which every cell file's manifest checks run too.  Nothing
+here needs the chip or describes a topology."""
+import ast
 import copy
+import glob
+import importlib
 import json
 import os
 import shutil
@@ -140,6 +144,30 @@ def test_what_makes_a_run_incorrect():
     assert not run.is_correct(0, 0, [7.8, float("nan"), 6.0], band)   # warm-up not finite
 
 
+def test_the_numbers_compared_stand_beside_their_limits(monkeypatch, capsys):
+    """The result line's last key, and the last lines of standard error, give
+    every number `correct` compares with its limit."""
+    band = {"first": [6.4, 8.4]}
+    got = run.compared(1, 2, [7.8, float("nan"), 6.2], band)
+    assert {k: v["value"] for k, v in got.items() if k != "warmup_loss_change"} == {
+        "compiles_in_window": 1, "failed_steps": 2, "nonfinite_warmup_losses": 1,
+        "first_warmup_loss": 7.8}
+    assert got["warmup_loss_change"]["value"] == pytest.approx(-1.6)
+    assert got["first_warmup_loss"]["limit"] == [6.4, 8.4]
+    result = {"correct": False, "attempted": 3, "failed": 2, "metrics": {}, "device": {},
+              "compared": got}
+    monkeypatch.setattr(run, "load_cell", lambda name: (MANIFEST, 1, {}, {}, None))
+    monkeypatch.setattr(run, "measure", lambda *args: result)
+    assert run.main(["--workload", "any", "--seed", str(2 ** 31 + 5)]) == 0
+    out, err = capsys.readouterr()
+    assert list(json.loads(out.strip().splitlines()[-1]))[-1] == "compared"
+    assert err.strip().splitlines()[-len(got):] == [
+        "compared compiles_in_window 1 limit == 0", "compared failed_steps 2 limit == 0",
+        "compared nonfinite_warmup_losses 1 limit == 0",
+        "compared first_warmup_loss 7.8 limit [6.4, 8.4]",
+        f"compared warmup_loss_change {got['warmup_loss_change']['value']!r} limit < 0"]
+
+
 def test_valid_token_count_matches_the_mask():
     _m, _c, _cell, cfg, bert = run.load_cell("bert_base.phase1_t128")
     cfg, cell = dict(cfg, **TINY_BERT), TINY_CELL
@@ -175,16 +203,23 @@ def test_toy_rehearsal_of_a_whole_run(monkeypatch, capsys):
     assert out["device"]["platform"] == "cpu"
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
+    assert list(out)[-1] == "compared"
+    assert out["compared"] == run.compared(0, 0, earlier["warmup_losses"], TINY_CELL["loss_band"])
 
 
 NEW_CELL = "toy_lm.short"
 NEW_METRIC = "toy_idle_pct.tok"
+SHARED_METRIC = "toy_shared_idle_pct.tok"
+XING_CELL = "xing4_29b_a4b.sft_t8192_ep8share"
 NEW_READER = '''"""A made-up per-layer metric: device 0's idle share of the window."""
 
 
 def read(trace, spans, cell):
     return trace.idle_pct(0)
 '''
+# the test files of the cells, each with its MANIFEST_CHECKS: found by name, no list to edit
+CELL_FILES = sorted(glob.glob(os.path.join(HERE, "test_*_cell.py")))
+MANIFEST_LISTS = {"configs", "workloads", "end_to_end", "per_layer"}
 
 
 def write_json(value, *parts):
@@ -192,12 +227,25 @@ def write_json(value, *parts):
         json.dump(value, f, indent=1)
 
 
+def manifest_checks_of(path):
+    """The MANIFEST_CHECKS of a cell's test file: what must hold of its cell in
+    a manifest, each a function of one."""
+    found = getattr(importlib.import_module(os.path.basename(path)[:-len(".py")]),
+                    "MANIFEST_CHECKS", ())
+    assert found, f"{os.path.basename(path)} has no MANIFEST_CHECKS: give it its manifest " \
+        "assertions as functions of one manifest"
+    return found
+
+
 def checkout_with_an_addition(root, addition):
     """A checkout at `root` with the benchmark's files as they are and, for an
     `addition`, one configuration, one cell and one per-layer metric added by
     new files and appends alone; the toy BERT's builder serves as the new
     configuration's.  For "no_workloads_list" the metric's entry has no
-    `workloads` key.  Returns the checkout's manifest."""
+    `workloads` key.  "decoder_cell_shared_metric" appends as a decoder
+    configuration does: the cell also to `dispatch_ms.tok` and
+    `device_idle_pct.tok`, and a second new metric shared with the Xing cell.
+    Returns the checkout's manifest."""
     here = os.path.join(root, "chipbench")
     shutil.copytree(run.HERE, here, ignore=shutil.ignore_patterns("__pycache__"))
     manifest = copy.deepcopy(MANIFEST)
@@ -209,30 +257,39 @@ def checkout_with_an_addition(root, addition):
         cell = run.load_json(run.HERE, "workloads", "bert_base.phase1_t128.json")
         write_json(dict(cell, name=NEW_CELL, config="toy_lm", **TINY_CELL),
                    here, "workloads", NEW_CELL + ".json")
-        with open(os.path.join(here, "layer_metrics", NEW_METRIC + ".py"), "w") as f:
-            f.write(NEW_READER)
+        for name in (NEW_METRIC, SHARED_METRIC):
+            with open(os.path.join(here, "layer_metrics", name + ".py"), "w") as f:
+                f.write(NEW_READER)
         manifest["configs"].append({
             "name": "toy_lm", "source": "a made-up configuration of this test",
             "file": "chipbench/configs/toy_lm.json", "reduced": [], "why": "a rehearsal"})
         manifest["workloads"].append({"name": NEW_CELL, "config": "toy_lm", "traffic": "short",
                                       "chips": 1, "why": "a rehearsal"})
+        listed_under = {"tokens_per_s", *checks.SETUP}
+        if addition == "decoder_cell_shared_metric":
+            listed_under |= {"dispatch_ms.tok", "device_idle_pct.tok"}
         for m in manifest["end_to_end"] + manifest["per_layer"]:
-            if m["name"] == "tokens_per_s" or m["name"] in checks.SETUP:
+            if m["name"] in listed_under:
                 m["workloads"].append(NEW_CELL)
         metric = {"name": NEW_METRIC, "unit": "%", "better": "lower", "source": "device_trace",
                   "layer": "device (v5e)", "moves": "tokens_per_s", "workloads": [NEW_CELL]}
         if addition == "no_workloads_list":
             del metric["workloads"]
         manifest["per_layer"].append(metric)
+        if addition == "decoder_cell_shared_metric":
+            manifest["per_layer"].append(dict(metric, name=SHARED_METRIC,
+                                              workloads=[XING_CELL, NEW_CELL]))
     write_json(manifest, root, "BENCHMARK.json")
     return manifest
 
 
-@pytest.mark.parametrize("addition", ["nothing", "cell_config_metric", "no_workloads_list"])
+@pytest.mark.parametrize("addition", ["nothing", "cell_config_metric", "no_workloads_list",
+                                      "decoder_cell_shared_metric"])
 def test_an_addition_takes_new_files_and_appends(tmp_path, monkeypatch, addition):
-    """Every manifest check holds of the benchmark plus a configuration, a cell
-    and a metric that came by new files and appends to lists, no file that is
-    there edited; a metric with no `workloads` list fails them."""
+    """Every manifest check, and every cell file's MANIFEST_CHECKS, holds of
+    the benchmark plus a configuration, a cell and metrics that came by new
+    files and appends to lists, no file that is there edited; a metric with no
+    `workloads` list fails them."""
     manifest = checkout_with_an_addition(str(tmp_path), addition)
     monkeypatch.setattr(run, "ROOT", str(tmp_path))
     monkeypatch.setattr(run, "HERE", str(tmp_path / "chipbench"))
@@ -241,6 +298,9 @@ def test_an_addition_takes_new_files_and_appends(tmp_path, monkeypatch, addition
             checks.every_check(manifest)
         return
     checks.every_check(manifest)
+    for path in CELL_FILES:
+        for check in manifest_checks_of(path):
+            check(manifest)
     if addition == "nothing":
         return
     # the runner takes the new cell, with the end-to-end metrics it was appended to
@@ -250,6 +310,54 @@ def test_an_addition_takes_new_files_and_appends(tmp_path, monkeypatch, addition
     out = run.measure(NEW_CELL, *loaded, seed=2 ** 31 + 13, seconds=0.3, traced=False)
     assert out["correct"] and set(out["metrics"]) == {"tokens_per_s", "step_ms_p95", "setup_s"}
     layer = {m["name"] for m in run.metrics_of(manifest, "per_layer", NEW_CELL)}
-    assert layer == set(checks.SETUP) | {NEW_METRIC}
+    assert layer >= set(checks.SETUP) | {NEW_METRIC}
+    if addition == "decoder_cell_shared_metric":
+        assert layer >= {"dispatch_ms.tok", "device_idle_pct.tok", SHARED_METRIC}
+        assert SHARED_METRIC in {m["name"] for m in run.metrics_of(manifest, "per_layer",
+                                                                   XING_CELL)}
     made = trace.Trace([[("fusion f32[8]", 0.0, 1.0), ("fusion f32[8]", 3.0, 4.0)]], [])
     assert checks.reader(NEW_METRIC).read(made, made.spans, loaded[2]) == pytest.approx(50.0)
+
+
+def is_a_read(node):
+    """Whether `node` itself reads one of the manifest's lists."""
+    return (isinstance(node, ast.Subscript) and isinstance(node.slice, ast.Constant)
+            and node.slice.value in MANIFEST_LISTS) or \
+        (isinstance(node, ast.Attribute) and node.attr == "metrics_of")
+
+
+def reads_the_manifest(node):
+    return any(is_a_read(n) for n in ast.walk(node))
+
+
+def freezes(node):
+    """Whether `node` takes a position from the end, a length or an equality
+    of what reads one of the manifest's lists."""
+    if isinstance(node, ast.Subscript) and reads_the_manifest(node.value):
+        at = node.slice.lower if isinstance(node.slice, ast.Slice) else node.slice
+        return isinstance(at, ast.UnaryOp) and isinstance(at.op, ast.USub)
+    if isinstance(node, ast.Call) and getattr(node.func, "id", None) == "len":
+        return any(reads_the_manifest(a) for a in node.args)
+    if isinstance(node, ast.Compare) and any(isinstance(op, (ast.Eq, ast.NotEq))
+                                             for op in node.ops):
+        return any(reads_the_manifest(side) for side in [node.left, *node.comparators])
+    return False
+
+
+@pytest.mark.parametrize("path", CELL_FILES, ids=os.path.basename)
+def test_a_cell_file_asserts_on_the_manifest_in_its_checks_alone(path):
+    """A cell's test file reads the manifest's lists only in the functions of
+    its MANIFEST_CHECKS, which the rehearsal of an addition runs on appended
+    entries, and takes no position, length or exact set of them anywhere:
+    what must hold of its cell, never where things stand."""
+    named = {f.__name__ for f in manifest_checks_of(path)}
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for top in tree.body:
+        inside = isinstance(top, ast.FunctionDef) and top.name in named
+        for node in ast.walk(top):
+            where = f"{os.path.basename(path)}:{getattr(node, 'lineno', top.lineno)}"
+            assert inside or not is_a_read(node), \
+                f"{where} reads the manifest outside MANIFEST_CHECKS: move it into one"
+            assert not freezes(node), \
+                f"{where} pins a position, a length or an exact set of a manifest list"

@@ -2,13 +2,19 @@
 configuration file against the published config, the manifest's entries and
 both new readers, weights and first loss that do not follow `--seed`, a toy
 rehearsal of a whole run, the benchmark's copy of the reference, the FLOP and
-byte counts worked by hand, and the KDA readers on a hand-made trace."""
+byte counts worked by hand, and the KDA readers on a hand-made trace.
+
+What must hold of the manifest is in the functions of MANIFEST_CHECKS, each of
+one manifest, which the rehearsal of an addition (test_chipbench_harness.py)
+also runs on the manifest plus appended entries: presence, fields and
+supersets, never a position, a length or an exact set of a shared list."""
 import json
 import os
 
 import numpy as onp
 import pytest
 
+import manifest_checks
 from chipbench import run, trace
 
 CELL = "kimi_linear_48b_a3b.sft_t16384_ep32share"
@@ -32,6 +38,42 @@ def reader(name):
     return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
 
 
+def the_configurations_entry(manifest):
+    """The configuration and its cell are listed once each, with the cut the
+    configuration's file states and the cell's share of the deployment."""
+    entry = manifest_checks.listed_once(manifest["configs"], "kimi_linear_48b_a3b")
+    cfg = run.load_json(run.ROOT, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
+    workload = manifest_checks.listed_once(manifest["workloads"], CELL)
+    assert "32 times" in workload["why"] and "512" in workload["why"]
+
+
+def the_cells_metrics(manifest):
+    """The cell is listed under every metric it reports: a superset, since a
+    later addition may list it under more; KDA's entries keep their fields."""
+    ends = manifest_checks.end_to_end_of(manifest, CELL)
+    assert ends >= {"tokens_per_s", "step_ms_p95", "setup_s"}
+    layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
+    assert layers >= {
+        "dispatch_ms.tok", "device_idle_pct.tok", "setup_compile_s", "setup_programs",
+        "setup_step_programs", "setup_initialize_s", "step_mfu_pct.tok",
+        "flash_roofline_pct.tok", "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok",
+        "kda_roofline_pct.tok", "kda_step_share_pct.tok", "gmm_kernel_path_pct.tok",
+        "kda_kernel_path_pct.tok", "setup_import_s", "setup_trace_s", "setup_lower_s",
+        "setup_backend_s", "setup_cache_misses", "setup_settle_s", "setup_trace_self_max_s",
+        "setup_unaccounted_s"}
+    for name, better in (("kda_roofline_pct.tok", "higher"), ("kda_step_share_pct.tok", "lower")):
+        entry = manifest_checks.entry(manifest, name)
+        assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s"
+        assert entry["source"] == "device_trace" and entry["better"] == better
+        assert entry["layer"] == manifest_checks.OPS_LAYER
+        assert callable(reader(name).read)
+
+
+MANIFEST_CHECKS = (the_configurations_entry, the_cells_metrics)
+
+
 PUBLISHED = dict(
     hidden_size=2304, intermediate_size=9216, moe_intermediate_size=1024, kv_lora_rank=512,
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, num_attention_heads=32,
@@ -50,9 +92,7 @@ def test_configuration_keeps_the_published_value(loaded, key):
 
 def test_configuration_states_its_cut(loaded):
     manifest, chips, cell, cfg, mod = loaded
-    entry = next(c for c in manifest["configs"] if c["name"] == "kimi_linear_48b_a3b")
-    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
-    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
+    the_configurations_entry(manifest)
     assert (cfg["num_hidden_layers"], cfg["num_experts"], cfg["vocab_size"]) == (5, 8, 20480)
     assert cfg["published"] == {"num_hidden_layers": 27, "num_experts": 256,
                                 "vocab_size": 163840}
@@ -67,8 +107,6 @@ def test_configuration_states_its_cut(loaded):
     assert "32 chips" in cfg["deployment"] and cfg["dtype"] == "bfloat16" and cfg["remat"]
     assert chips == 1 and (cell["batch"], cell["seq_len"], cell["ring"]) == (1, 16384, 4)
     assert cell["trace_steps"] == 6 and "32 times" in cell["why"] and "512" in cell["why"]
-    workload = next(w for w in manifest["workloads"] if w["name"] == CELL)
-    assert "32 times" in workload["why"] and "512" in workload["why"]
     lo, hi = cell["loss_band"]["first"]
     assert hi - lo == pytest.approx(0.004, abs=1e-6) and 10.38 < lo < 10.3821 < hi
     assert cell["memory"]["chip"]["timed_program"]["temp_bytes"] \
@@ -77,24 +115,7 @@ def test_configuration_states_its_cut(loaded):
 
 
 def test_the_manifest_lists_the_cell_under_the_metrics_it_reports(loaded):
-    manifest = loaded[0]
-    ends = {m["name"] for m in run.metrics_of(manifest, "end_to_end", CELL)}
-    assert ends >= {"tokens_per_s", "step_ms_p95", "setup_s"}
-    layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
-    assert layers >= {
-        "dispatch_ms.tok", "device_idle_pct.tok", "setup_compile_s", "setup_programs",
-        "setup_step_programs", "setup_initialize_s", "step_mfu_pct.tok",
-        "flash_roofline_pct.tok", "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok",
-        "kda_roofline_pct.tok", "kda_step_share_pct.tok", "gmm_kernel_path_pct.tok",
-        "kda_kernel_path_pct.tok", "setup_import_s", "setup_trace_s", "setup_lower_s",
-        "setup_backend_s", "setup_cache_misses", "setup_settle_s", "setup_trace_self_max_s",
-        "setup_unaccounted_s"}
-    for name, better in (("kda_roofline_pct.tok", "higher"), ("kda_step_share_pct.tok", "lower")):
-        entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s"
-        assert entry["source"] == "device_trace" and entry["better"] == better
-        assert entry["layer"] == "ops and kernels (ops/nn.py, ops/pallas_kernels.py)"
-        assert callable(reader(name).read)
+    the_cells_metrics(loaded[0])
 
 
 def test_the_two_copies_of_the_reference_agree():
@@ -145,8 +166,7 @@ def test_toy_rehearsal_of_a_whole_run(loaded, seed, monkeypatch, capsys):
     out = run.measure(CELL, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_CFG),
                       mod, seed=seed, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {m["name"] for m in
-                                   run.metrics_of(manifest, "end_to_end", CELL)}
+    assert set(out["metrics"]) == manifest_checks.end_to_end_of(manifest, CELL)
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
     # one number for every seed: the weights and ring batch 0 are the file's

@@ -2,7 +2,12 @@
 configuration file against the published config, weights and first loss
 that do not follow `--seed`, a toy rehearsal of a whole run, the benchmark's
 copy of the reference, the FLOP and byte counts worked by hand, and the
-roofline readers on a hand-made trace."""
+roofline readers on a hand-made trace.
+
+What must hold of the manifest is in the functions of MANIFEST_CHECKS, each of
+one manifest, which the rehearsal of an addition (test_chipbench_harness.py)
+also runs on the manifest plus appended entries: presence, fields and
+supersets, never a position, a length or an exact set of a shared list."""
 import json
 import os
 
@@ -10,6 +15,7 @@ import jax
 import numpy as onp
 import pytest
 
+import manifest_checks
 from chipbench import run, trace
 
 CELL = "mellum2_12b_a2p5b.sft_t8192_ep4share"
@@ -30,6 +36,31 @@ def reader(name):
     return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
 
 
+def the_configurations_entry(manifest):
+    """The configuration is listed once, with the cut its file states."""
+    entry = manifest_checks.listed_once(manifest["configs"], "mellum2_12b_a2p5b")
+    cfg = run.load_json(run.ROOT, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
+
+
+def the_cells_metrics(manifest):
+    """The cell is listed once, on one chip, under every metric it reports: a
+    superset, since a later addition may list it under more."""
+    workload = manifest_checks.listed_once(manifest["workloads"], CELL)
+    assert workload["chips"] == 1
+    assert manifest_checks.end_to_end_of(manifest, CELL) >= {"tokens_per_s", "step_ms_p95",
+                                                             "setup_s"}
+    layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
+    assert layers >= set(manifest_checks.SETUP) | {
+        "setup_compile_s", "setup_programs", "setup_step_programs", "setup_initialize_s",
+        "dispatch_ms.tok", "device_idle_pct.tok", "step_mfu_pct.tok", "flash_roofline_pct.tok",
+        "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok", "gmm_kernel_path_pct.tok"}
+
+
+MANIFEST_CHECKS = (the_configurations_entry, the_cells_metrics)
+
+
 PUBLISHED = dict(hidden_size=2304, num_attention_heads=32, num_key_value_heads=4, head_dim=128,
                  moe_intermediate_size=896, num_experts_per_tok=8, sliding_window=1024,
                  intermediate_size=7168, rms_norm_eps=1e-06, max_position_embeddings=131072,
@@ -43,9 +74,7 @@ def test_configuration_keeps_the_published_value(loaded, key):
 
 def test_configuration_states_its_cut(loaded):
     manifest, chips, cell, cfg, _mod = loaded
-    entry = next(c for c in manifest["configs"] if c["name"] == "mellum2_12b_a2p5b")
-    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
-    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
+    the_configurations_entry(manifest)
     assert (cfg["num_hidden_layers"], cfg["num_experts"], cfg["vocab_size"]) == (4, 16, 24576)
     assert cfg["published"] == {"num_hidden_layers": 28, "num_experts": 64, "vocab_size": 98304}
     assert cfg["num_experts_routed"] == 64 and cfg["ep_rank"] in range(4)
@@ -58,6 +87,10 @@ def test_configuration_states_its_cut(loaded):
     assert {"deployment", "assumed", "departures", "weights_seed"} <= set(cfg)
     assert chips == 1 and (cell["batch"], cell["seq_len"], cell["ring"]) == (2, 8192, 4)
     assert "four times" in cell["why"]
+
+
+def test_the_manifest_lists_the_cell_under_the_metrics_it_reports(loaded):
+    the_cells_metrics(loaded[0])
 
 
 def test_the_two_copies_of_the_reference_agree():
@@ -106,8 +139,7 @@ def test_toy_rehearsal_of_a_whole_run(loaded, seed, monkeypatch, capsys):
     out = run.measure(CELL, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_CFG),
                       mod, seed=seed, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {m["name"] for m in
-                                   run.metrics_of(manifest, "end_to_end", CELL)}
+    assert set(out["metrics"]) == manifest_checks.end_to_end_of(manifest, CELL)
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
     # one number for every seed: the weights and ring batch 0 are the file's

@@ -1,5 +1,5 @@
 """The readers of set-up's span record (`chipbench/setup_record.py`) and of the
-two kernel-path counters, on the CPU: each resolves to its entry, reads a
+kernel-path counters, on the CPU: each resolves to its entry, reads a
 hand-made record, returns nothing on a record from before these spans, and
 reads the record that a toy run left from the process's start.  The set-up
 readers list every cell; each kernel-path counter lists the cell of its kernels
@@ -15,7 +15,7 @@ from chipbench import run, setup_record, trace
 
 MANIFEST = run.load_json(run.ROOT, "BENCHMARK.json")
 SETUP = checks.SETUP
-PATHS = ("kda_kernel_path_pct.tok", "gmm_kernel_path_pct.tok")
+PATHS = tuple(checks.KERNEL_PATHS)
 MS = 1_000_000   # ns
 reader = checks.reader
 
@@ -165,7 +165,8 @@ def test_self_time_by_class_sums_to_the_outermost_spans():
 @pytest.mark.parametrize("name,counter,kernel,other", [
     ("kda_kernel_path_pct.tok", "mxtpu_linear_attention_lowerings", "pallas_chunk",
      "chunked_scan"),
-    ("gmm_kernel_path_pct.tok", "mxtpu_grouped_matmul_lowerings", "pallas", "ragged_dot")])
+    ("gmm_kernel_path_pct.tok", "mxtpu_grouped_matmul_lowerings", "pallas", "ragged_dot"),
+    ("mhc_kernel_path_pct.tok", "mxtpu_hyperconnection_lowerings", "pallas", "xla")])
 def test_kernel_path_share_reads_the_lowering_counter(monkeypatch, name, counter, kernel, other):
     from mxnet_tpu import telemetry
     registry = telemetry.MetricsRegistry()
@@ -250,4 +251,4 @@ def test_the_stage_split_covers_what_setup_compile_s_reads(toy_run):
     assert read["setup_settle_s"] == 0 and "block.settle_shapes" not in toy_run["names"]
     assert {"process.before_import", "runtime.import", "runtime.backend_start",
             "fused_step.build"} <= set(toy_run["names"])
-    assert read["kda_kernel_path_pct.tok"] is None and read["gmm_kernel_path_pct.tok"] is None
+    assert all(read[name] is None for name in PATHS)           # BERT runs none of them

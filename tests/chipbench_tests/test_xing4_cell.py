@@ -4,7 +4,13 @@ config, the cut and its parameter count, the manifest's entries and every
 invariant of manifest_checks on the real manifest, a ring that does not follow
 `--seed`, a toy rehearsal of a whole run, the benchmark's copy of the
 reference, mHC's FLOP and byte counts worked by hand, and the two mHC readers
-on a hand-made trace (mhc_trace_fixture.json)."""
+on a hand-made trace (mhc_trace_fixture.json).
+
+What must hold of the manifest is in the functions of MANIFEST_CHECKS, each of
+one manifest: the tests here run them on BENCHMARK.json, and the rehearsal of
+an addition (test_chipbench_harness.py) on the manifest plus appended entries.
+They state presence, fields and supersets, never a position, a length or an
+exact set of a list that later additions append to."""
 import json
 import os
 
@@ -54,6 +60,55 @@ def reader(name):
     return run.load_py(os.path.join(run.HERE, "layer_metrics", name + ".py"))
 
 
+def the_configurations_entry(manifest):
+    """The configuration is listed once, with the cut and the source its file states."""
+    entry = manifest_checks.listed_once(manifest["configs"], "xing4_29b_a4b")
+    cfg = run.load_json(run.ROOT, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
+    assert entry["source"] == cfg["source"]
+    manifest_checks.one_line(entry, "why", "source")
+
+
+def the_cells_entries(manifest):
+    """Both cells are listed once each, on one chip, with a one-line `why`."""
+    xing = manifest_checks.listed_once(manifest["workloads"], CELL)
+    bert = manifest_checks.listed_once(manifest["workloads"], BERT2)
+    assert xing["config"] == "xing4_29b_a4b" and xing["chips"] == 1 and "512" in xing["why"]
+    assert bert["config"] == "bert_base" and bert["chips"] == 1
+    for entry in (xing, bert):
+        manifest_checks.one_line(entry, "why")
+
+
+def the_cells_metrics(manifest):
+    """Each cell is listed under every metric it reports: a superset, since a
+    later addition may list it under more; the mHC entries keep their fields."""
+    tok = {"tokens_per_s", "step_ms_p95", "setup_s"}
+    for cell in (CELL, BERT2):
+        assert manifest_checks.end_to_end_of(manifest, cell) >= tok, cell
+    layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
+    assert layers >= set(manifest_checks.SETUP) | {
+        "setup_compile_s", "setup_programs", "setup_step_programs", "setup_initialize_s",
+        "dispatch_ms.tok", "device_idle_pct.tok", "step_mfu_pct.tok", "flash_roofline_pct.tok",
+        "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok", "gmm_kernel_path_pct.tok",
+        "mhc_step_share_pct.tok", "mhc_roofline_pct.tok", "mhc_kernel_path_pct.tok"}
+    for name, better, source in (("mhc_step_share_pct.tok", "lower", "device_trace"),
+                                 ("mhc_roofline_pct.tok", "higher", "device_trace"),
+                                 ("mhc_kernel_path_pct.tok", "higher", "program_counter")):
+        entry = manifest_checks.entry(manifest, name)
+        assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s"
+        assert entry["source"] == source and entry["better"] == better
+        assert entry["layer"] == manifest_checks.OPS_LAYER and entry["unit"] == "%"
+    # phase 2 is read as phase 1 is: the step's, the device's and set-up's readers
+    bert = set(manifest_checks.SETUP) | {"dispatch_ms.tok", "device_idle_pct.tok"} | {
+        n for n in manifest_checks.PROGRAM if not n.endswith(".img")}
+    for cell in ("bert_base.phase1_t128", BERT2):
+        assert {m["name"] for m in run.metrics_of(manifest, "per_layer", cell)} >= bert, cell
+
+
+MANIFEST_CHECKS = (the_configurations_entry, the_cells_entries, the_cells_metrics)
+
+
 @pytest.mark.parametrize("key", sorted(PUBLISHED))
 def test_configuration_keeps_the_published_value(loaded, key):
     cfg = loaded[3]
@@ -64,10 +119,7 @@ def test_configuration_keeps_the_published_value(loaded, key):
 
 def test_configuration_states_its_cut(loaded):
     manifest, chips, cell, cfg, mod = loaded
-    entry = next(c for c in manifest["configs"] if c["name"] == "xing4_29b_a4b")
-    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    assert [r.split()[0] for r in cfg["reduced"]] == entry["reduced"]
-    assert entry["source"] == cfg["source"]
+    the_configurations_entry(manifest)
     assert cfg["router_experts"] == 64 and cfg["ep_rank"] in range(8)
     assert cfg["layers_held"] == [0, 2, 3, 4, 5]        # the dense layer once, four routed
     assert mod.layer_kinds(cfg) == [("latent_attention", "dense")] + \
@@ -79,8 +131,6 @@ def test_configuration_states_its_cut(loaded):
     assert any("rotate-half" in d for d in cfg["departures"])
     assert chips == 1 and (cell["batch"], cell["seq_len"], cell["ring"]) == (1, 8192, 4)
     assert cell["trace_steps"] == 6 and "512" in cell["why"]
-    workload = next(w for w in manifest["workloads"] if w["name"] == CELL)
-    assert "512" in workload["why"] and workload["chips"] == 1
     assert cell["mhc_result_shapes"] and cell["flash_result_shapes"] == []
 
 
@@ -102,24 +152,7 @@ def test_the_cut_holds_the_stated_parameters(loaded):
 
 
 def test_the_manifest_lists_the_cells_under_the_metrics_they_report(loaded):
-    manifest = loaded[0]
-    ends = {m["name"] for m in run.metrics_of(manifest, "end_to_end", CELL)}
-    assert ends == {"tokens_per_s", "step_ms_p95", "setup_s"}
-    layers = {m["name"] for m in run.metrics_of(manifest, "per_layer", CELL)}
-    assert layers == set(manifest_checks.SETUP) | {
-        "setup_compile_s", "setup_programs", "setup_step_programs", "setup_initialize_s",
-        "dispatch_ms.tok", "device_idle_pct.tok", "step_mfu_pct.tok", "flash_roofline_pct.tok",
-        "moe_experts_roofline_pct.tok", "moe_load_max_over_mean.tok", "gmm_kernel_path_pct.tok",
-        "mhc_step_share_pct.tok", "mhc_roofline_pct.tok"}
-    for name, better in (("mhc_step_share_pct.tok", "lower"), ("mhc_roofline_pct.tok", "higher")):
-        entry = manifest_checks.entry(manifest, name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s"
-        assert entry["source"] == "device_trace" and entry["better"] == better
-        assert entry["layer"] == manifest_checks.OPS_LAYER and entry["unit"] == "%"
-    bert = {m["name"] for m in run.metrics_of(manifest, "per_layer", BERT2)}
-    assert bert == {m["name"] for m in run.metrics_of(manifest, "per_layer",
-                                                      "bert_base.phase1_t128")}
-    assert {m["name"] for m in run.metrics_of(manifest, "end_to_end", BERT2)} == ends
+    the_cells_metrics(loaded[0])
 
 
 def test_every_check_passes_on_the_real_manifest():
@@ -127,19 +160,13 @@ def test_every_check_passes_on_the_real_manifest():
 
 
 def test_the_new_entries_were_appended(loaded):
+    """The configuration and both cells are there once each; the per-layer
+    entries that came with them follow those that were there before, in the
+    order they came (manifest_checks.APPENDED)."""
     manifest = loaded[0]
-    assert [w["name"] for w in manifest["workloads"]][-2:] == [CELL, BERT2]
-    assert manifest["configs"][-1]["name"] == "xing4_29b_a4b"
-    for entry in manifest["workloads"][-2:] + manifest["configs"][-1:]:   # one line each
-        assert all(0 < len(entry[k]) <= 200 and "\n" not in entry[k] and "\t" not in entry[k]
-                   for k in ("why", "source") if k in entry), entry["name"]
-    assert [m["name"] for m in manifest["per_layer"]][-2:] == ["mhc_step_share_pct.tok",
-                                                              "mhc_roofline_pct.tok"]
-    for m in manifest["per_layer"] + manifest["end_to_end"]:
-        listed = m.get("workloads", [])
-        for cell in (CELL, BERT2):
-            if cell in listed:   # appended after every cell that was there
-                assert listed.index(cell) >= len(listed) - 2, m["name"]
+    the_configurations_entry(manifest)
+    the_cells_entries(manifest)
+    manifest_checks.order_kept(manifest)
 
 
 def test_the_bert_phase2_cell_is_phase1_at_512(loaded):
@@ -192,8 +219,7 @@ def test_toy_rehearsal_of_a_whole_run(loaded, seed, monkeypatch, capsys):
     out = run.measure(CELL, manifest, chips, dict(cell, **TINY_CELL), dict(cfg, **TINY_CFG),
                       mod, seed=seed, seconds=0.3, traced=False)
     assert out["correct"] and out["failed"] == 0 and out["attempted"] >= 3
-    assert set(out["metrics"]) == {m["name"] for m in
-                                   run.metrics_of(manifest, "end_to_end", CELL)}
+    assert set(out["metrics"]) == manifest_checks.end_to_end_of(manifest, CELL)
     earlier = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert earlier["compiles_in_window"] == 0 and earlier["mfu_bf16"] > 0
     assert earlier["warmup_losses"][0] == pytest.approx(
